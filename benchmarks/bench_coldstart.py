@@ -8,9 +8,13 @@ batch-bucket x seq-bucket x nfe grid), measured from engine construction:
   own XLA compile at drain time (the pre-warmup serving behavior).
 * ``aot``         — ``BatchedSampler.warmup()``: the grid is lowered and
   compiled from abstract shapes before the first request (no sampling).
-* ``cache_cold``  — AOT warmup with a *fresh* persistent compilation
-  cache dir: same compile wall as ``aot``, but every program is written
-  to disk (the first deploy of a fleet).
+* ``cache_cold``  — AOT warmup with an *empty* persistent compilation
+  cache: same compile wall as ``aot``, but every program is written to
+  disk (the first deploy of a fleet).  The cache lives where
+  :func:`repro.serving.cache_dir` says: ``$JAX_COMPILATION_CACHE_DIR`` when
+  set — which this bench never clears, so the record says whether the
+  scenario really started cold — else the checkout's ``.jax_cache``, which
+  it clears first.
 * ``cache_warm``  — AOT warmup against the now-populated cache dir: the
   redeploy path, where warmup is disk loads instead of XLA compiles.
 
@@ -27,7 +31,7 @@ Reported per scenario (all seconds from engine construction):
   request-path fresh compiles than a cold boot (0 vs 1).
 
 The persistent-cache config is process-global (``jax.config``), so the
-cache-less scenarios run first and the cache dir is a tmpdir wiped at
+cache-less scenarios run first, and the cache is switched off again at
 exit.  All four engines live in one process: the in-process ``_jitted``
 executable cache is per-engine, so a later scenario never reuses an
 earlier scenario's executables — only the on-disk cache carries over,
@@ -41,7 +45,6 @@ import json
 import os
 import shutil
 import sys
-import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -52,6 +55,7 @@ from repro.serving import (  # noqa: E402
     SampleRequest,
     configure_persistent_cache,
 )
+from repro.serving.compile_cache import CACHE_DIR_ENV, cache_dir  # noqa: E402
 from repro.serving import result_keys as K  # noqa: E402
 
 BATCH_BUCKETS = (1, 2) if C.SMOKE else (1, 4, 8)
@@ -126,20 +130,23 @@ def run(out: str = "BENCH_coldstart.json") -> None:
     # cache-less boots must run before the cache dir is enabled
     for mode in ("cold", "aot"):
         scenarios.append(boot(mode, dlm, params))
-    cache_dir = tempfile.mkdtemp(prefix="era_compile_cache_")
+    # an outside-placed cache is never cleared: it may hold other programs
+    cleared = not os.environ.get(CACHE_DIR_ENV)
+    if cleared:
+        shutil.rmtree(cache_dir(), ignore_errors=True)
     try:
-        configure_persistent_cache(cache_dir)
+        configure_persistent_cache()
         for mode in ("cache_cold", "cache_warm"):
             scenarios.append(boot(mode, dlm, params))
     finally:
-        # the cache config is process-global; leave no dangling pointer at
-        # the wiped tmpdir for later suites in a benchmarks.run invocation
-        import jax
-        from jax._src import compilation_cache as _cc
+        # the cache config is process-global: unless the environment placed
+        # it, later suites of a benchmarks.run invocation compile without it
+        if cleared:
+            import jax
+            from jax._src import compilation_cache as _cc
 
-        jax.config.update("jax_compilation_cache_dir", None)
-        _cc.reset_cache()
-        shutil.rmtree(cache_dir, ignore_errors=True)
+            jax.config.update("jax_compilation_cache_dir", None)
+            _cc.reset_cache()
 
     by_mode = {s["mode"]: s for s in scenarios}
     record = {
@@ -151,6 +158,8 @@ def run(out: str = "BENCH_coldstart.json") -> None:
             "nfes": list(NFES),
             "programs": len(_grid()),
         },
+        "cache_dir": cache_dir(),
+        "cache_cleared_before_cache_cold": cleared,
         "scenarios": scenarios,
     }
 

@@ -25,10 +25,12 @@ Each mode reports p50/p99 arrival-to-result latency and throughput over the
 stream makespan, and the whole sweep is written as a JSON artifact
 (`BENCH_serving.json` by default — the CI bench-smoke job uploads it).
 
-Mesh sweep (`--mesh`): reruns the scenarios on 1 vs 8 virtual host devices
-(`XLA_FLAGS=--xla_force_host_platform_device_count=8`, one child process per
-device count since the flag binds at jax init) with the engine batch-sharded
-over a ("data",) mesh — the same placement a TPU pod slice would use.
+Mesh sweep (`--mesh`): a CPU tool.  It reruns the scenarios on 1 vs 8
+virtual host devices (`XLA_FLAGS=--xla_force_host_platform_device_count=8`,
+one child process per device count since the flag binds at jax init, each
+pinned to `JAX_PLATFORMS=cpu`) with the engine batch-sharded over a
+("data",) mesh — the placement a TPU slice would use, but never on the chip:
+the chip's mesh path is `python chip_smoke.py --chips 4`.
 
 Solver sweep (`--solver-sweep`): runs **every registry solver** through one
 engine via per-request `solver=` routing (the PR-4 solver-program refactor:
@@ -75,7 +77,9 @@ if fused traffic compiles more programs than |nfe_buckets| x
 
 Front-door sweep (`--frontdoor`): boots the real HTTP server as a
 subprocess (`python -m repro.launch.serve --listen --port 0`, waiting on
-its `FRONTDOOR READY <url>` line), then drives an open-loop Poisson client
+its `FRONTDOOR READY <url>` line) from a parent that never touches a JAX
+backend — a chip belongs to one process, and the server child must get
+it — then drives an open-loop Poisson client
 over the wire — every request pays JSON + base64 + loopback TCP, and
 concurrent wire requests fuse in the server's scheduler exactly like
 in-process submits.  Reports wire p50/p99 arrival-to-result latency and
@@ -674,7 +678,18 @@ FRONTDOOR_REQUIRED_METRICS = (
 
 def _boot_frontdoor_server(nfe: int, seq: int, max_wait_ms: float):
     """Launch `repro.launch.serve --listen --port 0` as a subprocess and
-    wait for its `FRONTDOOR READY <url>` sentinel.  Returns (proc, url)."""
+    wait for its `FRONTDOOR READY <url>` sentinel.  Returns (proc, url).
+
+    The child needs the accelerator, which a parent that has initialized a
+    JAX backend would hold, so that case is refused up front."""
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError(
+            "the front-door sweep's parent has initialized a JAX backend, so "
+            "the server child could not get the device; run it alone: "
+            "python -m benchmarks.bench_serving --frontdoor"
+        )
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(root, "src") + (
@@ -811,14 +826,15 @@ def run_on_local_mesh() -> None:
 
 
 def run_mesh_sweep() -> None:
-    """1 vs N virtual devices, one subprocess per device count (XLA_FLAGS
-    must be set before jax initializes)."""
+    """1 vs N virtual CPU devices, one subprocess per device count (XLA_FLAGS
+    must be set before jax initializes).  CPU only: each child is pinned to
+    ``JAX_PLATFORMS=cpu``, so this never takes the chip."""
     for n in MESH_SWEEP_DEVICES:
         env = dict(os.environ)
         flags = f"--xla_force_host_platform_device_count={n}"
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flags).strip()
         # the flag only multiplies CPU devices; pin the child to CPU so the
-        # sweep doesn't silently bench a 1-GPU mesh twice
+        # sweep neither benches a 1-chip mesh twice nor contends for a chip
         env["JAX_PLATFORMS"] = "cpu"
         proc = subprocess.run(
             [sys.executable, "-m", "benchmarks.bench_serving", "--mesh-child"],

@@ -27,16 +27,22 @@ Engine notes (serving path):
   Lagrange buffers in place.
 * :func:`sample_scan` takes the eps/t buffers as explicit arguments so a
   jitting caller (``repro.serving.BatchedSampler``) can donate them.
-* Steps 2-4 default to the fused Pallas kernel
-  (``repro.kernels.era_update``) — one HBM round trip per operand instead of
-  ~(k+5) — with automatic ``interpret=True`` fallback off-TPU and a
-  pure-jnp fallback if Pallas itself is unavailable.
+* Steps 2-4 run the fused Pallas kernel (``repro.kernels.era_update``) —
+  one HBM round trip per operand instead of ~(k+5).  The platform picks how
+  it runs: compiled by Mosaic on TPU, in interpret mode everywhere else.
+  There is no runtime probe and no fallback: a kernel that fails to lower
+  or compile raises.  ``ERAConfig.use_fused_update=False`` selects the
+  pure-jnp reference combine, which the parity tests and the chip smoke
+  check compare the kernel against.
 * :func:`sample_scan` optionally takes explicit carry ``shardings``
   (``parallel.sharding.sampler_shardings``): latents and Lagrange buffers
   batch-sharded over a mesh's data axes, t grid replicated.  With
   ``per_sample=True`` every step's ERS math is row-local, so the sharded
   scan runs with **zero cross-device collectives inside the loop** (the only
   batch reduction, the delta_eps diagnostic mean, happens once after it).
+  The fused step then runs per batch shard under ``shard_map``
+  (``parallel.sharding.per_batch_shard``): XLA cannot partition a Mosaic
+  kernel.
 """
 
 from __future__ import annotations
@@ -64,6 +70,7 @@ from repro.core.solver_base import (
     ddim_step,
     step_grid,
 )
+from repro.parallel.sharding import per_batch_shard
 
 Array = jax.Array
 
@@ -80,49 +87,29 @@ class ERAConfig(SolverConfig):
     selection: str = "ers"         # "ers" | "fixed" | "const"
     const_power: float = 1.0       # used when selection == "const"
     error_norm: str = "global"     # "global" (Eq. 15) | "mean" (per-sample mean)
-    use_fused_update: bool = True  # route step 2-4 through the Pallas kernel
+    # False = the pure-jnp reference combine (kernel parity checks only)
+    use_fused_update: bool = True
     # beyond-paper: independent delta_eps + base selection per batch element
     # (the paper shares one scalar across the batch)
     per_sample: bool = False
 
 
-_FUSED_OK: dict[str, bool] = {}
-_FUSED_TOL = 1e-5
+def _fixed_order_sum(v: Array) -> Array:
+    """Sum over the last axis in an order fixed by that axis alone.
 
-
-def _fused_ops():
-    """The Pallas wrapper module, or None when the fused path is unusable.
-
-    Unusable means Pallas missing OR the kernel failing the one-time (per
-    process, per backend) numerics parity probe against the pure-jnp
-    reference — every ERA entry point shares this gate, so a misbehaving
-    kernel degrades to the jnp combine instead of silently wrong samples.
-
-    The probe can only execute eagerly (it runs the kernel and reads the
-    error as a Python float).  If the gate's first consultation happens
-    inside an outer jit trace — a jitting caller's very first trace on a
-    fresh process — the probe is deferred rather than run-and-failed: that
-    trace takes the jnp path, the cache stays unpoisoned, and the next
-    eager consultation (e.g. ``serving.BatchedSampler``, which checks the
-    gate before building each jitted bucket) enables the kernel normally.
-    Caveat for direct jitting callers: jax never retraces a cached shape,
-    so an executable compiled during the deferral keeps the jnp path for
-    its lifetime even after ``fused_path_ok()`` turns True — consult the
-    gate eagerly before jitting (as the engine does) to avoid that.
-    """
-    try:
-        from repro.kernels import ops as _kops
-    except Exception:  # missing pallas / unsupported backend
-        return None
-    backend = jax.default_backend()
-    if backend not in _FUSED_OK:
-        if not jax.core.trace_state_clean():
-            return None  # mid-trace: defer the probe, don't cache a verdict
-        try:
-            _FUSED_OK[backend] = _kops.fused_step_parity() <= _FUSED_TOL
-        except Exception:
-            _FUSED_OK[backend] = False
-    return _kops if _FUSED_OK[backend] else None
+    XLA picks a reduction's association order, and inside a larger program
+    that order can change with the leading (batch, sequence) shape.  Halving
+    with elementwise adds (after zero-padding to a power of two, which adds
+    exact zeros) gives every row the same float result at any leading
+    shape."""
+    n = v.shape[-1]
+    width = 1 << max(n - 1, 0).bit_length()
+    if width != n:
+        v = jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, width - n)])
+    while v.shape[-1] > 1:
+        half = v.shape[-1] // 2
+        v = v[..., :half] + v[..., half:]
+    return v[..., 0]
 
 
 def _seq_sq_sums(d: Array, valid: Array | None) -> Array:
@@ -134,9 +121,10 @@ def _seq_sq_sums(d: Array, valid: Array | None) -> Array:
     run.  A plain ``jnp.sum`` over the padded layout cannot promise that:
     XLA may re-associate a size-L' reduction differently from a size-L one
     even when the extra entries are exact zeros.  So the reduction here is
-    (a) features first, at fixed per-position shape, then (b) a strictly
-    sequential ``lax.scan`` over positions — appending zero-masked pad
-    positions only appends ``acc + 0.0`` steps, which are exact no-ops.
+    (a) features first, in a fixed order (:func:`_fixed_order_sum`), then
+    (b) a strictly sequential ``lax.scan`` over positions — appending
+    zero-masked pad positions only appends ``acc + 0.0`` steps, which are
+    exact no-ops.
     The accumulation is elementwise per row, so a batch-sharded run stays
     collective-free.  Rank-2 inputs (no sequence axis) keep the plain
     squared norm.
@@ -144,7 +132,7 @@ def _seq_sq_sums(d: Array, valid: Array | None) -> Array:
     d = d.astype(jnp.float32)
     if d.ndim < 3:
         return jnp.sum(d.reshape(d.shape[0], -1) ** 2, axis=-1)
-    p = jnp.sum(d.reshape(d.shape[0], d.shape[1], -1) ** 2, axis=-1)  # (B, S)
+    p = _fixed_order_sum(d.reshape(d.shape[0], d.shape[1], -1) ** 2)  # (B, S)
     if valid is not None:
         p = jnp.where(valid, p, 0.0)
     total, _ = jax.lax.scan(
@@ -268,7 +256,17 @@ def sample_scan(
         ts = None
         t0 = steps.ts[:, 0].reshape((-1,) + (1,) * (x_init.ndim - 1))
     dt = config.solver_dtype
-    kops = _fused_ops() if config.use_fused_update else None
+    if config.use_fused_update:
+        from repro.kernels import ops as kops  # kernels import core.lagrange
+    else:
+        kops = None
+
+    def per_shard(fn, *args, batch_dims):
+        # on a mesh the kernel runs per batch shard: XLA cannot partition it
+        if shardings is None:
+            return fn(*args)
+        return per_batch_shard(shardings.x, fn, *args, batch_dims=batch_dims)
+
     am4 = jnp.asarray(AM4, jnp.float32)
     valid = (
         None
@@ -345,21 +343,19 @@ def sample_scan(
                 # fused per-sample step: vmap the Pallas kernel over the
                 # batch (each element carries its own Lagrange nodes; with
                 # per-row grids, also its own times and DDIM coefficients)
-                if steps is None:
-                    x_next, eps_bar = jax.vmap(
-                        lambda xb, es, tn, eh: kops.era_step(
-                            xb, es, tn, eh, t_next, cx, ce, am4
-                        )
-                    )(x, eps_sel, t_sel, e_hist_b)
-                else:
-                    x_next, eps_bar = jax.vmap(
-                        lambda xb, es, tn, eh, tnb, cxb, ceb: kops.era_step(
-                            xb, es, tn, eh, tnb, cxb, ceb, am4
-                        )
-                    )(
-                        x, eps_sel, t_sel, e_hist_b,
-                        t_next.reshape(-1), cx.reshape(-1), ce.reshape(-1),
-                    )
+                r = None if steps is None else 0
+                rows = jax.vmap(
+                    lambda xb, es, tn, eh, tnb, cxb, ceb: kops.era_step(
+                        xb, es, tn, eh, tnb, cxb, ceb, am4
+                    ),
+                    in_axes=(0, 0, 0, 0, r, r, r),
+                )
+                if steps is not None:
+                    t_next, cx, ce = (a.reshape(-1) for a in (t_next, cx, ce))
+                x_next, eps_bar = per_shard(
+                    rows, x, eps_sel, t_sel, e_hist_b, t_next, cx, ce,
+                    batch_dims=(0, 0, 0, 0, r, r, r),
+                )
                 return x_next, eps_bar, tau
             if steps is None:
                 eps_bar, eps_corr = jax.vmap(
@@ -380,8 +376,10 @@ def sample_scan(
             # fused step: predictor combine + AM4 corrector + DDIM x-update
             # in one HBM pass
             cx, ce = schedule.ddim_coeffs(t_cur, t_next)
-            x_next, eps_bar = kops.era_step(
-                x, eps_sel, t_sel, e_hist, t_next, cx, ce, am4
+            x_next, eps_bar = per_shard(
+                lambda *a: kops.era_step(*a, am4),
+                x, eps_sel, t_sel, e_hist, t_next, cx, ce,
+                batch_dims=(0, 1, None, 1, None, None, None),
             )
             return x_next, eps_bar, tau
         eps_bar, eps_corr = era_combine(eps_sel, t_sel, e_hist, t_next)
@@ -520,13 +518,6 @@ class ERAProgram(SolverProgram):
 
     def alloc_buffers(self, x_like, cfg: ERAConfig, shardings=None):
         return alloc_buffers(x_like, cfg, shardings)
-
-    def pre_compile(self, cfg: ERAConfig) -> None:
-        # consult the fused-kernel parity gate eagerly — the probe cannot
-        # run inside a jit trace, and a process serving only compiled
-        # buckets would otherwise never enable the Pallas step
-        if cfg.use_fused_update:
-            _fused_ops()
 
     def sample_scan(
         self, eps_fn, x_init, buffers, schedule, cfg, shardings=None,

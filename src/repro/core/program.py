@@ -39,9 +39,6 @@ solver-specific branches:
   never change a valid position's math (elementwise solvers get this for
   free; ERA masks its ERS error norms so a pad token can never flip a
   Lagrange-basis selection).
-* ``pre_compile(cfg)`` — eager hook consulted before a caller jits the
-  program (ERA uses it to run the fused-kernel parity probe, which cannot
-  execute inside a jit trace).
 
 Concrete programs live next to their solver math (``DDIMProgram`` in
 ``ddim.py``, ...) and are registered in :mod:`repro.core.registry`.
@@ -268,10 +265,6 @@ class SolverProgram:
         )
 
     # ---- compiled entry --------------------------------------------------
-    def pre_compile(self, cfg: SolverConfig) -> None:
-        """Eager hook run before a caller jits ``sample_scan`` (probes that
-        cannot execute mid-trace, e.g. ERA's fused-kernel parity gate)."""
-
     def sample_scan(
         self,
         eps_fn: EpsFn,

@@ -11,11 +11,11 @@ Grid: 1-D over flattened-sample blocks.  Scalar operands (Lagrange weights,
 AM4 coefficients, DDIM cx/ce) ride in SMEM via PrefetchScalarGridSpec so
 they are resident before the tile loop starts.
 
-This kernel is the *default* ERA step path (``ERAConfig.use_fused_update``):
-``repro.kernels.ops.era_step`` auto-selects ``interpret=True`` off-TPU, and
-``repro.kernels.ops.fused_step_parity`` gates its numerics against the
-pure-jnp reference combine.  Per-sample ERS batches vmap this kernel (the
-pallas batching rule prepends a grid dimension).
+This kernel is ERA's step: ``repro.kernels.ops.era_step`` compiles it on
+TPU and runs it in interpret mode elsewhere, and the tests hold it to the
+pure-jnp reference combine (``repro.kernels.ops.fused_step_parity``).
+Per-sample ERS batches vmap this kernel; with scalar-prefetch operands the
+pallas batching rule lowers that to a loop of one kernel call per row.
 """
 
 from __future__ import annotations
@@ -83,6 +83,7 @@ def era_update(
     )
     x_next, eps_bar = pl.pallas_call(
         kernel,
+        name="era_update",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,

@@ -5,8 +5,14 @@ Handles the hardware-alignment plumbing so callers keep natural shapes:
   (padded key slots get position -1 => masked out; padded head dims are
   zeros => contribute nothing to dot products, scale uses the true hd);
 * pads GQA group G to the f32 sublane multiple (8) for the decode kernel;
-* auto-selects interpret mode off-TPU so the same call sites work in CPU
-  tests and on real hardware.
+* picks how the kernels run from the platform: compiled by Mosaic on TPU,
+  in interpret mode everywhere else, so the same call sites run in CPU
+  tests and on the chip.  There is no fallback: a kernel that fails to
+  lower or compile raises.
+
+XLA cannot partition a Mosaic kernel, so on a multi-device mesh a caller
+runs these per batch shard (``parallel.sharding.per_batch_shard``, as the
+serving executor does); every kernel here is row-local.
 """
 
 from __future__ import annotations
@@ -24,7 +30,8 @@ from repro.core.lagrange import lagrange_weights
 Array = jax.Array
 
 
-def _interpret() -> bool:
+def interpret_mode() -> bool:
+    """True off TPU, where the Pallas kernels run in interpret mode."""
     return jax.default_backend() != "tpu"
 
 
@@ -60,7 +67,7 @@ def flash_attention(
     b, sq, h, hd = q.shape
     sk, kvh = k.shape[1], k.shape[2]
     bq = min(block_q, max(8, 1 << (sq - 1).bit_length()))
-    bk = min(block_k, max(128, 0) if sk >= 128 else 128)
+    bk = min(block_k, 128)
     # kernel layout (B, H, S, hd)
     qt = _pad_to(_pad_to(q.transpose(0, 2, 1, 3), 128, 3), bq, 2)
     kt = _pad_to(_pad_to(k.transpose(0, 2, 1, 3), 128, 3), bk, 2)
@@ -76,7 +83,7 @@ def flash_attention(
         qt, kt, vt, qp, kp,
         window=window, causal=causal, softcap=softcap, protected=protected,
         scale=hd**-0.5, block_q=bq, block_k=bk,
-        interpret=_interpret(), kv_mask=km,
+        interpret=interpret_mode(), kv_mask=km,
     )
     return out[:, :, :sq, :hd].transpose(0, 2, 1, 3)
 
@@ -112,7 +119,7 @@ def decode_attention(
     out = _dec.decode_attention(
         qt, kt, vt, q_pos, kp,
         window=window, protected=protected, scale=hd**-0.5,
-        block_k=block_k, interpret=_interpret(),
+        block_k=block_k, interpret=interpret_mode(),
     )
     out = out.reshape(b, kvh, gp, -1)[:, :, :g, :hd].reshape(b, h, hd)
     return out[:, None] if squeeze else out
@@ -141,16 +148,20 @@ def era_step(
     xf = _pad_to(x.reshape(-1), block, 0)
     es = _pad_to(eps_sel.reshape(eps_sel.shape[0], -1), block, 1)
     eh = _pad_to(e_hist.reshape(3, -1), block, 1)
-    x_next, eps_bar = _era.era_update(
-        xf, es, lag_w, eh, am4, cx, ce, block=block, interpret=_interpret()
+    # barriers: in interpret mode the kernel is XLA ops, and fusing the pad
+    # or the slice into them rounds differently at different sample sizes,
+    # which would break the bitwise padding contract
+    operands = jax.lax.optimization_barrier((xf, es, lag_w, eh, am4, cx, ce))
+    x_next, eps_bar = jax.lax.optimization_barrier(
+        _era.era_update(*operands, block=block, interpret=interpret_mode())
     )
     return x_next[:n].reshape(shape), eps_bar[:n].reshape(shape)
 
 
 def era_combine(eps_sel, t_sel, e_hist, t_next, am4=None):
     """Drop-in for repro.core.era.era_combine backed by the fused kernel
-    (combine only — the DDIM x-update stays outside; used when the solver
-    requested use_fused_update but the caller manages x itself)."""
+    (combine only — the DDIM x-update stays outside, for callers that
+    manage x themselves)."""
     from repro.core.era import AM4
 
     am4 = jnp.asarray(AM4 if am4 is None else am4, jnp.float32)
@@ -169,13 +180,9 @@ def fused_step_parity(
     seed: int = 0,
 ) -> float:
     """Max abs error of the fused `era_step` vs the reference combine + DDIM
-    update on a random probe — the numerics gate for the fused default path
-    (runs in interpret mode off-TPU).  Returns the error; callers decide the
-    tolerance (1e-5 is comfortable in f32).
-
-    Must run eagerly: it executes the kernel and converts the error to a
-    Python float, neither of which works under an ambient jit trace (the
-    gate in ``core.era._fused_ops`` guards that case)."""
+    update on a random input (interpret mode off-TPU, compiled on TPU).
+    Returns the error; callers decide the tolerance (1e-5 is comfortable in
+    f32).  Runs eagerly: it converts the error to a Python float."""
     from repro.core.era import AM4, era_combine
 
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)

@@ -23,9 +23,10 @@ docs/serving.md) over the same engine and scheduler; once the socket is
 bound it prints the machine-parsable ready line ``FRONTDOOR READY <url>``
 (``--port 0`` binds an ephemeral port) and serves until interrupted.  The
 AOT warmup grid compiles on a background thread behind ``/readyz``
-(``--no-warm`` to skip; ``--compile-cache-dir`` turns redeploy warmups
-into disk loads).  ``--connect URL`` is the matching wire client: it
-needs no model or params, just the server's URL.
+(``--no-warm`` to skip; ``--compile-cache`` turns redeploy warmups into
+disk loads from ``$JAX_COMPILATION_CACHE_DIR``, or the checkout's
+``.jax_cache`` when that is unset).  ``--connect URL`` is the matching
+wire client: it needs no model or params, just the server's URL.
 
 Every diffusion mode builds its engine through
 :func:`repro.serving.build_engine` — the one-shot facade, the continuous
@@ -100,7 +101,7 @@ def _engine_config(
             else None
         ),
         warmup_seq_lens=warmup_seq_lens if fused else None,
-        compile_cache_dir=args.compile_cache_dir,
+        compile_cache=args.compile_cache,
     )
 
 
@@ -305,11 +306,10 @@ def main() -> None:
         "(default: --nfe only)",
     )
     ap.add_argument(
-        "--compile-cache-dir",
-        default=None,
-        metavar="DIR",
-        help="persistent XLA compilation cache directory "
-        "(jax_compilation_cache_dir): warmup on a redeployed replica "
+        "--compile-cache",
+        action="store_true",
+        help="persistent XLA compilation cache in $JAX_COMPILATION_CACHE_DIR "
+        "(else the checkout's .jax_cache): warmup on a redeployed replica "
         "loads yesterday's programs from disk instead of recompiling",
     )
     ap.add_argument("--requests", type=int, default=16)

@@ -5,8 +5,9 @@
   This is the dry-run / long-context path: memory is O(Sq * chunk) and the
   FLOPs are what a TPU flash kernel would do, so ``cost_analysis`` stays
   honest on CPU where a Pallas TPU kernel cannot compile.
-* ``pallas``  — the TPU-target flash kernels in :mod:`repro.kernels`
-  (validated in interpret mode on CPU; the deployment fast path).
+* ``pallas``  — the Pallas flash kernel in :mod:`repro.kernels` (compiled
+  on TPU, interpret mode elsewhere).  ``auto`` resolves to it on TPU
+  (:func:`resolve_impl`).
 
 Masking is positional: every key slot carries an absolute position (-1 for
 invalid ring-buffer slots), so full causal, sliding-window, and ring-buffer
@@ -15,6 +16,7 @@ decode all share one code path.
 
 from __future__ import annotations
 
+import functools
 import warnings
 from typing import Callable
 
@@ -279,7 +281,7 @@ def sdpa(
     """``kv_mask`` is an optional (B, Sk) per-row key-validity mask — the
     mixed-seq-len serving path marks right-padding pad positions invalid so
     they get zero attention weight.  Every impl takes it natively: the
-    Pallas flash kernel carries it as a BlockSpec operand and the banded
+    Pallas flash kernel folds it into per-row key positions and the banded
     fast path slices it along the band, so masked mixed-length batches run
     the same fast kernels as unmasked ones.  The only remaining rewrite is
     an explicitly requested ``banded`` whose layout preconditions (causal,
@@ -318,14 +320,47 @@ def sdpa(
             kv_mask=kv_mask,
         )
     if impl == "pallas":
-        from repro.kernels import ops as kops
-
-        return kops.flash_attention(
-            q, k, v, q_pos, kv_pos,
-            window=window, causal=causal, softcap=softcap,
-            protected=protected, kv_mask=kv_mask,
-        )
+        opts = (window, causal, softcap, protected, min(chunk, max(sk, 128)))
+        return _pallas_sdpa(q, k, v, q_pos, kv_pos, kv_mask, opts)
     raise ValueError(f"unknown attention impl {impl!r}")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _pallas_sdpa(q, k, v, q_pos, kv_pos, kv_mask, opts):
+    """The Pallas flash kernel.  It has no backward pass of its own, so its
+    gradient is the chunked XLA path's, which computes the same function:
+    ``auto`` picks this kernel on TPU for training as well as sampling."""
+    from repro.kernels import ops as kops
+
+    window, causal, softcap, protected, _ = opts
+    return kops.flash_attention(
+        q, k, v, q_pos, kv_pos,
+        window=window, causal=causal, softcap=softcap,
+        protected=protected, kv_mask=kv_mask,
+    )
+
+
+def _pallas_sdpa_fwd(q, k, v, q_pos, kv_pos, kv_mask, opts):
+    out = _pallas_sdpa(q, k, v, q_pos, kv_pos, kv_mask, opts)
+    return out, (q, k, v, q_pos, kv_pos, kv_mask)
+
+
+def _pallas_sdpa_bwd(opts, res, g):
+    q, k, v, q_pos, kv_pos, kv_mask = res
+    window, causal, softcap, protected, chunk = opts
+    _, vjp = jax.vjp(
+        lambda q, k, v: _chunked_sdpa(
+            q, k, v, q_pos, kv_pos,
+            window=window, causal=causal, softcap=softcap, chunk=chunk,
+            protected=protected, kv_mask=kv_mask,
+        ),
+        q, k, v,
+    )
+    # positions and the mask are not differentiable
+    return (*vjp(g), None, None, None)
+
+
+_pallas_sdpa.defvjp(_pallas_sdpa_fwd, _pallas_sdpa_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -528,7 +563,7 @@ def attention(
         out = sdpa(
             q, ek, ev, q_pos, kv_pos,
             window=0, causal=False, softcap=cfg.attn_logit_softcap,
-            impl=_resolve_impl(cfg, s, ek.shape[1]), chunk=cfg.attn_chunk,
+            impl=resolve_impl(cfg, s, ek.shape[1]), chunk=cfg.attn_chunk,
         )
         return L.linear(p["wo"], out.reshape(b, s, h * hd)), cache
 
@@ -553,7 +588,7 @@ def attention(
         out = sdpa(
             q, k_all, v_all, positions, kv_pos,
             window=window, causal=True, softcap=cfg.attn_logit_softcap,
-            impl=_resolve_impl(cfg, 1, k_all.shape[1]), chunk=cfg.attn_chunk,
+            impl=resolve_impl(cfg, 1, k_all.shape[1]), chunk=cfg.attn_chunk,
             protected=protected,
         )
     else:
@@ -567,14 +602,21 @@ def attention(
         out = sdpa(
             q, k, v, positions, positions,
             window=window, causal=causal, softcap=cfg.attn_logit_softcap,
-            impl=_resolve_impl(cfg, s, s), chunk=cfg.attn_chunk,
+            impl=resolve_impl(cfg, s, s), chunk=cfg.attn_chunk,
             protected=protected, kv_mask=kv_mask,
         )
 
     return L.linear(p["wo"], out.reshape(b, s, h * hd)), new_cache
 
 
-def _resolve_impl(cfg, sq: int, sk: int) -> str:
+def resolve_impl(cfg, sq: int, sk: int) -> str:
+    """The SDPA impl a layer runs: ``cfg.attention_impl`` unless it is
+    ``auto``, which picks by platform.  On TPU every multi-query call runs
+    the Pallas flash kernel (it pads any head_dim and sequence length to its
+    blocks); single-token decode and every other platform run XLA (naive
+    for short sequences, chunked above)."""
     if cfg.attention_impl != "auto":
         return cfg.attention_impl
+    if sq > 1 and jax.default_backend() == "tpu":
+        return "pallas"
     return "naive" if sq * sk <= 1024 * 2048 else "chunked"

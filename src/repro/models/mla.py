@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import layers as L
-from repro.models.attention import sdpa
+from repro.models.attention import resolve_impl, sdpa
 
 Array = jax.Array
 
@@ -88,7 +88,7 @@ def mla_train(p, x: Array, cfg, mode: str = "train", cache=None, lengths=None):
         q, k, jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, q.shape[-1] - v.shape[-1]))),
         positions, positions,
         window=0, causal=True, softcap=0.0,
-        impl="naive" if s * s <= 1024 * 2048 else "chunked",
+        impl=resolve_impl(cfg, s, s),
         chunk=cfg.attn_chunk, kv_mask=kv_mask,
     )[..., : a.v_head_dim]
 

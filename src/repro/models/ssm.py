@@ -21,7 +21,10 @@ sums; (2) chunk boundaries inside the prefix coincide between the exact
 and padded runs (``chunk = min(chunk, s)`` either yields the same chunking
 over the prefix, or both runs put the whole prefix in their first chunk),
 and masked/pad slots contribute exact ``+0.0`` terms to the fixed-shape
-contractions.  ``tests/test_prefix_safety.py`` walls this per block kind;
+contractions.  mLSTM contracts over the whole chunk, so its chunk length is
+fixed (a short sequence pads up to it) instead of ``min(chunk, s)``: a sum
+over 5 terms and one over 9 with 4 exact zeros can round differently.
+``tests/test_prefix_safety.py`` walls this per block kind;
 it is what lets SSM kinds join ``MASKABLE_BLOCKS`` in
 :mod:`repro.models.diffusion`.
 """
@@ -237,7 +240,10 @@ def mlstm_chunkwise(q, k, v, i_pre, logf, state, chunk: int):
     never materialized along the sequence.
     """
     b, s, nh, hd = q.shape
-    chunk = max(min(chunk, s), 1)
+    # the chunk length never follows s: a short sequence pads up to it, so
+    # a prefix's intra-chunk sums have the same length (hence the same
+    # float association) however far the sequence is right-padded
+    chunk = max(chunk, 1)
     nchunks = -(-s // chunk)
     pad = nchunks * chunk - s
     if pad:
@@ -258,7 +264,7 @@ def mlstm_chunkwise(q, k, v, i_pre, logf, state, chunk: int):
 
     def body(carry, xs):
         c0, n0, m0 = carry                       # stabilized: C = c0 e^{m0}
-        qj, kj, vj, ij, fj = xs                  # (B,L,nh,*)
+        qj, kj, vj, ij, fj, rj = xs              # (B,L,nh,*); rj (L,) real
         cum = jnp.cumsum(fj, axis=1)             # (B,L,nh): sum_{u<=j} logf_u
         # running max of (logi_i - cum_i) over i<=j
         g = jax.lax.associative_scan(jnp.maximum, ij - cum, axis=1)
@@ -285,6 +291,9 @@ def mlstm_chunkwise(q, k, v, i_pre, logf, state, chunk: int):
         den = jnp.maximum(
             jnp.abs(jnp.einsum("blnd,blnd->bln", n_all, qj)), jnp.exp(-m_all)
         )
+        # pad rows (zero queries) are dropped, but a tiny den there would
+        # put 0/0 into the gradient of the division
+        den = jnp.where(rj[None, :, None], den, 1.0)
         h = num / den[..., None]
         # carry update (stabilized at m_last)
         m_last = m_all[:, -1]
@@ -297,8 +306,9 @@ def mlstm_chunkwise(q, k, v, i_pre, logf, state, chunk: int):
         n_new = n0 * wc[..., None] + jnp.einsum("blnd,bln->bnd", kj, wi)
         return (c_new, n_new, m_last), h
 
+    real = (jnp.arange(nchunks * chunk) < s).reshape(nchunks, chunk)
     (c, n, m), hs = jax.lax.scan(
-        body, (state["c"], state["n"], state["m"]), (qc, kc, vc, ic, fc)
+        body, (state["c"], state["n"], state["m"]), (qc, kc, vc, ic, fc, real)
     )
     h = jnp.moveaxis(hs, 0, 1).reshape(b, nchunks * chunk, nh, hd)[:, :s]
     return h, {"c": c, "n": n, "m": m}

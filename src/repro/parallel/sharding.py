@@ -180,6 +180,27 @@ def solver_carry_shardings(
     )
 
 
+def per_batch_shard(rows: NamedSharding, fn, *args, batch_dims):
+    """``fn(*args)`` run once per shard of the batch under ``shard_map``.
+
+    ``rows`` is a batch-leading sharding (a carry's ``x``): its first spec
+    entry names the mesh axes the batch is split over, or None when the
+    batch is replicated.  ``batch_dims[i]`` is the batch axis of
+    ``args[i]``, None for an argument every shard sees whole (any pytree).
+    Every output is batch-leading.  XLA cannot partition a Mosaic kernel,
+    so a mesh program that runs Pallas kernels runs them this way; ``fn``
+    must be row-local."""
+    axes = rows.spec[0] if len(rows.spec) else None
+    in_specs = tuple(
+        P() if d is None else P(*([None] * d), axes) for d in batch_dims
+    )
+    # check_vma off: pallas_call's out_shape carries no varying-axes type
+    return jax.shard_map(
+        fn, mesh=rows.mesh, in_specs=in_specs, out_specs=P(axes),
+        check_vma=False,
+    )(*args)
+
+
 class ParamReplicator:
     """Replicate a params tree over a mesh, caching the placed copy.
 

@@ -5,12 +5,12 @@ from repro.parallel.sharding import (
     sampler_shardings,
 )
 from repro.serving import result_keys
-from repro.serving.compile_cache import configure_persistent_cache, disk_cache_hits
-from repro.serving.diffusion_sampler import (
-    BatchedSampler,
-    SamplerService,
-    fused_path_ok,
+from repro.serving.compile_cache import (
+    cache_dir,
+    configure_persistent_cache,
+    disk_cache_hits,
 )
+from repro.serving.diffusion_sampler import BatchedSampler, SamplerService
 from repro.serving.engine import Engine, ServeConfig, cache_slots, resolve_window
 from repro.serving.executor import (
     DEFAULT_MAX_BATCH,
@@ -76,6 +76,7 @@ __all__ = [
     "ServeConfig",
     "WARMUP_MODES",
     "build_engine",
+    "cache_dir",
     "cache_slots",
     "configure_persistent_cache",
     "decode_request",
@@ -83,7 +84,6 @@ __all__ = [
     "disk_cache_hits",
     "encode_request",
     "encode_result",
-    "fused_path_ok",
     "make_solver_config",
     "open_loop",
     "resolve_window",

@@ -33,10 +33,9 @@ Architecture:
   paper's shared scalar delta_eps couple the batch, so the engine serves
   them one exact-size request at a time instead of fusing (and, on a mesh,
   only at dp-multiple batches — exact-size runs cannot round up).
-* The fused Pallas step is the default path; core gates it with a one-time
-  per-backend numerics parity probe (``era._fused_ops`` /
-  ``kernels.ops.fused_step_parity``) and falls back to the pure-jnp combine
-  if the kernel misbehaves — ``fused_path_ok()`` reports the outcome.
+* ERA's step runs the fused Pallas kernel, compiled on TPU and in
+  interpret mode elsewhere (``kernels.ops.interpret_mode``); there is no
+  runtime probe and no fallback path.
 * Mesh mode (``mesh=`` a ``jax.sharding.Mesh``): the engine batch-shards the
   latents and Lagrange eps buffer over the mesh's data axes
   (``parallel.sharding.sampler_shardings``) and replicates the denoiser
@@ -59,7 +58,6 @@ import jax
 from jax.sharding import Mesh
 
 from repro.core import NoiseSchedule, SolverConfig, get_program
-from repro.core import era as era_mod
 from repro.models.diffusion import DiffusionLM
 from repro.serving.executor import (
     DEFAULT_MAX_BATCH,
@@ -74,13 +72,6 @@ from repro.serving.executor import (
 from repro.serving.metrics import MetricsRegistry
 
 Array = jax.Array
-
-def fused_path_ok() -> bool:
-    """Is the fused Pallas step active on this backend?  (The parity gate
-    itself lives in core — `era._fused_ops` — so every ERA entry point is
-    covered; this is the serving-side introspection hook.)"""
-    return era_mod._fused_ops() is not None
-
 
 class BatchedSampler:
     """Request-batching diffusion sampling engine (submit/drain).

@@ -95,6 +95,7 @@ from repro.models.diffusion import DiffusionLM
 from repro.parallel.sharding import (
     ParamReplicator,
     dp_size,
+    per_batch_shard,
     round_to_dp,
 )
 from repro.serving import result_keys as K
@@ -905,16 +906,9 @@ class FusedExecutor:
         solver, cfg, batch, seq_len, _, masked, stepped = key
         program = self.program_for(solver)
         shardings = self._shardings(program, cfg, batch)
-        # eager pre-compile hook: probes that cannot run inside the jit
-        # trace below (ERA's fused-kernel parity gate)
-        program.pre_compile(cfg)
 
         def run(params, x_init, lengths, steps, *buffers):
-            eps_fn = (
-                self.dlm.eps_fn(params)
-                if lengths is None
-                else self.dlm.eps_fn(params, lengths=lengths)
-            )
+            eps_fn = self._eps_fn(params, lengths, shardings)
             out = program.sample_scan(
                 eps_fn,
                 x_init,
@@ -957,6 +951,29 @@ class FusedExecutor:
         self._m_compile_programs.inc(solver=solver, source=source)
         self._m_compile_wall.observe(wall, solver=solver, source=source)
         return compiled, source
+
+    def _eps_fn(self, params, lengths, shardings):
+        """The denoiser as the program calls it, ``eps_fn(x, t)``.  On a
+        mesh it runs per batch shard (``per_batch_shard``): XLA cannot
+        partition its Pallas kernels, and every op in it is row-local."""
+        if shardings is None:
+            if lengths is None:
+                return self.dlm.eps_fn(params)
+            return self.dlm.eps_fn(params, lengths=lengths)
+
+        def rows(p, x, t, *ln):
+            return self.dlm.eps_fn(p, *ln)(x, t)
+
+        def eps_fn(x, t):
+            # t is shared by the batch, or one time per row
+            t_dim = 0 if jnp.ndim(t) and jnp.shape(t)[0] == x.shape[0] else None
+            ln = () if lengths is None else (lengths,)
+            return per_batch_shard(
+                shardings.x, rows, params, x, t, *ln,
+                batch_dims=(None, 0, t_dim) + (0,) * len(ln),
+            )
+
+        return eps_fn
 
     def _abstract_inputs(
         self, program, cfg, batch, seq_len, masked, stepped, params, shardings

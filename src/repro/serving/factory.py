@@ -73,12 +73,11 @@ class EngineConfig:
       grid beyond the defaults (the config's ``nfe``; the seq-bucket
       ladder, or — for exact-seq-len traffic — the lengths callers expect
       to serve).
-    * ``compile_cache_dir`` — persistent XLA compilation cache directory
-      (``jax_compilation_cache_dir``, process-global): a redeployed
-      replica's warmup becomes disk loads instead of fresh compiles.  The
-      ``compile_cache_*`` thresholds mirror the ``jax_persistent_cache_*``
-      flags but default to persisting everything — see
-      :func:`~repro.serving.compile_cache.configure_persistent_cache`.
+    * ``compile_cache`` — turn on the persistent XLA compilation cache
+      (process-global): a redeployed replica's warmup becomes disk loads
+      instead of fresh compiles.  Where it lives is not an engine option:
+      ``$JAX_COMPILATION_CACHE_DIR`` when set, else the fixed in-checkout
+      ``.jax_cache`` (:func:`~repro.serving.compile_cache.cache_dir`).
     """
 
     solver: str = "era"
@@ -95,9 +94,7 @@ class EngineConfig:
     warmup: str = "none"
     warmup_nfes: tuple[int, ...] | None = None
     warmup_seq_lens: tuple[int, ...] | None = None
-    compile_cache_dir: str | None = None
-    compile_cache_min_entry_bytes: int = -1
-    compile_cache_min_compile_secs: float = 0.0
+    compile_cache: bool = False
 
 
 def make_solver_config(cfg: EngineConfig) -> SolverConfig:
@@ -124,7 +121,7 @@ def build_engine(
     they ride alongside the config rather than inside it (a mesh is not
     hashable; a registry is per-process state).
 
-    ``cfg.compile_cache_dir`` is applied here (process-global jax config);
+    ``cfg.compile_cache`` is applied here (process-global jax config);
     ``cfg.warmup`` is *policy*, not an action — building an engine never
     compiles.  Callers run the warmup themselves once params are in hand:
     ``engine.warmup(params, **warmup_kwargs(cfg))`` (or hand the kwargs to
@@ -136,12 +133,8 @@ def build_engine(
             f"EngineConfig.warmup must be one of {WARMUP_MODES}, "
             f"got {cfg.warmup!r}"
         )
-    if cfg.compile_cache_dir:
-        configure_persistent_cache(
-            cfg.compile_cache_dir,
-            min_entry_size_bytes=cfg.compile_cache_min_entry_bytes,
-            min_compile_time_secs=cfg.compile_cache_min_compile_secs,
-        )
+    if cfg.compile_cache:
+        configure_persistent_cache()
     return BatchedSampler(
         dlm,
         schedule,

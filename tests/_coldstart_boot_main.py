@@ -1,15 +1,15 @@
 """Subprocess child for the persistent compile-cache round-trip test.
 
 One replica boot: build the Oracle engine through ``build_engine`` with
-``warmup="grid"`` and the shared ``compile_cache_dir`` from argv, run the
-grid warmup, serve one request, and print a JSON record of the warmup
-report / compile-source counters / an x0 checksum.  The parent runs this
-twice against the same cache dir and asserts the second boot's warmup
-came from disk, with bit-identical sampling output.
+``warmup="grid"`` and the persistent compile cache on (placed by the
+parent through ``JAX_COMPILATION_CACHE_DIR``), run the grid warmup, serve
+one request, and print a JSON record of the warmup report /
+compile-source counters / an x0 checksum.  The parent runs this twice
+against the same cache dir and asserts the second boot's warmup came from
+disk, with bit-identical sampling output.
 """
 
 import json
-import sys
 
 # sys.path[0] is this script's dir (tests/), so conftest resolves; the
 # parent provides src/ on PYTHONPATH
@@ -24,7 +24,6 @@ from repro.serving import (
 
 
 def main() -> None:
-    cache_dir = sys.argv[1]
     analytic = AnalyticGaussian()
     cfg = EngineConfig(
         nfe=6,
@@ -32,7 +31,7 @@ def main() -> None:
         batch_buckets=(1, 2),
         seq_buckets=(4, 8),
         warmup="grid",
-        compile_cache_dir=cache_dir,
+        compile_cache=True,
     )
     engine = build_engine(OracleDenoiser(analytic), analytic.schedule, cfg)
     report = engine.warmup(None, **warmup_kwargs(cfg))

@@ -13,9 +13,8 @@ Per-sample ERS is what makes this hold (each row's delta_eps measurement
 and Lagrange base selection read only its own row), and this property is
 what makes continuous batching correctness-preserving at all: scheduler
 timing must never leak into results.  Randomized over seq_len / nfe / seeds
-/ arrival delays via `tests/_hypothesis_compat.py` (real hypothesis in CI,
-the deterministic shim in bare environments), and re-checked on the
-8-virtual-device mesh fixture.
+/ arrival delays with hypothesis, and re-checked on the 8-virtual-device
+mesh fixture.
 
 PR-4 extends the wall to **mixed-solver streams**: requests routed to
 different registry solvers (`era` / `ddim` / `dpm_solver_pp2m`) interleave
@@ -45,7 +44,7 @@ import time
 
 import numpy as np
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from conftest import AnalyticGaussian, OracleDenoiser
 from repro.core import ERAConfig
 from repro.serving import (

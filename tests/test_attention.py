@@ -1,13 +1,14 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.models.attention import (
     _chunked_sdpa,
     _naive_sdpa,
     cache_insert,
     init_cache,
+    resolve_impl,
     sdpa,
 )
 
@@ -271,3 +272,59 @@ def test_masked_fast_paths_do_not_fire_fallback():
     finally:
         A.unregister_fallback_observer(obs)
     assert events == []
+
+
+def test_auto_attention_picks_by_platform(monkeypatch):
+    """``auto`` resolves from the platform: XLA SDPA off TPU, the Pallas
+    flash kernel for every multi-query call on TPU, XLA for single-token
+    decode; an explicit impl is never rewritten."""
+    from repro.configs import get_config
+
+    cfg = get_config("qwen2-1.5b", smoke=True)
+    assert resolve_impl(cfg, 8, 8) == "naive"
+    assert resolve_impl(cfg, 4096, 4096) == "chunked"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_impl(cfg, 512, 512) == "pallas"
+    assert resolve_impl(cfg, 8, 8) == "pallas"
+    assert resolve_impl(cfg, 1, 512) == "naive"
+    assert resolve_impl(cfg.with_(attention_impl="chunked"), 512, 512) == (
+        "chunked"
+    )
+
+
+def test_auto_attention_trains_through_the_flash_kernel_on_tpu(monkeypatch):
+    """On TPU ``auto`` runs the Pallas flash kernel in training too.  The
+    kernel has no backward pass of its own; its gradient comes from the
+    chunked path and matches XLA attention's gradient of the same loss."""
+    from repro.configs import get_config
+    from repro.core import linear_schedule
+    from repro.kernels import ops
+    from repro.models import build_model
+    from repro.models.diffusion import DiffusionLM
+
+    cfg = get_config("qwen2-1.5b", smoke=True)
+    dlm = DiffusionLM(build_model(cfg))
+    params = dlm.init(jax.random.PRNGKey(0))
+    # a non-zero eps head, so the loss reaches the attention layers
+    params["eps_head"]["w"] = 0.1 * _rand(1, *params["eps_head"]["w"].shape)
+    batch = {"latents": _rand(2, 2, 16, cfg.d_model)}
+    loss = lambda p: dlm.loss(
+        p, batch, jax.random.PRNGKey(3), linear_schedule()
+    )[0]
+    g_xla = jax.grad(loss)(params)
+
+    calls = []
+    flash = ops.flash_attention
+
+    def counted(*a, **kw):
+        calls.append(1)
+        return flash(*a, **kw)
+
+    # the platform says TPU; with no chip here the kernel still interprets
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ops, "interpret_mode", lambda: True)
+    monkeypatch.setattr(ops, "flash_attention", counted)
+    g_tpu = jax.grad(loss)(params)
+    assert calls, "auto did not run the Pallas flash kernel"
+    for a, b in zip(jax.tree.leaves(g_tpu), jax.tree.leaves(g_xla)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)

@@ -1,4 +1,4 @@
-"""Batched sampling engine: fused-step parity (+ broken-kernel fallback),
+"""Batched sampling engine: fused-step parity, platform kernel selection,
 compile-once-per-bucket, batch-of-N == N-independent-runs equivalence
 (per-sample ERS on), padding invariance, and mesh-sharded drain parity."""
 
@@ -11,9 +11,8 @@ import pytest
 
 from conftest import OracleDenoiser, run_mesh_subprocess
 from repro.core import ERAConfig, get_solver
-from repro.core import era as era_mod
 from repro.kernels import ops
-from repro.serving import BatchedSampler, SampleRequest, fused_path_ok
+from repro.serving import BatchedSampler, SampleRequest
 
 D_MODEL = OracleDenoiser.D_MODEL
 
@@ -37,79 +36,27 @@ def test_fused_step_parity_within_1e5():
             assert err <= 1e-5, (shape, k, err)
 
 
-def test_fused_path_ok_gate():
-    assert fused_path_ok()
+def test_cpu_runs_the_kernel_in_interpret_mode_without_fallback(analytic):
+    """The platform picks the kernel, not a probe: off TPU the ERA step
+    traces to the Pallas kernel (interpret mode) on both the shared and the
+    per-sample path, and only the explicit reference config leaves it."""
+    assert jax.default_backend() == "cpu" and ops.interpret_mode()
+    x = jnp.zeros((2, 6, D_MODEL), jnp.float32)
 
+    def jaxpr(cfg):
+        return str(
+            jax.make_jaxpr(
+                lambda x: get_solver("era")(
+                    analytic.eps, x, analytic.schedule, cfg
+                ).x0
+            )(x)
+        )
 
-def test_parity_gate_active_in_float32():
-    """The gate is actually on for this backend: the f32 parity probe is
-    within tolerance and core resolves the fused ops module (not the jnp
-    fallback)."""
-    assert ops.fused_step_parity() <= era_mod._FUSED_TOL
-    backend = jax.default_backend()
-    assert era_mod._fused_ops() is not None
-    assert era_mod._FUSED_OK[backend] is True
-
-
-def test_gate_first_consulted_inside_jit_trace_is_not_poisoned(monkeypatch):
-    """The probe cannot execute under an ambient jit trace; a fresh process
-    whose first gate consultation happens mid-trace must defer (jnp path
-    for that trace) WITHOUT caching a failure, so the next eager check
-    still enables the kernel.  Regression: this used to cache False and
-    silently disable the fused path process-wide."""
-    monkeypatch.setattr(era_mod, "_FUSED_OK", {})  # fresh-process cache
-
-    @jax.jit
-    def traced(z):
-        assert era_mod._fused_ops() is None  # deferred, not probed
-        return z
-
-    traced(jnp.zeros(()))
-    assert jax.default_backend() not in era_mod._FUSED_OK  # unpoisoned
-    assert fused_path_ok()  # eager probe now enables the kernel
-
-
-def test_engine_enables_fused_path_from_fresh_process(monkeypatch, analytic):
-    """The engine's jitted-bucket path probes the gate eagerly before
-    tracing, so a process that only ever serves compiled drains still gets
-    the fused kernel."""
-    monkeypatch.setattr(era_mod, "_FUSED_OK", {})
-    eng = BatchedSampler(
-        OracleDenoiser(analytic), analytic.schedule, batch_buckets=(2,)
-    )
-    eng.submit(SampleRequest(batch=1, seq_len=6, nfe=6, seed=0))
-    eng.drain(params=None)
-    assert era_mod._FUSED_OK[jax.default_backend()] is True
-
-
-def test_broken_kernel_silently_falls_back_to_jnp(monkeypatch, analytic):
-    """A kernel that fails the parity probe must degrade to the pure-jnp
-    combine — same samples as use_fused_update=False, never garbage — and
-    report fused_path_ok() is False."""
-
-    def broken_era_step(x, eps_sel, t_sel, e_hist, t_next, cx, ce, am4, **kw):
-        return x + 1e3, eps_sel[0] + 1e3
-
-    monkeypatch.setattr(ops, "era_step", broken_era_step)
-    monkeypatch.setattr(era_mod, "_FUSED_OK", {})  # force a fresh probe
-    assert fused_path_ok() is False
-
-    cfg = ERAConfig(nfe=8, per_sample=True)
-    x = jax.random.normal(jax.random.PRNGKey(2), (2, 6, D_MODEL), jnp.float32)
-    out = get_solver("era")(analytic.eps, x, analytic.schedule, cfg)
-    assert not bool(jnp.any(jnp.isnan(out.x0)))
-    ref = get_solver("era")(
-        analytic.eps,
-        x,
-        analytic.schedule,
-        dataclasses.replace(cfg, use_fused_update=False),
-    )
-    np.testing.assert_array_equal(np.asarray(out.x0), np.asarray(ref.x0))
-
-
-def test_gate_recovers_after_restore(analytic):
-    """The monkeypatched probe above must not poison the session cache."""
-    assert fused_path_ok()
+    for per_sample in (False, True):
+        cfg = ERAConfig(nfe=6, per_sample=per_sample)
+        assert "pallas_call" in jaxpr(cfg)
+        reference = dataclasses.replace(cfg, use_fused_update=False)
+        assert "pallas_call" not in jaxpr(reference)
 
 
 # ---------------------------------------------------------------------------

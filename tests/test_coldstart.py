@@ -7,8 +7,8 @@ The wall these tests form around :meth:`BatchedSampler.warmup`:
 * warmup populates the full program grid with **zero** sampling — no
   ``run_chunk`` calls, no drained batches — and serving after it is pure
   memory hits with output bit-identical to a cold engine's;
-* a second process boot against the same ``compile_cache_dir`` loads its
-  programs from disk instead of compiling them;
+* a second process boot against the same ``JAX_COMPILATION_CACHE_DIR``
+  loads its programs from disk instead of compiling them;
 * the front door answers ``/readyz`` 503 (with progress) until warmup
   finishes, 200 after, and stays 503 with the error when warmup dies —
   while ``/healthz`` stays pure liveness throughout.
@@ -233,12 +233,12 @@ def _boot_subprocess(cache_dir, timeout=600):
     root = os.path.dirname(tests_dir)
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
     env["PYTHONPATH"] = os.path.join(root, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
     )
     proc = subprocess.run(
-        [sys.executable, os.path.join(tests_dir, "_coldstart_boot_main.py"),
-         str(cache_dir)],
+        [sys.executable, os.path.join(tests_dir, "_coldstart_boot_main.py")],
         capture_output=True, text=True, timeout=timeout, cwd=root, env=env,
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
@@ -263,8 +263,21 @@ def test_persistent_cache_round_trip_across_boots(tmp_path):
     assert second["x0_sum"] == first["x0_sum"]
 
 
+def test_cache_dir_is_env_else_fixed_checkout_path(monkeypatch, tmp_path):
+    """One rule places the persistent cache: the environment when it says,
+    else one fixed directory at the checkout root (never a per-run name)."""
+    from repro.serving import cache_dir
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cache_dir() == os.path.join(root, ".jax_cache")
+    assert cache_dir() == cache_dir()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert cache_dir() == str(tmp_path)
+
+
 def test_cache_configured_after_first_compile_still_takes_effect(
-    analytic, tmp_path
+    analytic, tmp_path, monkeypatch
 ):
     """Regression: jax latches its cache handle at the first compile of
     the process; configure_persistent_cache must un-latch it or a cache
@@ -279,7 +292,8 @@ def test_cache_configured_after_first_compile_still_takes_effect(
         return eng.warmup(None)
 
     boot()  # a compile before any cache dir exists (latches jax's handle)
-    configure_persistent_cache(str(tmp_path / "cache"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    assert configure_persistent_cache() == str(tmp_path / "cache")
     try:
         assert boot()["fresh"] == 1  # writes
         assert boot()["disk"] == 1  # reads

@@ -1,6 +1,6 @@
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.lagrange import (
     ers_select,

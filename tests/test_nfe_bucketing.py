@@ -26,7 +26,7 @@ and the info dict, and the mesh8 mixed-NFE drain matches.
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from conftest import AnalyticGaussian, OracleDenoiser
 from repro.core import ERAConfig, solver_names
 from repro.serving import (

@@ -4,15 +4,14 @@ The whole fused serving path rests on one claim: a batch-of-N ERA run with
 per-sample ERS equals N independent single-sample runs (paper Alg. 1 per
 row).  This is what makes request fusion, bucket padding, and mesh batch
 sharding all correctness-preserving.  Checked here over randomized
-seq_len / nfe / k / seed via `tests/_hypothesis_compat.py` (real hypothesis
-in CI, the deterministic fallback shim in bare environments).
+seq_len / nfe / k / seed with hypothesis.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from conftest import AnalyticGaussian
 from repro.core import ERAConfig, get_solver
 

@@ -22,7 +22,7 @@ exact-shape grouping, and the mesh8 mixed-length drain matches.
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from conftest import AnalyticGaussian, OracleDenoiser
 from repro.core import ERAConfig
 from repro.serving import (

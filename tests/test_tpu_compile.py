@@ -1,0 +1,168 @@
+"""Compile-only walls: the main path's Pallas kernels lower and compile for a
+described TPU v5e chip at qwen2-1.5b widths, with no chip attached.
+
+Nothing runs here; the TPU compiler (installed with libtpu) compiles for
+the described device and refuses what the chip would refuse: misaligned
+block shapes, illegal operand layouts, too much VMEM.  Each test asserts
+that the compiled program holds the Mosaic kernel (``tpu_custom_call``),
+so an interpret-mode or XLA fallback cannot pass for the kernel.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and the test workers all
+import this file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.era import AM4
+from repro.kernels import era_update as era_kernel
+from repro.kernels import flash_attention as flash_kernel
+
+# qwen2-1.5b attention and latent widths, serving batch 8 at seq 512
+B, H, KV, S, HD, D = 8, 12, 2, 512, 128, 1536
+K_ORDER = 4
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache off around these
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, *avals) -> str:
+    return jax.jit(fn).lower(*avals).compile().as_text()
+
+
+def _era_avals(one_chip, lead=()):
+    n = S * D
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    return (
+        sds(lead + (n,)),
+        sds(lead + (K_ORDER, n)),
+        sds(lead + (K_ORDER,)),
+        sds(lead + (3, n)),
+    )
+
+
+def _era_call(x, eps_sel, lag_w, e_hist):
+    am4 = jnp.asarray(AM4, jnp.float32)
+    return era_kernel.era_update(
+        x, eps_sel, lag_w, e_hist, am4, jnp.float32(0.9), jnp.float32(-0.1)
+    )
+
+
+def test_era_update_compiles_for_v5e(one_chip):
+    text = _compiled_text(_era_call, *_era_avals(one_chip))
+    assert "tpu_custom_call" in text
+
+
+def test_era_update_per_sample_vmap_compiles_for_v5e(one_chip):
+    """Per-sample ERS vmaps the kernel over the batch: each row carries its
+    own Lagrange weights, and the batching rule adds a grid axis."""
+    text = _compiled_text(jax.vmap(_era_call), *_era_avals(one_chip, (B,)))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize(
+    "masked, dtype",
+    [(False, jnp.bfloat16), (True, jnp.bfloat16), (True, jnp.float32)],
+    ids=["unmasked", "masked", "masked-f32"],
+)
+def test_flash_attention_compiles_for_v5e(one_chip, masked, dtype):
+    """bf16 is the served dtype; float32 is the chip check's twin engine."""
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    q = sds((B, H, S, HD), dtype)
+    kv = sds((B, KV, S, HD), dtype)
+    pos = sds((S,), jnp.int32)
+    mask = sds((B, S), jnp.int32)
+
+    def call(q, k, v, pos, mask=None):
+        return flash_kernel.flash_attention(
+            q, k, v, pos, pos, causal=False, kv_mask=mask
+        )
+
+    avals = (q, kv, kv, pos) + ((mask,) if masked else ())
+    assert "tpu_custom_call" in _compiled_text(call, *avals)
+
+
+def test_kernels_compile_per_batch_shard_on_a_v5e_mesh(topo, monkeypatch):
+    """On a mesh XLA cannot partition a Mosaic kernel; run per batch shard
+    (``per_batch_shard``, as the serving executor runs the denoiser and
+    ERA runs its step), the 4-chip program compiles with the kernels in it
+    and the batch rows spread over the chips."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.kernels import ops
+    from repro.parallel.sharding import per_batch_shard
+
+    # the wrappers ask the platform whether to interpret: answer as the
+    # chip would, since the compile below is for the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",), axis_types=(AxisType.Auto,))
+    rows = NamedSharding(mesh, P("data"))
+    rep = NamedSharding(mesh, P())
+    sds = lambda shape, dt, sh: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+
+    def step(q, k, v, pos, mask, x, eps_sel, t_sel, e_hist):
+        o = per_batch_shard(
+            rows,
+            lambda q, k, v, pos, mask: ops.flash_attention(
+                q, k, v, pos, pos, causal=False, kv_mask=mask
+            ),
+            q, k, v, pos, mask, batch_dims=(0, 0, 0, None, 0),
+        )
+        am4 = jnp.asarray(AM4, jnp.float32)
+        x_next, _ = per_batch_shard(
+            rows,
+            jax.vmap(
+                lambda xb, es, ts, eh: ops.era_step(
+                    xb, es, ts, eh, jnp.float32(0.1), jnp.float32(0.9),
+                    jnp.float32(-0.1), am4,
+                )
+            ),
+            x, eps_sel, t_sel, e_hist, batch_dims=(0, 0, 0, 0),
+        )
+        return o, x_next
+
+    avals = (
+        sds((B, S, H, HD), jnp.bfloat16, rows),
+        sds((B, S, KV, HD), jnp.bfloat16, rows),
+        sds((B, S, KV, HD), jnp.bfloat16, rows),
+        sds((S,), jnp.int32, rep),
+        sds((B, S), jnp.int32, rows),
+        sds((B, S, D), jnp.float32, rows),
+        sds((B, K_ORDER, S, D), jnp.float32, rows),
+        sds((B, K_ORDER), jnp.float32, rows),
+        sds((B, 3, S, D), jnp.float32, rows),
+    )
+    compiled = jax.jit(step).lower(*avals).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    x_rows = compiled.input_shardings[0][5].devices_indices_map((B, S, D))
+    assert sorted(idx[0].start for idx in x_rows.values()) == [0, 2, 4, 6]
