@@ -659,8 +659,6 @@ FRONTDOOR_LOADS = (2.0, 4.0)
 FRONTDOOR_REQUIRED_METRICS = (
     "sampler_queue_depth_rows",
     "sampler_fuse_occupancy_ratio",
-    "sampler_compile_cache_hits_total",
-    "sampler_compile_cache_misses_total",
     "sampler_compile_programs_total",
     "sampler_compile_seconds",
     "sampler_warmup_grid_programs",
@@ -672,6 +670,8 @@ FRONTDOOR_REQUIRED_METRICS = (
     "sampler_masked_fallback_total",
     "sampler_nfe_padding_rows_total",
     "sampler_request_latency_seconds",
+    "sampler_queue_wait_seconds",
+    "sampler_assembly_seconds",
     "frontdoor_http_requests_total",
 )
 

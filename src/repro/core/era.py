@@ -77,6 +77,13 @@ Array = jax.Array
 # Adams--Moulton order-4 corrector coefficients (paper Eq. 10/11).
 AM4 = (9.0 / 24.0, 19.0 / 24.0, -5.0 / 24.0, 1.0 / 24.0)
 
+# Name scopes of a step's device ops (op metadata only: the same ops and
+# fusions), so a device trace splits the solver's time from the denoiser's:
+# ERS (basis selection and the error norm) and the update (Lagrange
+# predictor, AM4 corrector, DDIM x-update, history append).
+ERS_SCOPE = "era.ers"
+UPDATE_SCOPE = "era.update"
+
 
 @dataclasses.dataclass(frozen=True)
 class ERAConfig(SolverConfig):
@@ -302,27 +309,45 @@ def sample_scan(
 
     def warm_branch(ops):
         x, eps_buf, t_buf, de, i, t_cur, t_next = ops
-        e_cur = jax.lax.dynamic_index_in_dim(eps_buf, i, 0, keepdims=False)
-        x_next = ddim_step(schedule, x, e_cur, t_cur, t_next)
+        with jax.named_scope(UPDATE_SCOPE):
+            e_cur = jax.lax.dynamic_index_in_dim(eps_buf, i, 0, keepdims=False)
+            x_next = ddim_step(schedule, x, e_cur, t_cur, t_next)
         # prediction placeholder: the DDIM-held noise; no selection yet
         return x_next, e_cur, jnp.zeros(tau_shape, jnp.int32)
 
     def main_branch(ops):
         x, eps_buf, t_buf, de, i, t_cur, t_next = ops
-        e_hist = jnp.stack(
-            [
-                jax.lax.dynamic_index_in_dim(eps_buf, i - j, 0, keepdims=False)
-                for j in range(3)
-            ]
-        )
-        if config.per_sample:
-            # beyond-paper: each batch element selects its own bases from
-            # its own measured error
-            tau = jax.vmap(
-                lambda d: lagrange.select_bases(
-                    i, k, d, config.lam, config.selection, config.const_power
+        with jax.named_scope(UPDATE_SCOPE):
+            e_hist = jnp.stack(
+                [
+                    jax.lax.dynamic_index_in_dim(
+                        eps_buf, i - j, 0, keepdims=False
+                    )
+                    for j in range(3)
+                ]
+            )
+        with jax.named_scope(ERS_SCOPE):
+            if config.per_sample:
+                # beyond-paper: each batch element selects its own bases
+                # from its own measured error
+                tau = jax.vmap(
+                    lambda d: lagrange.select_bases(
+                        i, k, d, config.lam, config.selection,
+                        config.const_power,
+                    )
+                )(de)                                        # (B, k)
+            else:
+                tau = lagrange.select_bases(
+                    i, k, de, config.lam, config.selection, config.const_power
                 )
-            )(de)                                            # (B, k)
+        with jax.named_scope(UPDATE_SCOPE):
+            x_next, eps_bar = update(x, eps_buf, t_buf, e_hist, t_cur, t_next, tau)
+        return x_next, eps_bar, tau
+
+    def update(x, eps_buf, t_buf, e_hist, t_cur, t_next, tau):
+        """Lagrange predictor, AM4 corrector and DDIM x-update on the
+        bases ``tau``: returns ``(x_next, eps_bar)``."""
+        if config.per_sample:
             if steps is None:
                 t_sel = jnp.take(t_buf, tau, axis=0)         # (B, k)
             else:
@@ -352,11 +377,10 @@ def sample_scan(
                 )
                 if steps is not None:
                     t_next, cx, ce = (a.reshape(-1) for a in (t_next, cx, ce))
-                x_next, eps_bar = per_shard(
+                return per_shard(
                     rows, x, eps_sel, t_sel, e_hist_b, t_next, cx, ce,
                     batch_dims=(0, 0, 0, 0, r, r, r),
                 )
-                return x_next, eps_bar, tau
             if steps is None:
                 eps_bar, eps_corr = jax.vmap(
                     era_combine, in_axes=(0, 0, 0, None)
@@ -365,26 +389,20 @@ def sample_scan(
                 eps_bar, eps_corr = jax.vmap(era_combine)(
                     eps_sel, t_sel, e_hist_b, t_next.reshape(-1)
                 )
-            x_next = ddim_step(schedule, x, eps_corr, t_cur, t_next)
-            return x_next, eps_bar, tau
-        tau = lagrange.select_bases(
-            i, k, de, config.lam, config.selection, config.const_power
-        )
+            return ddim_step(schedule, x, eps_corr, t_cur, t_next), eps_bar
         t_sel = jnp.take(t_buf, tau, axis=0)
         eps_sel = jnp.take(eps_buf, tau, axis=0)
         if kops is not None:
             # fused step: predictor combine + AM4 corrector + DDIM x-update
             # in one HBM pass
             cx, ce = schedule.ddim_coeffs(t_cur, t_next)
-            x_next, eps_bar = per_shard(
+            return per_shard(
                 lambda *a: kops.era_step(*a, am4),
                 x, eps_sel, t_sel, e_hist, t_next, cx, ce,
                 batch_dims=(0, 1, None, 1, None, None, None),
             )
-            return x_next, eps_bar, tau
         eps_bar, eps_corr = era_combine(eps_sel, t_sel, e_hist, t_next)
-        x_next = ddim_step(schedule, x, eps_corr, t_cur, t_next)
-        return x_next, eps_bar, tau
+        return ddim_step(schedule, x, eps_corr, t_cur, t_next), eps_bar
 
     def step(carry, inp):
         x, eps_buf, t_buf, de = carry
@@ -399,7 +417,8 @@ def sample_scan(
         )
         if steps is not None:
             # a spent row's latents freeze bitwise for the rest of the scan
-            x_next = jnp.where(step_active(steps, i, x.ndim), x_next, x)
+            with jax.named_scope(UPDATE_SCOPE):
+                x_next = jnp.where(step_active(steps, i, x.ndim), x_next, x)
 
         # Observe eps at the new point — except on the final step, whose
         # x_next is the output (keeps total cost at exactly `nfe` evals).
@@ -409,15 +428,20 @@ def sample_scan(
         # spares the bucket's terminal eval).
         def observe(_):
             e_new = eps_fn(x_next, t_next).astype(dt)
-            if config.per_sample:
-                de_new = _delta_eps_batch(e_new, eps_bar, valid)
-            else:
-                de_new = _delta_eps(e_new, eps_bar, config.error_norm, valid)
+            with jax.named_scope(ERS_SCOPE):
+                if config.per_sample:
+                    de_new = _delta_eps_batch(e_new, eps_bar, valid)
+                else:
+                    de_new = _delta_eps(
+                        e_new, eps_bar, config.error_norm, valid
+                    )
             if steps is not None:
                 obs = (i + 1) < steps.active_steps           # (B,)
-                e_new = jnp.where(
-                    obs.reshape((-1,) + (1,) * (e_new.ndim - 1)), e_new, 0.0
-                )
+                with jax.named_scope(UPDATE_SCOPE):
+                    e_new = jnp.where(
+                        obs.reshape((-1,) + (1,) * (e_new.ndim - 1)),
+                        e_new, 0.0,
+                    )
                 de_new = jnp.where(obs, de_new, de)
             return e_new, de_new
 
@@ -427,10 +451,11 @@ def sample_scan(
         e_new, de_new = jax.lax.cond(i + 1 < n, observe, skip, None)
         # Alg. 1 line 16: delta_eps only updates once predictions are real.
         de = jnp.where(i >= k - 1, de_new, de)
-        eps_buf, t_buf = buffer_append(
-            eps_buf, t_buf, i + 1, e_new,
-            jnp.float32(0.0) if steps is not None else t_next,
-        )
+        with jax.named_scope(UPDATE_SCOPE):
+            eps_buf, t_buf = buffer_append(
+                eps_buf, t_buf, i + 1, e_new,
+                jnp.float32(0.0) if steps is not None else t_next,
+            )
         traj_x = x_next if config.return_trajectory else None
         # per-sample: emit the raw (B,) errors and reduce after the scan, so
         # a batch-sharded run keeps the loop body free of collectives

@@ -75,6 +75,7 @@ interleaving donated-buffer executions.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import threading
 import time
@@ -254,6 +255,18 @@ def resolve_future(fut: Future, result=None, exception=None) -> None:
         pass
 
 
+def _denoiser_scope(eps_fn):
+    """``eps_fn`` with its ops under the ``denoiser`` name scope (op
+    metadata only: the same ops and fusions), so a device trace splits
+    each NFE into denoiser and solver time."""
+
+    def scoped(x, t):
+        with jax.named_scope("denoiser"):
+            return eps_fn(x, t)
+
+    return scoped
+
+
 class FusedExecutor:
     """Fused-chunk runner shared by the sync drain path and the scheduler.
 
@@ -324,17 +337,6 @@ class FusedExecutor:
         # into the same scrape (get-or-create registration, so sharing is
         # idempotent).  Everything below is cheap host-side accounting.
         self.metrics = metrics if metrics is not None else MetricsRegistry()
-        self._m_compile_hits = self.metrics.counter(
-            "sampler_compile_cache_hits_total",
-            "fused chunks served by an already-compiled bucket program "
-            "(in-process executable cache)",
-        )
-        self._m_compile_misses = self.metrics.counter(
-            "sampler_compile_cache_misses_total",
-            "bucket programs built at the lower/compile boundary, labelled "
-            "by source: disk (persistent compilation cache) or fresh "
-            "(real XLA compile)",
-        )
         self._m_compile_programs = self.metrics.counter(
             "sampler_compile_programs_total",
             "program acquisitions by source: memory (in-process "
@@ -384,7 +386,13 @@ class FusedExecutor:
             buckets=(0.125, 0.25, 0.375, 0.5, 0.625, 0.75, 0.875, 1.0),
         )
         self._m_wall = self.metrics.histogram(
-            "sampler_batch_wall_seconds", "device wall time per fused batch"
+            "sampler_batch_wall_seconds",
+            "host-clock time per fused batch, from dispatch to ready",
+        )
+        self._m_assembly = self.metrics.histogram(
+            "sampler_assembly_seconds",
+            "host-clock time per fused batch from the chunk's start to the "
+            "program call: noise draw, host copy, padding, step mask, upload",
         )
         # the permanent canary that masked (mixed-seq-len) traffic regressed
         # off the fast path.  Two sources feed it: sdpa rewriting a requested
@@ -702,8 +710,20 @@ class FusedExecutor:
         exact, or the shared seq bucket ``seq_len`` each request's rows are
         right-padded up to.  Serialized under the executor lock — safe
         to call from the scheduler thread and sync drain() callers
-        concurrently; blocks until the fused result is on host."""
-        with self._lock:
+        concurrently; blocks until the fused result is on host.
+
+        Profiler spans (no-ops unless a ``jax.profiler`` session is active):
+        ``sampler.chunk`` covers the call, the wait for the lock included,
+        and carries the chunk's tickets and group key; inside it
+        ``sampler.assemble`` (up to the program call), ``sampler.execute``
+        (the interval ``batch_wall_s`` times) and ``sampler.scatter``
+        (per-request results)."""
+        solver = self.resolve_solver(chunk[0][1])
+        with jax.profiler.TraceAnnotation(
+            "sampler.chunk",
+            tickets=" ".join(str(ticket) for ticket, _, _ in chunk),
+            key=f"{solver}/{seq_len}/{nfe}",
+        ), self._lock:
             self._run_chunk_locked(params, seq_len, nfe, chunk, results, pad)
 
     def _step_times_host(self, solver: str, nfe: int) -> np.ndarray:
@@ -721,142 +741,146 @@ class FusedExecutor:
         return ts
 
     def _run_chunk_locked(self, params, seq_len, nfe, chunk, results, pad):
-        d = self.dlm.config.d_model
-        solver = self.resolve_solver(chunk[0][1])
-        program = self.program_for(solver)
-        masked = self.seq_masked(solver)
-        stepped = self.nfe_masked(solver)
-        total = sum(req.batch for _, req, _ in chunk)
-        padded = self.bucket_batch(total) if pad else total
-        # assemble the batch on the host: eager jnp.concatenate would XLA-
-        # compile once per chunk *composition* (request sizes + pad rows),
-        # and under continuous batching every drain can have a new
-        # composition — 40-90ms of compile against a ~10ms solver run.
-        # Per-request noise stays jax.random (seed-deterministic across
-        # batch compositions); numpy does the composition-shaped work.
-        # Seq bucketing: each request's noise is drawn at its *exact*
-        # (batch, seq_len, d) shape — identical to its solo run — and
-        # right-padded with zero rows up to the chunk's seq bucket.
-        parts = []
-        row_lengths: list[int] = []
-        for _, req, _ in chunk:
-            noise = np.asarray(
-                jax.random.normal(
-                    jax.random.PRNGKey(req.seed),
-                    (req.batch, req.seq_len, d),
-                    jnp.float32,
-                )
-            )
-            if req.seq_len < seq_len:
-                noise = np.concatenate(
-                    [
-                        noise,
-                        np.zeros(
-                            (req.batch, seq_len - req.seq_len, d), np.float32
-                        ),
-                    ],
-                    axis=1,
-                )
-            parts.append(noise)
-            row_lengths += [req.seq_len] * req.batch
-        if padded > total:
-            parts.append(np.zeros((padded - total, seq_len, d), np.float32))
-            # pad rows are fully "valid": their lanes run ordinary (masked)
-            # math on zeros and are sliced away, never a 0-length edge case
-            row_lengths += [seq_len] * (padded - total)
-        x_init = jnp.asarray(np.concatenate(parts, axis=0))
-        lengths = (
-            jnp.asarray(np.asarray(row_lengths, np.int32)) if masked else None
-        )
-
-        cfg = dataclasses.replace(self.config_for(solver), nfe=nfe)
-        # mixed-NFE fusion: assemble the per-row StepMask on the host.  The
-        # chunk's ``nfe`` is the group's NFE *bucket*; each request row
-        # carries its own step count and its own exact-NFE time grid
-        # (terminal-padded to the bucket's step count), so its active
-        # prefix computes the very floats its unpadded run would.  Batch
-        # pad rows run fully active on the bucket grid — ordinary masked
-        # math on zeros, never a 0-step edge case.
-        steps = None
-        if stepped:
-            cap = program.steps_for_nfe(nfe, cfg)
-            acts: list[int] = []
-            rows_ts: list[np.ndarray] = []
-            nfe_padded_rows = 0
+        t_start = time.perf_counter()
+        with jax.profiler.TraceAnnotation("sampler.assemble"):
+            d = self.dlm.config.d_model
+            solver = self.resolve_solver(chunk[0][1])
+            program = self.program_for(solver)
+            masked = self.seq_masked(solver)
+            stepped = self.nfe_masked(solver)
+            total = sum(req.batch for _, req, _ in chunk)
+            padded = self.bucket_batch(total) if pad else total
+            # assemble the batch on the host: eager jnp.concatenate would XLA-
+            # compile once per chunk *composition* (request sizes + pad rows),
+            # and under continuous batching every drain can have a new
+            # composition — 40-90ms of compile against a ~10ms solver run.
+            # Per-request noise stays jax.random (seed-deterministic across
+            # batch compositions); numpy does the composition-shaped work.
+            # Seq bucketing: each request's noise is drawn at its *exact*
+            # (batch, seq_len, d) shape — identical to its solo run — and
+            # right-padded with zero rows up to the chunk's seq bucket.
+            parts = []
+            row_lengths: list[int] = []
             for _, req, _ in chunk:
-                n_r = program.steps_for_nfe(req.nfe, cfg)
-                ts_r = self._step_times_host(solver, req.nfe)
-                if n_r < cap:
-                    ts_r = np.concatenate(
-                        [ts_r, np.full((cap - n_r,), ts_r[-1], np.float32)]
+                noise = np.asarray(
+                    jax.random.normal(
+                        jax.random.PRNGKey(req.seed),
+                        (req.batch, req.seq_len, d),
+                        jnp.float32,
                     )
-                    nfe_padded_rows += req.batch
-                acts += [n_r] * req.batch
-                rows_ts += [ts_r] * req.batch
-            if padded > total:
-                bucket_ts = self._step_times_host(solver, nfe)
-                acts += [cap] * (padded - total)
-                rows_ts += [bucket_ts] * (padded - total)
-            steps = StepMask(
-                active_steps=jnp.asarray(np.asarray(acts, np.int32)),
-                ts=jnp.asarray(np.stack(rows_ts, axis=0)),
-            )
-            if nfe_padded_rows:
-                self._m_nfe_pad_rows.inc(nfe_padded_rows, solver=solver)
-        shardings = self._shardings(program, cfg, padded)
-        if shardings is not None:
-            x_init = jax.device_put(x_init, shardings.x)
-            if lengths is not None:
-                lengths = jax.device_put(lengths, shardings.lengths)
-            if steps is not None:
-                steps = StepMask(
-                    active_steps=jax.device_put(
-                        steps.active_steps, shardings.active_steps
-                    ),
-                    ts=jax.device_put(steps.ts, shardings.step_ts),
                 )
-            params = self._replicate(params)
-        run = self._jit_for(solver, cfg, padded, seq_len, masked, stepped, params)
-        t0 = time.perf_counter()
-        buffers = program.alloc_buffers(x_init, cfg, shardings)
-        x0, aux = run(params, x_init, lengths, steps, *buffers)
-        x0 = jax.block_until_ready(x0)
-        wall = time.perf_counter() - t0
+                if req.seq_len < seq_len:
+                    noise = np.concatenate(
+                        [
+                            noise,
+                            np.zeros(
+                                (req.batch, seq_len - req.seq_len, d), np.float32
+                            ),
+                        ],
+                        axis=1,
+                    )
+                parts.append(noise)
+                row_lengths += [req.seq_len] * req.batch
+            if padded > total:
+                parts.append(np.zeros((padded - total, seq_len, d), np.float32))
+                # pad rows are fully "valid": their lanes run ordinary (masked)
+                # math on zeros and are sliced away, never a 0-length edge case
+                row_lengths += [seq_len] * (padded - total)
+            x_init = jnp.asarray(np.concatenate(parts, axis=0))
+            lengths = (
+                jnp.asarray(np.asarray(row_lengths, np.int32)) if masked else None
+            )
+
+            cfg = dataclasses.replace(self.config_for(solver), nfe=nfe)
+            # mixed-NFE fusion: assemble the per-row StepMask on the host.  The
+            # chunk's ``nfe`` is the group's NFE *bucket*; each request row
+            # carries its own step count and its own exact-NFE time grid
+            # (terminal-padded to the bucket's step count), so its active
+            # prefix computes the very floats its unpadded run would.  Batch
+            # pad rows run fully active on the bucket grid — ordinary masked
+            # math on zeros, never a 0-step edge case.
+            steps = None
+            if stepped:
+                cap = program.steps_for_nfe(nfe, cfg)
+                acts: list[int] = []
+                rows_ts: list[np.ndarray] = []
+                nfe_padded_rows = 0
+                for _, req, _ in chunk:
+                    n_r = program.steps_for_nfe(req.nfe, cfg)
+                    ts_r = self._step_times_host(solver, req.nfe)
+                    if n_r < cap:
+                        ts_r = np.concatenate(
+                            [ts_r, np.full((cap - n_r,), ts_r[-1], np.float32)]
+                        )
+                        nfe_padded_rows += req.batch
+                    acts += [n_r] * req.batch
+                    rows_ts += [ts_r] * req.batch
+                if padded > total:
+                    bucket_ts = self._step_times_host(solver, nfe)
+                    acts += [cap] * (padded - total)
+                    rows_ts += [bucket_ts] * (padded - total)
+                steps = StepMask(
+                    active_steps=jnp.asarray(np.asarray(acts, np.int32)),
+                    ts=jnp.asarray(np.stack(rows_ts, axis=0)),
+                )
+                if nfe_padded_rows:
+                    self._m_nfe_pad_rows.inc(nfe_padded_rows, solver=solver)
+            shardings = self._shardings(program, cfg, padded)
+            if shardings is not None:
+                x_init = jax.device_put(x_init, shardings.x)
+                if lengths is not None:
+                    lengths = jax.device_put(lengths, shardings.lengths)
+                if steps is not None:
+                    steps = StepMask(
+                        active_steps=jax.device_put(
+                            steps.active_steps, shardings.active_steps
+                        ),
+                        ts=jax.device_put(steps.ts, shardings.step_ts),
+                    )
+                params = self._replicate(params)
+            run = self._jit_for(solver, cfg, padded, seq_len, masked, stepped, params)
+        with jax.profiler.TraceAnnotation("sampler.execute"):
+            t0 = time.perf_counter()
+            buffers = program.alloc_buffers(x_init, cfg, shardings)
+            x0, aux = run(params, x_init, lengths, steps, *buffers)
+            x0 = jax.block_until_ready(x0)
+            wall = time.perf_counter() - t0
+        self._m_assembly.observe(t0 - t_start, solver=solver)
         self._m_batches.inc()
         self._m_rows.inc(total)
         self._m_occupancy.observe(total / padded, solver=solver)
         self._m_wall.observe(wall, solver=solver)
-
-        done = time.perf_counter()
-        off = 0
-        for ticket, req, t_submit in chunk:
-            x0_req = x0[off : off + req.batch]
-            scope_seq = None
-            if masked and req.seq_len < seq_len:
-                x0_req = x0_req[:, : req.seq_len]
-                scope_seq = req.seq_len
-            results[ticket] = SampleResult(
-                x0=x0_req,
-                aux=program.scope_aux(
-                    aux, off, req.batch, seq_len=scope_seq,
-                    # under NFE bucketing the scan ran the bucket's step
-                    # count; step-stacked aux drops this request's inert
-                    # tail so histories match the unpadded run's shape
-                    n_steps=(
-                        program.steps_for_nfe(req.nfe, cfg)
-                        if stepped else None
+        with jax.profiler.TraceAnnotation("sampler.scatter"):
+            done = time.perf_counter()
+            off = 0
+            for ticket, req, t_submit in chunk:
+                x0_req = x0[off : off + req.batch]
+                scope_seq = None
+                if masked and req.seq_len < seq_len:
+                    x0_req = x0_req[:, : req.seq_len]
+                    scope_seq = req.seq_len
+                results[ticket] = SampleResult(
+                    x0=x0_req,
+                    aux=program.scope_aux(
+                        aux, off, req.batch, seq_len=scope_seq,
+                        # under NFE bucketing the scan ran the bucket's step
+                        # count; step-stacked aux drops this request's inert
+                        # tail so histories match the unpadded run's shape
+                        n_steps=(
+                            program.steps_for_nfe(req.nfe, cfg)
+                            if stepped else None
+                        ),
+                        padded_steps=(
+                            program.steps_for_nfe(nfe, cfg) if stepped else None
+                        ),
                     ),
-                    padded_steps=(
-                        program.steps_for_nfe(nfe, cfg) if stepped else None
-                    ),
-                ),
-                latency_s=done - t_submit,
-                batch_wall_s=wall,
-                padded_batch=padded,
-                padded_seq_len=seq_len,
-                padded_nfe=nfe,
-            )
-            off += req.batch
+                    latency_s=done - t_submit,
+                    batch_wall_s=wall,
+                    padded_batch=padded,
+                    padded_seq_len=seq_len,
+                    padded_nfe=nfe,
+                )
+                off += req.batch
 
     def _jit_for(
         self, solver: str, cfg: SolverConfig, batch: int, seq_len: int,
@@ -890,25 +914,26 @@ class FusedExecutor:
         key = (solver, cfg, batch, seq_len, self.dp, masked, stepped)
         cached = self._jitted.get(key)
         if cached is not None:
-            self._m_compile_hits.inc(solver=solver)
             self._m_compile_programs.inc(solver=solver, source="memory")
             self._compile_counts["memory"] += 1
             return cached
         compiled, _ = self._compile(key, params)
         return compiled
 
+    @functools.partial(jax.profiler.annotate_function, name="sampler.compile")
     def _compile(self, key, params):
         """Lower and compile one bucket program from abstract shapes — no
         sampling, no params traffic — and cache the executable under
         ``key``.  Returns ``(compiled, source)`` with ``source`` ``"disk"``
         (served by the persistent compilation cache) or ``"fresh"`` (real
-        XLA compile).  Callers hold the executor lock."""
+        XLA compile).  Callers hold the executor lock.  A profiler span,
+        ``sampler.compile``, so a compile names its own gap."""
         solver, cfg, batch, seq_len, _, masked, stepped = key
         program = self.program_for(solver)
         shardings = self._shardings(program, cfg, batch)
 
         def run(params, x_init, lengths, steps, *buffers):
-            eps_fn = self._eps_fn(params, lengths, shardings)
+            eps_fn = _denoiser_scope(self._eps_fn(params, lengths, shardings))
             out = program.sample_scan(
                 eps_fn,
                 x_init,
@@ -947,7 +972,6 @@ class FusedExecutor:
         source = "disk" if disk_cache_hits() > disk_before else "fresh"
         self._jitted[key] = compiled
         self._compile_counts[source] += 1
-        self._m_compile_misses.inc(solver=solver, source=source)
         self._m_compile_programs.inc(solver=solver, source=source)
         self._m_compile_wall.observe(wall, solver=solver, source=source)
         return compiled, source

@@ -52,6 +52,7 @@ from http.client import HTTPConnection
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import urlsplit
 
+import jax
 import numpy as np
 
 from repro.serving.executor import SampleRequest, SampleResult
@@ -411,17 +412,22 @@ class FrontDoor:
         length = int(handler.headers.get("Content-Length") or 0)
         raw = handler.rfile.read(length) if length else b""
         try:
-            payload = json.loads(raw.decode("utf-8"))
+            with jax.profiler.TraceAnnotation("frontdoor.decode"):
+                req = decode_request(json.loads(raw.decode("utf-8")))
         except (UnicodeDecodeError, json.JSONDecodeError) as e:
             self._respond_json(
                 handler, route, 400,
                 encode_error("invalid_request", f"body is not JSON: {e}"),
             )
             return
-        try:
-            req = decode_request(payload)
-            fut = self.scheduler.submit(req)
         except (SchemaError, ValueError) as e:
+            self._respond_json(
+                handler, route, 400, encode_error("invalid_request", str(e))
+            )
+            return
+        try:
+            fut = self.scheduler.submit(req)
+        except ValueError as e:
             self._respond_json(
                 handler, route, 400, encode_error("invalid_request", str(e))
             )
@@ -444,7 +450,9 @@ class FrontDoor:
                 handler, route, 500, encode_error("internal", str(e))
             )
             return
-        self._respond_json(handler, route, 200, encode_result(res))
+        with jax.profiler.TraceAnnotation("frontdoor.encode"):
+            body = json.dumps(encode_result(res))
+        self._respond_text(handler, route, 200, body, "application/json")
 
     # ---- response plumbing ----------------------------------------------
     def _respond_text(

@@ -7,10 +7,11 @@ door serves at ``GET /metrics``.  One registry rides with each
 sync drains, the continuous-batching scheduler, the HTTP front door —
 instruments into the same scrape:
 
-* executor: compile-cache hits/misses, fused-batch count/rows, fuse
-  occupancy (real rows / padded rows), batch wall time;
-* scheduler: per-fuse-group queue depth, admission rejects, deadline
-  expirations, arrival-to-result latency histogram;
+* executor: program acquisitions by source (memory / disk / fresh),
+  fused-batch count/rows, fuse occupancy (real rows / padded rows), batch
+  wall time, chunk assembly time;
+* scheduler: per-fuse-group queue depth and queue wait, admission rejects,
+  deadline expirations, arrival-to-result latency histogram;
 * front door: HTTP request counts by route and status code.
 
 Thread-safety: every mutation and ``render()`` takes the instrument's (or

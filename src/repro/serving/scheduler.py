@@ -59,6 +59,8 @@ from collections import deque
 from concurrent.futures import Future
 from typing import Callable
 
+import jax
+
 from repro.serving import result_keys as K
 from repro.serving.diffusion_sampler import BatchedSampler
 from repro.serving.executor import (
@@ -260,6 +262,11 @@ class AsyncBatchedSampler:
         self._m_latency = m.histogram(
             "sampler_request_latency_seconds",
             "arrival-to-result latency per delivered request",
+        )
+        self._m_queue_wait = m.histogram(
+            "sampler_queue_wait_seconds",
+            "submit-to-launch wait per request, by fuse group "
+            "(solver, seq, nfe)",
         )
 
     # ---- client surface -------------------------------------------------
@@ -493,6 +500,10 @@ class AsyncBatchedSampler:
         # chunks assemble in boarding (priority) order; leftovers keep
         # their original arrival order and times
         taken = [entries[i] for i in taken_idx]
+        now = self._clock()
+        label = self._key_labels(key)
+        for (_, _, t_submit), _ in taken:
+            self._m_queue_wait.observe(now - t_submit, **label)
         self._queues[key] = deque(
             e for i, e in enumerate(entries) if i not in taken_set
         )
@@ -550,7 +561,8 @@ class AsyncBatchedSampler:
                     batches = self._pop_ready(now)
                     if batches or expired:
                         break
-                    self._cv.wait(timeout=self._next_deadline_s(now))
+                    with jax.profiler.TraceAnnotation("sampler.wait"):
+                        self._cv.wait(timeout=self._next_deadline_s(now))
                 stopping = self._stopping
                 if stopping:
                     now = self._clock()

@@ -1,3 +1,4 @@
+import glob
 import json
 import os
 import subprocess
@@ -67,6 +68,33 @@ class OracleDenoiser:
 
     def eps_fn(self, params, lengths=None):
         return self.analytic.eps
+
+
+def host_spans(log_dir, prefix="sampler."):
+    """Every host event of the profiler trace under ``log_dir`` whose name
+    starts with ``prefix``: name, thread, start and end in ns, and its
+    metadata, in order of start."""
+    import warnings
+
+    out = []
+    pattern = os.path.join(log_dir, "**", "*.xplane.pb")
+    for path in glob.glob(pattern, recursive=True):
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    if not e.name.startswith(prefix):
+                        continue
+                    with warnings.catch_warnings():
+                        # iterating stats warns from inside JAX's bindings
+                        warnings.simplefilter("ignore", DeprecationWarning)
+                        stats = {k: v for k, v in e.stats}
+                    out.append({
+                        "name": e.name, "line": line.name, "t": e.start_ns,
+                        "end": e.start_ns + e.duration_ns, "stats": stats,
+                    })
+    return sorted(out, key=lambda s: (s["t"], -s["end"]))
 
 
 @pytest.fixture(scope="session")

@@ -24,10 +24,11 @@ import threading
 import time
 from http.client import HTTPConnection
 
+import jax
 import numpy as np
 import pytest
 
-from conftest import AnalyticGaussian, OracleDenoiser
+from conftest import AnalyticGaussian, OracleDenoiser, host_spans
 from repro.core import linear_schedule
 from repro.serving import (
     AsyncBatchedSampler,
@@ -616,8 +617,6 @@ def test_metrics_and_healthz(door):
     for name in (
         "sampler_queue_depth_rows",
         "sampler_fuse_occupancy_ratio",
-        "sampler_compile_cache_hits_total",
-        "sampler_compile_cache_misses_total",
         "sampler_compile_programs_total",
         "sampler_compile_seconds",
         "sampler_warmup_grid_programs",
@@ -628,6 +627,8 @@ def test_metrics_and_healthz(door):
         "sampler_admission_rejects_total",
         "sampler_requests_submitted_total",
         "sampler_request_latency_seconds_bucket",
+        "sampler_queue_wait_seconds_bucket",
+        "sampler_assembly_seconds_bucket",
         "frontdoor_http_requests_total",
     ):
         assert name in text, name
@@ -635,6 +636,19 @@ def test_metrics_and_healthz(door):
     assert "# TYPE sampler_request_latency_seconds histogram" in text
     assert 'le="+Inf"' in text
     assert text.endswith("\n")
+
+
+def test_codec_spans(door, tmp_path):
+    """Request decode and result encode are profiler spans on the
+    handler thread, apart from the wait for the result."""
+    client = FrontDoorClient(door.url, timeout=60)
+    client.sample(req(seed=2))   # compiled before the trace
+    with jax.profiler.trace(str(tmp_path)):
+        client.sample(req(seed=3))
+    spans = host_spans(str(tmp_path), prefix="frontdoor.")
+    assert [s["name"] for s in spans] == ["frontdoor.decode", "frontdoor.encode"]
+    decode, encode = spans
+    assert decode["line"] == encode["line"] and decode["end"] <= encode["t"]
 
 
 def test_client_rejects_non_http_url():
