@@ -5,6 +5,8 @@ Handles the hardware-alignment plumbing so callers keep natural shapes:
   (padded key slots get position -1 => masked out; padded head dims are
   zeros => contribute nothing to dot products, scale uses the true hd);
 * pads GQA group G to the f32 sublane multiple (8) for the decode kernel;
+* lays out the selective scan's operands (A and the state transposed, d_inner
+  on lanes) and pads its sequence to the time block with identity steps;
 * picks how the kernels run from the platform: compiled by Mosaic on TPU,
   in interpret mode everywhere else, so the same call sites run in CPU
   tests and on the chip.  There is no fallback: a kernel that fails to
@@ -25,6 +27,7 @@ import jax.numpy as jnp
 from repro.kernels import decode_attention as _dec
 from repro.kernels import era_update as _era
 from repro.kernels import flash_attention as _fa
+from repro.kernels import selective_scan as _ss
 from repro.core.lagrange import lagrange_weights
 
 Array = jax.Array
@@ -123,6 +126,54 @@ def decode_attention(
     )
     out = out.reshape(b, kvh, gp, -1)[:, :, :g, :hd].reshape(b, h, hd)
     return out[:, None] if squeeze else out
+
+
+#: VMEM the selective scan's blocks may fill (v5e's default scoped limit is
+#: 16 MiB)
+SCAN_VMEM_BYTES = 12 << 20
+
+
+def _scan_block_d(di: int, n: int, block_t: int, itemsize: int) -> int:
+    """The widest multiple of 128 that divides ``di`` and whose blocks fit
+    ``SCAN_VMEM_BYTES``: dt and x in their dtype and y in float32, each
+    double-buffered, and the float32 state, A, h0 and h_last.  ``di`` whole
+    where it is no multiple of 128."""
+    if di % 128:
+        return di
+    per_lane = 2 * block_t * (2 * itemsize + 4) + 7 * n * 4
+    return max(
+        (bd for bd in range(128, di + 1, 128)
+         if di % bd == 0 and bd * per_lane <= SCAN_VMEM_BYTES),
+        default=128,
+    )
+
+
+@jax.jit
+def selective_scan(
+    dt: Array,      # (B, S, di)
+    x: Array,       # (B, S, di)
+    a: Array,       # (di, N) float32
+    bmat: Array,    # (B, S, N)
+    c: Array,       # (B, S, N)
+    h0: Array,      # (B, di, N) float32
+) -> tuple[Array, Array]:
+    """Mamba's selective scan with the state in VMEM: the same (y (B, S, di)
+    float32, h_last (B, di, N) float32) as ``ssm.chunked_ssm_outputs`` on
+    the float32 casts of its operands.  A sequence shorter than the kernel's
+    time block runs one block of its own length, rounded up to the kernel's
+    group; pad steps have ``dt = 0``, which leave the state as it is."""
+    s, di = x.shape[1], x.shape[2]
+    bt = min(_ss.BLOCK_T, -(-s // _ss.GROUP) * _ss.GROUP)
+    itemsize = max(dt.dtype.itemsize, x.dtype.itemsize)
+    bd = _scan_block_d(di, bmat.shape[-1], bt, itemsize)
+    dt, x, bmat, c = (_pad_to(t, bt, 1) for t in (dt, x, bmat, c))
+    y, h_last = _ss.selective_scan(
+        dt, x, bmat, c,
+        a.astype(jnp.float32).T,
+        h0.astype(jnp.float32).transpose(0, 2, 1),
+        block_t=bt, block_d=bd, interpret=interpret_mode(),
+    )
+    return y[:, :s], h_last.transpose(0, 2, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("block",))

@@ -1,9 +1,14 @@
 """State-space / recurrent blocks: Mamba (Hymba heads) and xLSTM cells.
 
-TPU adaptation notes (DESIGN.md §4): the CUDA "selective scan" kernel of
-Mamba is replaced by a *chunked* linear-recurrence scan — ``lax.scan`` over
-sequence chunks with an associative scan inside each chunk — which keeps the
-live state tensor at (B, chunk, d_inner, N) instead of (B, S, d_inner, N).
+TPU adaptation notes (DESIGN.md §4): on TPU a Mamba sequence (S > 1) runs
+the Pallas kernel :mod:`repro.kernels.selective_scan`, which, like Mamba's
+CUDA "selective scan", keeps the (d_inner, N) state on chip and sweeps it
+over time, reading dt, x, B, C and writing y once.  Decode (S = 1) and
+every other platform run :func:`chunked_ssm_outputs`, a *chunked*
+linear-recurrence scan — ``lax.scan`` over sequence chunks with an
+associative scan inside each chunk — which keeps the live state tensor at
+(B, chunk, d_inner, N) instead of (B, S, d_inner, N); it is also the
+kernel's backward pass and the reference it is tested against.
 xLSTM's sLSTM is an inherently sequential recurrence (recurrent weights),
 implemented as a time scan; mLSTM (matrix memory) uses the same chunked
 pattern as Mamba.
@@ -13,14 +18,17 @@ in this module is strictly left-to-right — ``causal_conv1d`` left-pads,
 the chunked recurrences carry state forward only, and the intra-chunk
 mLSTM scores are tril-masked to exact zeros before any contraction — so a
 right-padded row's outputs at positions ``< length`` are identical to the
-exact-shape run's.  Two structural facts make the identity *bitwise*, not
-just mathematical: (1) ``jax.lax.associative_scan``'s combine tree for
-prefix element ``p`` depends only on ``p`` (Brent–Kung interleave), not on
-the scanned length, so a longer padded axis doesn't re-associate prefix
-sums; (2) chunk boundaries inside the prefix coincide between the exact
-and padded runs (``chunk = min(chunk, s)`` either yields the same chunking
-over the prefix, or both runs put the whole prefix in their first chunk),
-and masked/pad slots contribute exact ``+0.0`` terms to the fixed-shape
+exact-shape run's.  The Pallas kernel has it by construction: each step
+reads only its own position and the carried state, and a pad step
+(``dt = 0``) leaves the state as it is.  Two structural facts make the
+chunked scan's identity *bitwise*, not just mathematical: (1)
+``jax.lax.associative_scan``'s combine tree for prefix element ``p``
+depends only on ``p`` (Brent–Kung interleave), not on the scanned length,
+so a longer padded axis doesn't re-associate prefix sums; (2) chunk
+boundaries inside the prefix coincide between the exact and padded runs
+(``chunk = min(chunk, s)`` either yields the same chunking over the
+prefix, or both runs put the whole prefix in their first chunk), and
+masked/pad slots contribute exact ``+0.0`` terms to the fixed-shape
 contractions.  mLSTM contracts over the whole chunk, so its chunk length is
 fixed (a short sequence pads up to it) instead of ``min(chunk, s)``: a sum
 over 5 terms and one over 9 with 4 exact zeros can round differently.
@@ -30,6 +38,8 @@ it is what lets SSM kinds join ``MASKABLE_BLOCKS`` in
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -129,6 +139,35 @@ def chunked_ssm_outputs(
     return y[:, :s], h_last
 
 
+def _f32(*ts):
+    return tuple(t.astype(jnp.float32) for t in ts)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _pallas_scan(dt, x, a, bmat, c, h0, chunk):
+    """The Pallas selective scan on operands in their compute dtype (cast
+    to float32 on chip).  It has no backward pass of its own, so its
+    gradient is :func:`chunked_ssm_outputs`', which computes the same
+    function."""
+    from repro.kernels import ops as kops
+
+    return kops.selective_scan(dt, x, a, bmat, c, h0)
+
+
+def _pallas_scan_fwd(dt, x, a, bmat, c, h0, chunk):
+    return _pallas_scan(dt, x, a, bmat, c, h0, chunk), (dt, x, a, bmat, c, h0)
+
+
+def _pallas_scan_bwd(chunk, res, g):
+    _, vjp = jax.vjp(
+        lambda *ts: chunked_ssm_outputs(*_f32(*ts), chunk), *res
+    )
+    return vjp(g)
+
+
+_pallas_scan.defvjp(_pallas_scan_fwd, _pallas_scan_bwd)
+
+
 def mamba_specs(cfg) -> dict:
     m = cfg.ssm
     d = cfg.d_model
@@ -159,18 +198,17 @@ def _mamba_core(p, xz: Array, cfg, conv_state, ssm_state, *, chunk):
     dt = jax.nn.softplus(L.linear(p["dt_proj"], dt))   # (B,S,di)
     a = -jnp.exp(p["A_log"].astype(jnp.float32))       # (di,N)
 
-    # fused chunked scan: discretization (a_bar = exp(dt*A), b_bar = dt*B*x),
-    # recurrence, and the <c, h> readout all happen per chunk — no
-    # (B, S, d_inner, N) tensor is ever materialized
-    y, h_last = chunked_ssm_outputs(
-        dt.astype(jnp.float32),
-        x.astype(jnp.float32),
-        a,
-        bmat.astype(jnp.float32),
-        cmat.astype(jnp.float32),
-        ssm_state,
-        chunk,
-    )
+    # discretization (a_bar = exp(dt*A), b_bar = dt*B*x), recurrence and the
+    # <c, h> readout in one pass: no (B, S, d_inner, N) tensor is ever
+    # materialized.  On TPU a sequence runs the Pallas kernel; decode and
+    # every other platform run the chunked scan.
+    with jax.named_scope("mamba.scan"):
+        if xz.shape[1] > 1 and jax.default_backend() == "tpu":
+            y, h_last = _pallas_scan(dt, x, a, bmat, cmat, ssm_state, chunk)
+        else:
+            y, h_last = chunked_ssm_outputs(
+                *_f32(dt, x, a, bmat, cmat, ssm_state), chunk
+            )
     y = (y + x.astype(jnp.float32) * p["D"].astype(jnp.float32)).astype(x.dtype)
     y = y * jax.nn.silu(z)
     return y, conv_state, h_last

@@ -303,3 +303,63 @@ def test_unmasked_flash_unchanged_by_mask_plumbing():
         q, k, v, pos, pos, kv_mask=jnp.ones((b, s), bool)
     )
     np.testing.assert_array_equal(np.asarray(out_none), np.asarray(out_full))
+
+
+# ---------------------------------------------------------------------------
+# selective scan (Mamba)
+# ---------------------------------------------------------------------------
+
+
+def _scan_inputs(b, s, di, n=16, dtype=jnp.float32):
+    dt = (0.5 * jax.nn.softplus(_rand(20, (b, s, di)))).astype(dtype)
+    x = _rand(21, (b, s, di), dtype)
+    bmat, c = _rand(22, (b, s, n), dtype), _rand(23, (b, s, n), dtype)
+    a = -jnp.exp(0.5 * _rand(24, (di, n)))
+    h0 = _rand(25, (b, di, n))
+    return dt, x, a, bmat, c, h0
+
+
+def _scan_f64(dt, x, a, bmat, c, h0):
+    """Step-by-step float64 recurrence: h_t = exp(dt_t A) h + dt_t x_t B_t,
+    y_t = <h_t, C_t>."""
+    dt, x, a, bmat, c, h = (np.asarray(t, np.float64) for t in (dt, x, a, bmat, c, h0))
+    ys = []
+    for t in range(x.shape[1]):
+        h = np.exp(dt[:, t, :, None] * a) * h + (
+            (dt[:, t] * x[:, t])[..., None] * bmat[:, t, None, :]
+        )
+        ys.append(np.einsum("bdn,bn->bd", h, c[:, t]))
+    return np.stack(ys, axis=1), h
+
+
+SCAN_CASES = [
+    # (B, S, di, dtype): S=300 spans three time blocks of 128, the last
+    # padded; di=384 is no multiple of 256 or 512
+    (b, s, di, jnp.float32)
+    for b in (1, 3) for s in (1, 7, 256, 300) for di in (256, 384)
+] + [(3, 300, 384, jnp.bfloat16)]
+
+
+@pytest.mark.parametrize(
+    "case", SCAN_CASES, ids=lambda c: f"b{c[0]}-s{c[1]}-d{c[2]}-{jnp.dtype(c[3]).name}"
+)
+def test_selective_scan_vs_chunked_and_f64(case):
+    """The kernel (interpret mode) against the chunked jnp scan it replaces
+    on TPU and a float64 step-by-step recurrence, from a nonzero h0, with
+    h_last checked too.  bf16 operands are cast to float32 exactly."""
+    from repro.models.ssm import chunked_ssm_outputs
+
+    b, s, di, dtype = case
+    dt, x, a, bmat, c, h0 = _scan_inputs(b, s, di, dtype=dtype)
+    y, h_last = ops.selective_scan(dt, x, a, bmat, c, h0)
+    assert y.shape == (b, s, di) and y.dtype == jnp.float32
+    assert h_last.shape == (b, di, 16) and h_last.dtype == jnp.float32
+    f32 = lambda t: t.astype(jnp.float32)
+    y_c, h_c = chunked_ssm_outputs(f32(dt), f32(x), a, f32(bmat), f32(c), h0, 32)
+    y_64, h_64 = _scan_f64(dt, x, a, bmat, c, h0)
+    for got, want in ((y, y_c), (h_last, h_c), (y, y_64), (h_last, h_64)):
+        scale = float(np.max(np.abs(want)))
+        np.testing.assert_allclose(
+            np.asarray(got, np.float64), np.asarray(want, np.float64),
+            rtol=0, atol=2e-6 * scale,
+        )

@@ -6,9 +6,10 @@ scan, so zero right-padding can never reach a prefix position's output
 (contract note in :mod:`repro.models.ssm`).  These tests pin that argument
 empirically, at two levels:
 
-* **module level** — the raw scan blocks (mamba, mlstm, slstm) run on a
+* **module level** — the raw scan blocks (mamba, on the chunked scan and
+  on the Pallas selective scan it runs on TPU, mlstm, slstm) run on a
   zero-right-padded input reproduce the exact-shape run BITWISE on the
-  valid prefix.
+  valid prefix; the selective scan kernel does so under arbitrary padding.
 * **model level** — every smoke architecture family's DiffusionLM ``eps``
   on a padded batch with ``lengths`` set reproduces the exact-shape batch
   BITWISE on the prefix, with the pad tail exactly zero.  This is the
@@ -47,15 +48,34 @@ def _padded_vs_exact(fn, x, l_exact):
     return np.asarray(exact), np.asarray(padded)
 
 
-@pytest.mark.parametrize("kind", ["mamba", "mlstm", "slstm"])
-def test_scan_blocks_prefix_bitwise(kind):
-    arch = {"mamba": "hymba-1.5b", "mlstm": "xlstm-350m", "slstm": "xlstm-350m"}
+def _steer_to_tpu(monkeypatch):
+    """The platform says TPU, so a Mamba sequence takes the Pallas selective
+    scan; with no chip here the kernel still interprets."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ops, "interpret_mode", lambda: True)
+    calls = []
+    scan = ops.selective_scan
+    monkeypatch.setattr(
+        ops, "selective_scan", lambda *a, **kw: calls.append(1) or scan(*a, **kw)
+    )
+    return calls
+
+
+@pytest.mark.parametrize("kind", ["mamba", "mamba-tpu", "mlstm", "slstm"])
+def test_scan_blocks_prefix_bitwise(kind, monkeypatch):
+    arch = {
+        "mamba": "hymba-1.5b", "mamba-tpu": "hymba-1.5b",
+        "mlstm": "xlstm-350m", "slstm": "xlstm-350m",
+    }
     cfg = get_config(arch[kind], smoke=True)
     key = jax.random.PRNGKey(0)
     b, s, l_exact = 2, 9, 5
     x = jax.random.normal(jax.random.PRNGKey(1), (b, s, cfg.d_model), cfg.dtype)
     x = x.at[:, l_exact:].set(0.0)  # zero right-padding
-    if kind == "mamba":
+    kernel_calls = _steer_to_tpu(monkeypatch) if kind == "mamba-tpu" else None
+    if kind.startswith("mamba"):
         p = init_params(ssm.mamba_specs(cfg), key, cfg.param_dtype)
         fn = lambda xi: ssm.mamba(p, xi, cfg)[0]
     elif kind == "mlstm":
@@ -65,9 +85,41 @@ def test_scan_blocks_prefix_bitwise(kind):
         p = init_params(ssm.slstm_specs(cfg), key, cfg.param_dtype)
         fn = lambda xi: ssm.slstm_block(p, xi, cfg)[0]
     exact, padded = _padded_vs_exact(fn, x, l_exact)
+    if kernel_calls is not None:
+        assert len(kernel_calls) == 2, "mamba did not run the selective scan"
     np.testing.assert_array_equal(
         padded[:, :l_exact], exact,
         err_msg=f"{kind}: right-padding leaked into the prefix",
+    )
+
+
+@pytest.mark.parametrize("l_exact, s", [(5, 9), (17, 40), (250, 300)])
+def test_selective_scan_kernel_prefix_bitwise(monkeypatch, l_exact, s):
+    """The Pallas selective scan, as a Mamba sequence runs it on TPU: a row
+    right-padded with arbitrary (nonzero) values reproduces the
+    exact-length run BITWISE on its prefix.  (5, 9) runs one short time
+    block against a longer one; (250, 300) spans several blocks, the exact
+    run's last one padded."""
+    from repro.kernels import ops
+
+    _steer_to_tpu(monkeypatch)
+    cfg = get_config("hymba-1.5b", smoke=True)
+    di, n = cfg.ssm.expand * cfg.d_model, cfg.ssm.state_dim
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    dt = 0.5 * jax.nn.softplus(jax.random.normal(ks[0], (2, s, di)))
+    x = jax.random.normal(ks[1], (2, s, di))
+    bmat = jax.random.normal(ks[2], (2, s, n))
+    c = jax.random.normal(ks[3], (2, s, n))
+    a = -jnp.exp(0.5 * jax.random.normal(ks[4], (di, n)))
+    h0 = jax.random.normal(ks[5], (2, di, n))
+    y_exact, _ = ops.selective_scan(
+        *(t[:, :l_exact] for t in (dt, x)), a,
+        *(t[:, :l_exact] for t in (bmat, c)), h0,
+    )
+    y_pad, _ = ops.selective_scan(dt, x, a, bmat, c, h0)
+    np.testing.assert_array_equal(
+        np.asarray(y_pad)[:, :l_exact], np.asarray(y_exact),
+        err_msg="selective scan: right-padding leaked into the prefix",
     )
 
 

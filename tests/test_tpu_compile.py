@@ -1,5 +1,6 @@
 """Compile-only walls: the main path's Pallas kernels lower and compile for a
-described TPU v5e chip at qwen2-1.5b widths, with no chip attached.
+described TPU v5e chip at qwen2-1.5b widths (the selective scan at
+hymba-1.5b's), with no chip attached.
 
 Nothing runs here; the TPU compiler (installed with libtpu) compiles for
 the described device and refuses what the chip would refuse: misaligned
@@ -22,10 +23,13 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.era import AM4
 from repro.kernels import era_update as era_kernel
 from repro.kernels import flash_attention as flash_kernel
+from repro.kernels import ops
 
 # qwen2-1.5b attention and latent widths, serving batch 8 at seq 512
 B, H, KV, S, HD, D = 8, 12, 2, 512, 128, 1536
 K_ORDER = 4
+# hymba-1.5b's Mamba heads: d_inner 3200, state 16
+DI, N_STATE = 3200, 16
 
 
 @pytest.fixture(scope="module")
@@ -111,15 +115,39 @@ def test_flash_attention_compiles_for_v5e(one_chip, masked, dtype):
     assert "tpu_custom_call" in _compiled_text(call, *avals)
 
 
+def _scan_avals(sharding, rows, seq, dtype, rep=None):
+    """dt, x, A, B, C, h0 as ``ops.selective_scan`` takes them."""
+    sds = lambda shape, dt, sh=sharding: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+    return (
+        sds((rows, seq, DI), dtype),
+        sds((rows, seq, DI), dtype),
+        sds((DI, N_STATE), jnp.float32, rep or sharding),
+        sds((rows, seq, N_STATE), dtype),
+        sds((rows, seq, N_STATE), dtype),
+        sds((rows, DI, N_STATE), jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_selective_scan_compiles_for_v5e(one_chip, monkeypatch, dtype):
+    """At the hymba-1.5b.offline cell's shapes: 4 rows of 1024 positions.
+    bf16 is the served dtype; float32 is the chip check's twin engine."""
+    # the wrapper asks the platform whether to interpret: answer as the
+    # chip would, since the compile below is for the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _compiled_text(ops.selective_scan, *_scan_avals(one_chip, 4, 1024, dtype))
+    assert "tpu_custom_call" in text
+
+
 def test_kernels_compile_per_batch_shard_on_a_v5e_mesh(topo, monkeypatch):
     """On a mesh XLA cannot partition a Mosaic kernel; run per batch shard
-    (``per_batch_shard``, as the serving executor runs the denoiser and
-    ERA runs its step), the 4-chip program compiles with the kernels in it
-    and the batch rows spread over the chips."""
+    (``per_batch_shard``, as the serving executor runs the denoiser, with
+    its flash attention and selective scan, and ERA runs its step), the
+    4-chip program compiles with the kernels in it and the batch rows
+    spread over the chips."""
     import numpy as np
     from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-    from repro.kernels import ops
     from repro.parallel.sharding import per_batch_shard
 
     # the wrappers ask the platform whether to interpret: answer as the
@@ -130,7 +158,7 @@ def test_kernels_compile_per_batch_shard_on_a_v5e_mesh(topo, monkeypatch):
     rep = NamedSharding(mesh, P())
     sds = lambda shape, dt, sh: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
 
-    def step(q, k, v, pos, mask, x, eps_sel, t_sel, e_hist):
+    def step(q, k, v, pos, mask, x, eps_sel, t_sel, e_hist, *scan_args):
         o = per_batch_shard(
             rows,
             lambda q, k, v, pos, mask: ops.flash_attention(
@@ -149,7 +177,11 @@ def test_kernels_compile_per_batch_shard_on_a_v5e_mesh(topo, monkeypatch):
             ),
             x, eps_sel, t_sel, e_hist, batch_dims=(0, 0, 0, 0),
         )
-        return o, x_next
+        y, h_last = per_batch_shard(
+            rows, ops.selective_scan, *scan_args,
+            batch_dims=(0, 0, None, 0, 0, 0),
+        )
+        return o, x_next, y, h_last
 
     avals = (
         sds((B, S, H, HD), jnp.bfloat16, rows),
@@ -161,8 +193,8 @@ def test_kernels_compile_per_batch_shard_on_a_v5e_mesh(topo, monkeypatch):
         sds((B, K_ORDER, S, D), jnp.float32, rows),
         sds((B, K_ORDER), jnp.float32, rows),
         sds((B, 3, S, D), jnp.float32, rows),
-    )
+    ) + _scan_avals(rows, B, S, jnp.bfloat16, rep=rep)
     compiled = jax.jit(step).lower(*avals).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert compiled.as_text().count("tpu_custom_call") >= 3
     x_rows = compiled.input_shardings[0][5].devices_indices_map((B, S, D))
     assert sorted(idx[0].start for idx in x_rows.values()) == [0, 2, 4, 6]
