@@ -4,15 +4,19 @@ program happens to compute: padding (rows, positions, head dims) and
 re-reads are not counted.
 
 Counted: every matrix product of the denoiser (2 flops per multiply-add):
-in_proj, the time MLP, attention projections, the score and value products
-of attention over the (query, key) pairs its mask admits, the MLP, the
-Mamba heads' projections and depthwise conv, and the eps head.  Left out:
-norms, RoPE, softmax, activations, and the Mamba selective scan's
-elementwise recurrence (about 6 flops per state element per position),
-which runs on the vector units and has no matrix-unit peak to compare with.
+in_proj, the time MLP and the eps head here, and each layer's products in
+its kind's module (``bench/layers/<kind>.py``: ``matmul_flops``), built on
+the GQA attention and SwiGLU MLP counts here; attention's score and value
+products are counted over the (query, key) pairs its mask admits.  Left
+out: norms, RoPE, softmax, activations, and whatever a module names (the
+Mamba selective scan's elementwise recurrence, about 6 flops per state
+element per position, runs on the vector units and has no matrix-unit peak
+to compare with).
 """
 
 from __future__ import annotations
+
+from bench import loader
 
 
 def attention_pairs(seq: int, window: int) -> int:
@@ -24,17 +28,19 @@ def attention_pairs(seq: int, window: int) -> int:
     return sum(min(seq, q + window) - max(0, q - window + 1) for q in range(seq))
 
 
-def _layer_matmul_params(cfg: dict, kind: str) -> int:
+def attention_flops(cfg: dict, rows: int, seq: int, window: int) -> float:
+    """GQA attention of one layer (``reference.attention``): the q, k, v
+    and output projections, and the score and value products over the
+    pairs the mask admits."""
     d = cfg["hidden_size"]
     nh, kv, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
-    attn = d * (nh * hd) + 2 * d * (kv * hd) + (nh * hd) * d
-    mlp = 3 * d * cfg["intermediate_size"]
-    if kind == "dense":
-        return attn + mlp
-    di = cfg["mamba_expand"] * d
-    n, dtr = cfg["mamba_d_state"], cfg["mamba_dt_rank"]
-    mamba = d * 2 * di + di * (dtr + 2 * n) + dtr * di + di * d
-    return attn + mlp + mamba + cfg["mamba_d_conv"] * di
+    proj = d * (nh * hd) + 2 * d * (kv * hd) + (nh * hd) * d
+    return 2.0 * rows * seq * proj + 4.0 * rows * nh * hd * attention_pairs(seq, window)
+
+
+def mlp_flops(cfg: dict, rows: int, seq: int) -> float:
+    """The SwiGLU MLP of one layer (``reference.mlp``)."""
+    return 2.0 * rows * seq * 3 * cfg["hidden_size"] * cfg["intermediate_size"]
 
 
 def forward_flops(cfg: dict, rows: int, seq: int) -> float:
@@ -42,21 +48,17 @@ def forward_flops(cfg: dict, rows: int, seq: int) -> float:
     positions."""
     d = cfg["hidden_size"]
     t = cfg["denoiser"]["time_embed_dim"]
-    nh, hd = cfg["num_attention_heads"], cfg["head_dim"]
-    tokens = rows * seq
-    total = 2.0 * tokens * 2 * d * d                 # in_proj + eps head
+    total = 2.0 * rows * seq * 2 * d * d             # in_proj + eps head
     total += 2.0 * rows * (t * d + d * d)            # time MLP
     for kind, count in cfg["layer_types"]:
-        window = cfg["attn_window_size"] if kind.endswith("_swa") else 0
-        total += count * 2.0 * tokens * _layer_matmul_params(cfg, kind)
-        total += count * 4.0 * rows * nh * hd * attention_pairs(seq, window)
+        total += count * loader.layer(kind).matmul_flops(cfg, rows, seq)
     return total
 
 
 def flash_attention_call(cfg: dict, rows: int, seq: int, window: int,
                          dtype_bytes: int = 2) -> tuple[float, float]:
-    """(flops, bytes) of one flash-attention call over one layer: q and
-    the output at every head, k and v at every kv head, each read or
+    """(flops, bytes) of one flash-attention call over one GQA layer: q
+    and the output at every head, k and v at every kv head, each read or
     written once."""
     nh, kv, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
     flops = 4.0 * rows * nh * hd * attention_pairs(seq, window)
@@ -64,8 +66,20 @@ def flash_attention_call(cfg: dict, rows: int, seq: int, window: int,
     return flops, nbytes
 
 
+def flash_work(cfg: dict, rows: int, seq: int) -> list[tuple[float, float, int]]:
+    """(flops, bytes, layer count) of each flash-attention call of one
+    forward, in layer order, as each kind's module counts it
+    (``flash_calls``)."""
+    return [
+        (f, b, count)
+        for kind, count in cfg["layer_types"]
+        for f, b in loader.layer(kind).flash_calls(cfg, rows, seq)
+    ]
+
+
 def flash_calls_per_nfe(cfg: dict) -> list[tuple[int, int]]:
-    """(window, layer count) of the attention calls of one forward."""
+    """(window, layer count) of the GQA attention calls of one forward:
+    a kind named ``*_swa`` attends over ``attn_window_size``."""
     out = []
     for kind, count in cfg["layer_types"]:
         out.append((cfg["attn_window_size"] if kind.endswith("_swa") else 0, count))

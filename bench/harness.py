@@ -3,8 +3,9 @@
 ``run.py`` parses the command line and calls :func:`run_cell`.  The order
 of a run:
 
-1. find the cell's files; fail before any work unless JAX's devices are
-   the chips the cell asks for, of a kind ``bench/peaks.json`` knows;
+1. find the cell's files, the layer modules of its configuration's kinds
+   among them; fail before any work unless JAX's devices are the chips the
+   cell asks for, of a kind ``bench/peaks.json`` knows;
 2. turn on the persistent compilation cache
    (``repro.serving.configure_persistent_cache``: ``$JAX_COMPILATION_CACHE_DIR``
    or ``.jax_cache`` in the checkout);
@@ -176,6 +177,7 @@ def measure(cell: str, seed: int, seconds: float, trace: bool,
     spec = loader.traffic(w["traffic"])
     divisor = int(w.get("rehearse", {}).get("seq_divisor", 1)) if rehearse else 1
     run_cfg = dict(cfg_file, **cfg_file["smoke"]) if rehearse else dict(cfg_file)
+    loader.layers(run_cfg)      # a kind with no module stops the run here
 
     devices = check_devices(int(w["chips"]), rehearse)
     _listen()

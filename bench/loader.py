@@ -5,12 +5,20 @@
 engine settings and correctness check), which names
 ``bench/configs/<config>.json`` and ``bench/traffic/<traffic>.json``.  Its
 entry is ``bench/entries/<entry>.py`` and each metric has a reader,
-``bench/metrics/<metric>.py``.  Adding a cell or a metric adds files; no
-file here changes.
+``bench/metrics/<metric>.py``.  Each kind in a configuration's
+``layer_types`` has a module, ``bench/layers/<kind>.py``, that holds its
+reference, its work counts and the program keys it is held to
+(``bench/layers/__init__.py`` gives the interface).
+
+Adding a cell or a metric adds files; no file here changes.  So does a
+configuration of a new architecture: ``bench/configs/<config>.json``, one
+``bench/layers/<kind>.py`` for each kind the benchmark has no module for,
+and its workload and traffic files.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -78,6 +86,18 @@ def entry(name: str):
 def metric(name: str):
     """A metric's reader: ``read(record, trace) -> float | None``."""
     return _module("metrics", name)
+
+
+@functools.cache
+def layer(kind: str):
+    """A layer kind's module (``bench/layers/__init__.py``); an unknown
+    kind is an error that names the missing file."""
+    return _module("layers", kind)
+
+
+def layers(cfg: dict) -> dict:
+    """The module of every kind in ``cfg["layer_types"]``, by kind."""
+    return {kind: layer(kind) for kind, _count in cfg["layer_types"]}
 
 
 def cell_metrics(bench: dict, cell: str) -> tuple[list[dict], list[dict]]:

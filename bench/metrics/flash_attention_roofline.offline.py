@@ -15,10 +15,8 @@ def read(record, trace):
         return None
     cfg, pk = record["config"], record["peaks"]
     c = common.chunks(record)[0]
-    calls = flops.flash_calls_per_nfe(cfg)
+    calls = flops.flash_work(cfg, c["padded_batch"], c["padded_seq_len"])
     least = sum(
-        n * max(f / pk["bf16_flops_per_s"], b / pk["hbm_bytes_per_s"])
-        for window, n in calls
-        for f, b in [flops.flash_attention_call(cfg, c["padded_batch"], c["padded_seq_len"], window)]
-    ) / sum(n for _, n in calls)
+        n * max(f / pk["bf16_flops_per_s"], b / pk["hbm_bytes_per_s"]) for f, b, n in calls
+    ) / sum(n for _, _, n in calls)
     return 100.0 * k["count"] * least / k["s"]

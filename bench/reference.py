@@ -7,15 +7,11 @@ under the parameter names the program uses, which the benchmark checks leaf
 by leaf against the program's own parameter shapes.
 
 * Denoiser: ``eps(x_t, t) = head(stack(in_proj(x_t) + time_mlp(t))) +
-  x_t``.  The stack runs bidirectionally.  A dense layer (Qwen2) is
-  pre-norm GQA attention with q/k/v biases and rotate-half RoPE, then a
-  SwiGLU MLP.  A Hymba layer runs attention heads and Mamba heads side by
-  side on the same normed input and averages their separately normed
-  outputs, then the MLP.  A window layer of the published (causal) model
-  sees the ``window`` positions up to its own; run bidirectionally it sees
-  them on both sides, keys with ``|q - k| < window``.
-  The Mamba heads are a selective scan written as a plain ``lax.scan``
-  over positions.
+  x_t``.  The stack runs bidirectionally, each layer by the ``reference``
+  of its kind's module, ``bench/layers/<kind>.py`` (found through
+  ``loader.layer``), which builds on the primitives here: ``mm``,
+  ``rmsnorm``, ``linear``, ``rope``, GQA ``attention`` (with an optional
+  window: keys with ``|q - k| < window``) and the SwiGLU ``mlp``.
 * Sampler: ERA-Solver (Algorithm 1 of arXiv:2301.12935) with per-sample
   error-robust selection, order-4 Adams-Moulton corrector, the DDIM update
   and the linear-beta VP schedule on a uniform grid, stepped on the host in
@@ -34,6 +30,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import loader
 
 HIGHEST = jax.lax.Precision.HIGHEST
 NEG_INF = -1e30
@@ -57,7 +55,7 @@ def _round8(x):
     return (x / scale).astype(FP8).astype(jnp.float32) * scale
 
 
-def _mm(a, b, precision: str, spec: str | None = None):
+def mm(a, b, precision: str, spec: str | None = None):
     a = a.astype(jnp.float32)
     b = b.astype(jnp.float32)
     if precision == "fp8":
@@ -67,16 +65,16 @@ def _mm(a, b, precision: str, spec: str | None = None):
     return jnp.einsum(spec, a, b, precision=HIGHEST)
 
 
-def _rmsnorm(scale, x, eps):
+def rmsnorm(scale, x, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
 
-def _linear(p, x, precision):
-    y = _mm(x, p["w"], precision)
+def linear(p, x, precision):
+    y = mm(x, p["w"], precision)
     return y + p["b"] if "b" in p else y
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """Rotate-half RoPE over positions 0..S-1; x: (B, S, H, hd)."""
     hd, s = x.shape[-1], x.shape[1]
     freqs = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
@@ -86,86 +84,31 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _attention(p, h, cfg, window, precision):
+def attention(p, h, cfg, window, precision):
+    """GQA attention with rotate-half RoPE over all positions, or with
+    ``window > 0`` over keys with ``|q - k| < window``."""
     b, s, _ = h.shape
     nh, kv, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
-    q = _linear(p["wq"], h, precision).reshape(b, s, nh, hd)
-    k = _linear(p["wk"], h, precision).reshape(b, s, kv, hd)
-    v = _linear(p["wv"], h, precision).reshape(b, s, kv, hd)
-    q, k = _rope(q, cfg["rope_theta"]), _rope(k, cfg["rope_theta"])
+    q = linear(p["wq"], h, precision).reshape(b, s, nh, hd)
+    k = linear(p["wk"], h, precision).reshape(b, s, kv, hd)
+    v = linear(p["wv"], h, precision).reshape(b, s, kv, hd)
+    q, k = rope(q, cfg["rope_theta"]), rope(k, cfg["rope_theta"])
     g = nh // kv
     q = q.reshape(b, s, kv, g, hd)
-    scores = _mm(q, k, precision, "bqkgd,bskd->bkgqs") / math.sqrt(hd)
+    scores = mm(q, k, precision, "bqkgd,bskd->bkgqs") / math.sqrt(hd)
     if window > 0:
         pos = jnp.arange(s)
         allowed = jnp.abs(pos[None, :] - pos[:, None]) < window     # (q, k)
         scores = jnp.where(allowed, scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
-    out = _mm(w, v, precision, "bkgqs,bskd->bqkgd").reshape(b, s, nh * hd)
-    return _linear(p["wo"], out, precision)
+    out = mm(w, v, precision, "bkgqs,bskd->bqkgd").reshape(b, s, nh * hd)
+    return linear(p["wo"], out, precision)
 
 
-def _mlp(p, h, precision):
-    gate = jax.nn.silu(_linear(p["wg"], h, precision))
-    return _linear(p["wo"], gate * _linear(p["wi"], h, precision), precision)
-
-
-def _mamba(p, h, cfg, precision):
-    """Selective SSM: h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t, y_t = C_t h_t."""
-    n = cfg["mamba_d_state"]
-    dtr = cfg["mamba_dt_rank"]
-    x, z = jnp.split(_linear(p["in_proj"], h, precision), 2, axis=-1)
-    width = p["conv"]["w"].shape[0]
-    xp = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
-    s = x.shape[1]
-    x = sum(xp[:, i : i + s] * p["conv"]["w"][i] for i in range(width))
-    x = jax.nn.silu(x + p["conv"]["b"])
-    proj = _linear(p["x_proj"], x, precision)
-    dt, bmat, cmat = jnp.split(proj, [dtr, dtr + n], axis=-1)
-    dt = jax.nn.softplus(_linear(p["dt_proj"], dt, precision))      # (B,S,di)
-    a = -jnp.exp(p["A_log"])                                         # (di,N)
-
-    def step(state, inp):
-        dt_t, x_t, b_t, c_t = inp                   # (B,di) (B,di) (B,N) (B,N)
-        state = jnp.exp(dt_t[..., None] * a) * state + (
-            (dt_t * x_t)[..., None] * b_t[:, None, :]
-        )
-        return state, jnp.einsum("bdn,bn->bd", state, c_t, precision=HIGHEST)
-
-    state0 = jnp.zeros((x.shape[0], x.shape[2], n), jnp.float32)
-    seq = tuple(jnp.moveaxis(t, 1, 0) for t in (dt, x, bmat, cmat))
-    _, ys = jax.lax.scan(step, state0, seq, unroll=8)
-    y = jnp.moveaxis(ys, 0, 1) + x * p["D"]
-    return _linear(p["out_proj"], y * jax.nn.silu(z), precision)
-
-
-def _dense_layer(p, x, cfg, precision):
-    eps = cfg["rms_norm_eps"]
-    x = x + _attention(p["attn"], _rmsnorm(p["ln1"]["scale"], x, eps), cfg, 0, precision)
-    return x + _mlp(p["mlp"], _rmsnorm(p["ln2"]["scale"], x, eps), precision)
-
-
-def _hymba_layer(p, x, cfg, window, precision):
-    eps = cfg["rms_norm_eps"]
-    h = _rmsnorm(p["ln1"]["scale"], x, eps)
-    attn = _attention(p["attn"], h, cfg, window, precision)
-    ssm = _mamba(p["mamba"], h, cfg, precision)
-    x = x + 0.5 * (
-        _rmsnorm(p["attn_norm"]["scale"], attn, eps)
-        + _rmsnorm(p["mamba_norm"]["scale"], ssm, eps)
-    )
-    return x + _mlp(p["mlp"], _rmsnorm(p["ln2"]["scale"], x, eps), precision)
-
-
-def _layer_fn(kind: str, cfg: dict, precision: str):
-    if kind == "dense":
-        return lambda p, x: _dense_layer(p, x, cfg, precision)
-    if kind == "hymba_full":
-        return lambda p, x: _hymba_layer(p, x, cfg, 0, precision)
-    if kind == "hymba_swa":
-        window = cfg["attn_window_size"]
-        return lambda p, x: _hymba_layer(p, x, cfg, window, precision)
-    raise ValueError(f"no reference for layer kind {kind!r}")
+def mlp(p, h, precision):
+    """SwiGLU: ``wo(silu(wg h) * wi h)``."""
+    gate = jax.nn.silu(linear(p["wg"], h, precision))
+    return linear(p["wo"], gate * linear(p["wi"], h, precision), precision)
 
 
 def _time_embed(t, dim):
@@ -179,14 +122,15 @@ def eps(params, x, t, cfg: dict, precision: str = "f32"):
     """eps_theta(x_t, t) for x (B, S, d) float32 and a scalar t."""
     tm = params["time_mlp"]
     tcond = _time_embed(t, cfg["denoiser"]["time_embed_dim"])[None]
-    tcond = _linear(tm["w2"], jax.nn.silu(_linear(tm["w1"], tcond, precision)), precision)
-    h = _linear(params["in_proj"], x, precision) + tcond[:, None, :]
+    tcond = linear(tm["w2"], jax.nn.silu(linear(tm["w1"], tcond, precision)), precision)
+    h = linear(params["in_proj"], x, precision) + tcond[:, None, :]
     segs = params["backbone"]["segs"]
     for i, (kind, _count) in enumerate(cfg["layer_types"]):
-        layer = _layer_fn(kind, cfg, precision)
-        h, _ = jax.lax.scan(lambda c, p: (layer(p, c), None), h, segs[f"{i}_{kind}"])
-    h = _rmsnorm(params["backbone"]["final_norm"]["scale"], h, cfg["rms_norm_eps"])
-    return _linear(params["eps_head"], h, precision) + x
+        layer = loader.layer(kind).reference
+        h, _ = jax.lax.scan(lambda c, p: (layer(p, c, cfg, precision), None), h,
+                            segs[f"{i}_{kind}"])
+    h = rmsnorm(params["backbone"]["final_norm"]["scale"], h, cfg["rms_norm_eps"])
+    return linear(params["eps_head"], h, precision) + x
 
 
 @functools.lru_cache(maxsize=None)

@@ -8,7 +8,12 @@ one, biases at zero, ``A_log`` normal(0, 0.5), the depthwise conv
 normal(0, 0.1), every other matrix normal(0, 1/fan_in) with fan_in its
 input width, and the eps head at ``eps_head_gain`` (a random-weight sampler
 is chaotic with a larger head).  The embedding table (and Hymba's meta
-tokens) are not on the denoiser's path and are left at zero.
+tokens) are not on the denoiser's path and are left at zero.  A leaf that
+none of these rules covers is drawn by its layer kind's ``init_leaf``
+(``bench/layers``), where the kind gives one.
+
+``program_config`` holds the program to the configuration file's widths:
+the keys compared here, and each layer kind's ``program_keys``.
 """
 
 from __future__ import annotations
@@ -16,6 +21,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import loader
 
 UNUSED = ("embed", "meta", "lm_head")
 
@@ -47,13 +54,8 @@ def program_config(bench_cfg: dict, rehearse: bool):
         "compute_dtype": jnp.dtype(cfg.dtype).name,
         "param_dtype": jnp.dtype(cfg.param_dtype).name,
     }
-    if cfg.ssm is not None:
-        got.update(
-            mamba_d_state=cfg.ssm.state_dim,
-            mamba_d_conv=cfg.ssm.conv_dim,
-            mamba_expand=cfg.ssm.expand,
-            mamba_dt_rank=cfg.ssm.dt_rank or -(-cfg.d_model // 16),
-        )
+    for mod in loader.layers(want).values():
+        got.update(mod.program_keys(cfg))
     want = dict(want, **{k: want["denoiser"][k] for k in ("compute_dtype", "param_dtype")})
     wrong = {k: (v, want.get(k)) for k, v in got.items() if v != want.get(k)}
     if wrong:
@@ -82,6 +84,10 @@ def _init_leaf(names, shape, key, gain):
         return jax.random.normal(key, shape, jnp.float32) * 0.1
     if last == "w":
         return jax.random.normal(key, shape, jnp.float32) / np.sqrt(shape[-2])
+    if names[:2] == ("backbone", "segs"):
+        mod = loader.layer(names[2].split("_", 1)[1])       # segment "<i>_<kind>"
+        if hasattr(mod, "init_leaf"):
+            return mod.init_leaf(names, shape, key, gain)
     raise ValueError(f"no rule for parameter {'/'.join(names)}")
 
 
