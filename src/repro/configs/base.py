@@ -16,30 +16,49 @@ import jax.numpy as jnp
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int               # the router's outputs: every expert
     top_k: int
     d_ff_expert: int
     num_shared: int = 0            # always-active shared experts (DeepSeek)
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True    # renormalise the top-k weights to sum to 1
+    # the experts this device holds: ids first_expert ..
+    # first_expert + experts_held - 1 (0 => all).  The router still scores
+    # every expert; assignments to the others are left to the devices that
+    # hold them (expert parallelism without its exchange).
+    first_expert: int = 0
+    experts_held: int = 0
+    capacity_factor: float = 1.25  # "dropping" only
     dispatch_group: int = 4096     # tokens per capacity group (§Perf: caps
                                    # the (E, C, d) dispatch buffer size)
     router_z_loss: float = 1e-3
     aux_loss_weight: float = 1e-2
-    # dispatch strategy: "dropping" (scatter, default), "dense_mix"
-    # (all-experts reference, smoke/oracle only), "expert_parallel"
-    # (shard_map all-to-all — perf path)
+    # dispatch strategy: "dropping" (capacity scatter, tokens over capacity
+    # dropped), "dropless" (every assignment to a held expert runs, as
+    # ragged products), "dense_mix" (every held expert on every token; the
+    # oracle of the tests)
     dispatch: str = "dropping"
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-V2 Multi-head Latent Attention."""
+    """DeepSeek-V2 Multi-head Latent Attention, with YaRN RoPE scaling
+    (``rope_factor`` 1 => plain RoPE and the plain softmax scale)."""
 
     kv_lora_rank: int = 512
     q_lora_rank: int = 0           # 0 => full-rank q projection (V2-Lite)
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    rope_factor: float = 1.0
+    rope_original_max_position: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -157,12 +176,17 @@ class ModelConfig:
             attn_chunk=64,
         )
         if self.moe:
+            e = min(self.moe.num_experts, 4)
+            # a share of the experts stays a share: the first half
+            partial = self.moe.held < self.moe.num_experts
             kw["moe"] = dataclasses.replace(
                 self.moe,
-                num_experts=min(self.moe.num_experts, 4),
+                num_experts=e,
                 top_k=min(self.moe.top_k, 2),
                 d_ff_expert=min(self.moe.d_ff_expert, 128),
                 num_shared=min(self.moe.num_shared, 1),
+                first_expert=0,
+                experts_held=e // 2 if partial else 0,
             )
         if self.mla:
             kw["mla"] = dataclasses.replace(

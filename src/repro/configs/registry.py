@@ -18,6 +18,7 @@ _ARCHS = [
     "qwen2_1_5b",
     "whisper_base",
     "deepseek_v2_lite",
+    "deepseek_v2_lite_ep8",
     "xlstm_350m",
     "mixtral_8x7b",
     "deepseek_67b",
@@ -32,6 +33,7 @@ ALIASES = {
     "qwen2-1.5b": "qwen2_1_5b",
     "whisper-base": "whisper_base",
     "deepseek-v2-lite-16b": "deepseek_v2_lite",
+    "deepseek-v2-lite-16b-ep8": "deepseek_v2_lite_ep8",
     "xlstm-350m": "xlstm_350m",
     "mixtral-8x7b": "mixtral_8x7b",
     "deepseek-67b": "deepseek_67b",

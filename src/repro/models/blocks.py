@@ -100,8 +100,32 @@ def moe_apply(p, x, cache, ctx: BlockCtx, cfg):
 
 
 # ---------------------------------------------------------------------------
-# mla_moe (deepseek-v2-lite)
+# mla_dense / mla_moe (deepseek-v2-lite: a leading dense layer, then MoE)
 # ---------------------------------------------------------------------------
+
+
+def _mla(p, h, cache, ctx: BlockCtx, cfg):
+    if ctx.mode == "decode":
+        return MLA.mla_decode(p, h, cfg, cache, ctx.pos)
+    return MLA.mla_train(
+        p, h, cfg, ctx.mode, cache, lengths=ctx.lengths, causal=ctx.causal
+    )
+
+
+def mla_dense_specs(cfg) -> dict:
+    return {
+        "ln1": L.rmsnorm_specs(cfg.d_model),
+        "mla": MLA.mla_specs(cfg),
+        "ln2": L.rmsnorm_specs(cfg.d_model),
+        "mlp": L.mlp_specs(cfg.d_model, cfg.d_ff, cfg.mlp_act),
+    }
+
+
+def mla_dense_apply(p, x, cache, ctx: BlockCtx, cfg):
+    attn_out, cache = _mla(p["mla"], L.rmsnorm(p["ln1"], x, cfg.norm_eps), cache, ctx, cfg)
+    x = x + attn_out
+    h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
+    return x + L.mlp(p["mlp"], h, cfg.mlp_act), cache, zero_aux()
 
 
 def mla_moe_specs(cfg) -> dict:
@@ -114,13 +138,7 @@ def mla_moe_specs(cfg) -> dict:
 
 
 def mla_moe_apply(p, x, cache, ctx: BlockCtx, cfg):
-    h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-    if ctx.mode == "decode":
-        attn_out, cache = MLA.mla_decode(p["mla"], h, cfg, cache, ctx.pos)
-    else:
-        attn_out, cache = MLA.mla_train(
-            p["mla"], h, cfg, ctx.mode, cache, lengths=ctx.lengths
-        )
+    attn_out, cache = _mla(p["mla"], L.rmsnorm(p["ln1"], x, cfg.norm_eps), cache, ctx, cfg)
     x = x + attn_out
     h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
     ffn_out, aux = MOE.moe_ffn(p["moe"], h, cfg)
@@ -343,6 +361,7 @@ class BlockDef:
 BLOCKS: dict[str, BlockDef] = {
     "dense": BlockDef(dense_specs, dense_apply, _attn_cache),
     "moe": BlockDef(moe_specs_, moe_apply, _attn_cache),
+    "mla_dense": BlockDef(mla_dense_specs, mla_dense_apply, _mla_cache),
     "mla_moe": BlockDef(mla_moe_specs, mla_moe_apply, _mla_cache),
     "mlstm": BlockDef(SSM.mlstm_specs, mlstm_apply, _mlstm_cache),
     "slstm": BlockDef(SSM.slstm_specs, slstm_apply, _slstm_cache),

@@ -28,7 +28,7 @@ Array = jax.Array
 #:
 #: * **maskable attention** — every cross-position mixing is an attention
 #:   softmax that takes the per-row kv_mask (dense / moe / enc / hymba_* /
-#:   mla_moe attention halves, xdec self-attention): pad keys get an exact
+#:   mla_* attention halves, xdec self-attention): pad keys get an exact
 #:   ``-1e30`` bias, valid keys an exact ``+0.0``.  All three SDPA impls
 #:   (naive / chunked / pallas+banded flash kernels) carry the mask
 #:   natively, so fused masked batches stay on the fast kernels.
@@ -44,7 +44,7 @@ MASKABLE_BLOCKS = frozenset(
     {
         "dense", "moe", "enc", "xdec",
         "mlstm", "slstm", "hymba_swa", "hymba_full",
-        "mla_moe",
+        "mla_dense", "mla_moe",
     }
 )
 

@@ -154,10 +154,11 @@ def rope_freqs(head_dim: int, theta: float) -> Array:
     )
 
 
-def apply_rope(x: Array, positions: Array, theta: float) -> Array:
-    """x: (..., seq, heads, head_dim); positions: (..., seq) int."""
+def apply_rope(x: Array, positions: Array, theta: float, inv_freq=None) -> Array:
+    """x: (..., seq, heads, head_dim); positions: (..., seq) int.
+    ``inv_freq`` ((head_dim/2,)) replaces the plain frequencies (YaRN)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    freqs = rope_freqs(hd, theta) if inv_freq is None else inv_freq  # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     cos = jnp.cos(angles)[..., None, :]                 # (..., S, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]

@@ -11,12 +11,19 @@ Two execution forms:
   the output so attention runs directly in latent space against the
   compressed cache.  This is the production DeepSeek serving trick and our
   paper-faithful baseline for decode shapes.
+
+Both forms share the YaRN RoPE of the rope dims (``rope_inv_freq``) and
+the softmax gain ``mscale(mscale_all_dim)**2`` (``softmax_gain``), which
+scales the queries so that the attention kernels keep their 1/sqrt(hd).
 """
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models import layers as L
 from repro.models.attention import resolve_impl, sdpa
@@ -39,14 +46,55 @@ def mla_specs(cfg) -> dict:
     }
 
 
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_inv_freq(a, theta: float) -> np.ndarray:
+    """Inverse frequencies of the rope dims: YaRN's blend of the plain ones
+    (kept for dims that turn more than ``rope_beta_fast`` times over the
+    original context) and the plain ones over ``rope_factor`` (for dims
+    that turn fewer than ``rope_beta_slow`` times), on a linear ramp
+    between the two correction dims."""
+    dim = a.qk_rope_head_dim
+    base = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if a.rope_factor <= 1:
+        return base.astype(np.float32)
+
+    def correction_dim(rotations):
+        turns = a.rope_original_max_position / (rotations * 2 * math.pi)
+        return dim * math.log(turns) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(a.rope_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(a.rope_beta_slow)), dim - 1)
+    high = high + 0.001 if low == high else high
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    return (base / a.rope_factor * ramp + base * (1 - ramp)).astype(np.float32)
+
+
+def softmax_gain(a) -> float:
+    """YaRN's softmax temperature, mscale(mscale_all_dim)**2 (1 without)."""
+    if not a.rope_mscale_all_dim:
+        return 1.0
+    return _yarn_mscale(a.rope_factor, a.rope_mscale_all_dim) ** 2
+
+
+def _rope(x, positions, cfg):
+    a = cfg.mla
+    out = L.apply_rope(x, positions, cfg.rope_theta,
+                       inv_freq=rope_inv_freq(a, cfg.rope_theta))
+    amp = (_yarn_mscale(a.rope_factor, a.rope_mscale)
+           / _yarn_mscale(a.rope_factor, a.rope_mscale_all_dim))
+    return out if amp == 1.0 else out * jnp.asarray(amp, out.dtype)
+
+
 def _project_q(p, x, cfg, positions):
     a = cfg.mla
     b, s, _ = x.shape
     h = cfg.num_heads
     q = L.linear(p["wq"], x).reshape(b, s, h, a.qk_nope_head_dim + a.qk_rope_head_dim)
     q_nope, q_rope = jnp.split(q, [a.qk_nope_head_dim], axis=-1)
-    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta)
-    return q_nope, q_rope
+    return q_nope, _rope(q_rope, positions, cfg)
 
 
 def _compress_kv(p, x, cfg, positions):
@@ -54,43 +102,49 @@ def _compress_kv(p, x, cfg, positions):
     kv_a = L.linear(p["wkv_a"], x)
     c_kv, k_rope = jnp.split(kv_a, [a.kv_lora_rank], axis=-1)
     c_kv = L.rmsnorm(p["ckv_norm"], c_kv, cfg.norm_eps)
-    k_rope = L.apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)
+    k_rope = _rope(k_rope[:, :, None, :], positions, cfg)
     return c_kv, k_rope[:, :, 0, :]          # (B,S,r), (B,S,rope)
 
 
-def mla_train(p, x: Array, cfg, mode: str = "train", cache=None, lengths=None):
+def mla_train(p, x: Array, cfg, mode: str = "train", cache=None, lengths=None,
+              causal: bool = True):
     """Full-sequence MLA (train / prefill). Returns (out, cache).
 
-    ``lengths`` ((B,) int32) marks right-padding: pad keys are masked out
-    of every row's softmax (the attention path is causal, so valid rows
-    never see pad keys anyway — the mask makes the guarantee explicit and
-    keeps MLA on the same mixed-seq-len contract as GQA attention)."""
+    ``causal=False`` lets every position attend to every other (the
+    diffusion denoiser).  ``lengths`` ((B,) int32) marks right-padding: pad
+    keys are masked out of every row's softmax, so a valid position's
+    output is the unpadded run's, causal or not."""
     a = cfg.mla
     b, s, _ = x.shape
     h = cfg.num_heads
     positions = jnp.arange(s, dtype=jnp.int32)
     kv_mask = None if lengths is None else positions[None, :] < lengths[:, None]
 
-    q_nope, q_rope = _project_q(p, x, cfg, positions)
-    c_kv, k_rope = _compress_kv(p, x, cfg, positions)
+    with jax.named_scope("mla.attention"):
+        q_nope, q_rope = _project_q(p, x, cfg, positions)
+        c_kv, k_rope = _compress_kv(p, x, cfg, positions)
 
-    kv = L.linear(p["wkv_b"], c_kv).reshape(
-        b, s, h, a.qk_nope_head_dim + a.v_head_dim
-    )
-    k_nope, v = jnp.split(kv, [a.qk_nope_head_dim], axis=-1)
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], q_rope.shape[:2] + (h, a.qk_rope_head_dim))],
-        axis=-1,
-    )
-    # pad v to qk head dim for the shared sdpa, then slice back
-    out = sdpa(
-        q, k, jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, q.shape[-1] - v.shape[-1]))),
-        positions, positions,
-        window=0, causal=True, softcap=0.0,
-        impl=resolve_impl(cfg, s, s),
-        chunk=cfg.attn_chunk, kv_mask=kv_mask,
-    )[..., : a.v_head_dim]
+        kv = L.linear(p["wkv_b"], c_kv).reshape(
+            b, s, h, a.qk_nope_head_dim + a.v_head_dim
+        )
+        k_nope, v = jnp.split(kv, [a.qk_nope_head_dim], axis=-1)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        gain = softmax_gain(a)
+        if gain != 1.0:
+            q = (q.astype(jnp.float32) * gain).astype(q_nope.dtype)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], q_rope.shape[:2] + (h, a.qk_rope_head_dim))],
+            axis=-1,
+        )
+        # pad v to qk head dim for the shared sdpa, then slice back
+        out = sdpa(
+            q, k, jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, q.shape[-1] - v.shape[-1]))),
+            positions, positions,
+            window=0, causal=causal, softcap=0.0,
+            impl=resolve_impl(cfg, s, s),
+            chunk=cfg.attn_chunk, kv_mask=kv_mask,
+        )[..., : a.v_head_dim]
+        out = L.linear(p["wo"], out.reshape(b, s, -1))
 
     if mode == "prefill":
         assert cache is not None
@@ -108,7 +162,7 @@ def mla_train(p, x: Array, cfg, mode: str = "train", cache=None, lengths=None):
                 cache["pos"], pos_arr, 0, axis=0
             ),
         }
-    return L.linear(p["wo"], out.reshape(b, s, -1)), cache
+    return out, cache
 
 
 def mla_decode(p, x: Array, cfg, cache: dict, pos: Array):
@@ -146,7 +200,7 @@ def mla_decode(p, x: Array, cfg, cache: dict, pos: Array):
 
     ckv = cache["ckv"]                          # (B, S, r)
     krope = cache["krope"]                      # (B, S, rope)
-    scale = (a.qk_nope_head_dim + a.qk_rope_head_dim) ** -0.5
+    scale = (a.qk_nope_head_dim + a.qk_rope_head_dim) ** -0.5 * softmax_gain(a)
     scores = (
         jnp.einsum("bshr,btr->bhst", q_lat, ckv.astype(q_lat.dtype))
         + jnp.einsum("bshr,btr->bhst", q_rope, krope.astype(q_rope.dtype))

@@ -1,17 +1,27 @@
 """Mixture-of-Experts layer (Mixtral top-2, DeepSeek shared+routed top-6).
 
+The router scores every expert (``num_experts``); a device holds the
+experts ``first_expert .. first_expert + experts_held - 1`` (all of them
+by default) and computes their part of the result.  That is one rank of
+expert parallelism, run without its exchange: on one chip the other
+experts' assignments are left to the ranks that would hold them.
+
 Dispatch strategies:
 
-* ``dropping`` (default) — capacity-based token dispatch realized with
+* ``dropless`` — every assignment to a held expert runs: assignments are
+  sorted by expert and the expert FFN runs as three ``jax.lax.ragged_dot``
+  products with per-expert group sizes (on TPU their time follows the held
+  rows, not the operand's), then each token's k outputs are gathered back
+  and summed with the top-k weights.  No capacity, so a token's output depends on its own
+  routing alone, never on its batch-mates or on padding (DeepSeek-V2).
+* ``dropping`` — capacity-based token dispatch realized with
   scatter/gather per batch group (TPU adaptation: no giant one-hot dispatch
   einsum, so compiled FLOPs stay honest — dispatch moves bytes, the expert
   FFN does the FLOPs).  Tokens over capacity are dropped (residual passes
-  through), the standard TPU training recipe.
-* ``dense_mix`` — every expert runs on every token, outputs mixed by router
-  probs.  O(E) FLOPs; used as the correctness oracle in tests and for tiny
-  smoke configs.
-* ``expert_parallel`` — shard_map + all_to_all path (see
-  repro/parallel/expert_parallel.py); a §Perf optimization.
+  through), the standard TPU training recipe.  Holds every expert.
+* ``dense_mix`` — every held expert runs on every token, outputs mixed by
+  router probs.  O(E) FLOPs; used as the correctness oracle in tests and
+  for tiny smoke configs.
 
 Router math is float32 throughout (bf16 routers destabilize top-k).
 """
@@ -31,9 +41,9 @@ def moe_specs(cfg) -> dict:
     m = cfg.moe
     d = cfg.d_model
     ff = m.d_ff_expert
-    e = m.num_experts
+    e = m.held
     s = {
-        "router": {"w": L.P((d, e), "fan_in")},
+        "router": L.P((d, m.num_experts), "fan_in"),
         "experts": {
             "wi": L.P((e, d, ff), "fan_in"),
             "wg": L.P((e, d, ff), "fan_in"),
@@ -47,10 +57,11 @@ def moe_specs(cfg) -> dict:
 
 def _router(p, x: Array, m) -> tuple[Array, Array, dict]:
     """Return (weights (..., k), ids (..., k), aux losses)."""
-    logits = (x.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32))
+    logits = x.astype(jnp.float32) @ p["router"].astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     weights, ids = jax.lax.top_k(probs, m.top_k)
-    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if m.norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     # Switch-style load-balance loss + router z-loss
     e = m.num_experts
     density = jnp.mean(
@@ -133,28 +144,65 @@ def _combine_group(out_buf: Array, meta: tuple, s: int) -> Array:
     )
 
 
+def _held_ids(ids: Array, m) -> Array:
+    """Each assignment's expert among the held ones, ``m.held`` where the
+    expert is held elsewhere."""
+    local = ids - m.first_expert
+    return jnp.where((local >= 0) & (local < m.held), local, m.held)
+
+
 def _dense_mix(p, x: Array, m) -> tuple[Array, dict]:
-    """Reference: run all experts on all tokens. x: (..., d)."""
+    """Reference: run every held expert on all tokens. x: (..., d)."""
     weights, ids, aux = _router(p, x, m)
-    e = m.num_experts
     d = x.shape[-1]
-    flat = jnp.broadcast_to(x.reshape(1, -1, d), (e, x.size // d, d))
-    outs = _expert_ffn(p["experts"], flat)        # (E, N, d)
-    outs = outs.reshape((e,) + x.shape)           # (E, ..., d)
-    sel = jnp.take_along_axis(
-        jnp.moveaxis(outs, 0, -2),                # (..., E, d)
-        ids[..., None],                           # (..., k, 1)
-        axis=-2,
-    )                                             # (..., k, d)
-    mix = jnp.sum(sel * weights[..., None].astype(x.dtype), axis=-2)
-    return mix, aux
+    flat = x.reshape(-1, d)
+    outs = _expert_ffn(
+        p["experts"], jnp.broadcast_to(flat[None], (m.held,) + flat.shape)
+    )                                             # (H, N, d)
+    # (N, H) routing weight of each held expert, 0 where not chosen
+    gate = jnp.einsum(
+        "nk,nkh->nh",
+        weights.reshape(flat.shape[0], -1),
+        jax.nn.one_hot(_held_ids(ids, m).reshape(flat.shape[0], -1), m.held),
+    )
+    mix = jnp.einsum("nh,hnd->nd", gate.astype(x.dtype), outs)
+    return mix.reshape(x.shape), aux
+
+
+def _dropless(p, x: Array, m) -> tuple[Array, dict]:
+    """Every assignment to a held expert, as ragged products. x: (..., d)."""
+    d = x.shape[-1]
+    flat = x.reshape(-1, d)
+    n, k = flat.shape[0], m.top_k
+    with jax.named_scope("moe.route"):
+        weights, ids, aux = _router(p, flat, m)   # (N, k)
+        expert = _held_ids(ids, m)                # (N, k)
+        order = jnp.argsort(expert.reshape(-1), stable=True)  # others sort last
+        group_sizes = jnp.bincount(expert.reshape(-1), length=m.held + 1)
+    with jax.named_scope("moe.experts"):
+        e = p["experts"]
+        gs = group_sizes[: m.held].astype(jnp.int32)
+        xs = flat[order // k]                     # (N*k, d), grouped by expert
+        h = jax.nn.silu(
+            jax.lax.ragged_dot(xs, e["wg"].astype(x.dtype), gs)
+        ) * jax.lax.ragged_dot(xs, e["wi"].astype(x.dtype), gs)
+        ys = jax.lax.ragged_dot(h, e["wo"].astype(x.dtype), gs)
+        # back to each token's k slots; rows past the held groups are the
+        # backend's to fill (the reference lowering zeroes them), so they
+        # are selected away rather than trusted to be 0
+        ys = ys[jnp.argsort(order)].reshape(n, k, d).astype(jnp.float32)
+        held = (expert < m.held)[..., None]
+        out = jnp.sum(jnp.where(held, ys * weights[..., None], 0.0), axis=1)
+    return out.astype(x.dtype).reshape(x.shape), aux
 
 
 def moe_ffn(p, x: Array, cfg) -> tuple[Array, dict]:
     """x: (B, S, d) -> (B, S, d), plus aux losses."""
     m = cfg.moe
     b, s, d = x.shape
-    if m.dispatch == "dense_mix":
+    if m.dispatch == "dropless":
+        out, aux = _dropless(p, x, m)
+    elif m.dispatch == "dense_mix":
         out, aux = _dense_mix(p, x, m)
     elif m.dispatch == "dropping":
         # split long sequences into dispatch groups so the (E, C, d)
@@ -176,5 +224,6 @@ def moe_ffn(p, x: Array, cfg) -> tuple[Array, dict]:
     else:
         raise ValueError(f"unknown MoE dispatch {m.dispatch!r}")
     if m.num_shared:
-        out = out + L.mlp(p["shared"], x, "silu")
+        with jax.named_scope("moe.shared"):
+            out = out + L.mlp(p["shared"], x, "silu")
     return out, aux

@@ -57,7 +57,7 @@ def test_router_z_loss_scales_with_logits():
     """z-loss penalizes large router logits (keeps the router calibrated)."""
     cfg = get_config("mixtral-8x7b", smoke=True)
     p = L.init_params(moe_specs(cfg), jax.random.PRNGKey(0))
-    p_hot = dict(p, router={"w": p["router"]["w"] * 50.0})
+    p_hot = dict(p, router=p["router"] * 50.0)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, cfg.d_model))
     _, aux = moe_ffn(p, x, cfg)
     _, aux_hot = moe_ffn(p_hot, x, cfg)
@@ -82,6 +82,7 @@ def test_shared_experts_always_active():
 def test_decode_single_token_not_dropped():
     """top-k assignments of a single token always fit capacity."""
     cfg = get_config("deepseek-v2-lite-16b", smoke=True)
+    cfg = cfg.with_(moe=dataclasses.replace(cfg.moe, dispatch="dropping"))
     p = L.init_params(moe_specs(cfg), jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 1, cfg.d_model))
     dense_cfg = cfg.with_(moe=dataclasses.replace(cfg.moe, dispatch="dense_mix"))

@@ -89,7 +89,7 @@ def test_expert_parallel_for_deepseek():
     specs = rules.param_pspec(model.init_abstract())
     flat = dict(_leaves_with_paths(specs))
     # 64 experts / 16 shards -> expert-parallel
-    assert flat["segs/0_mla_moe/moe/experts/wi"] == P(None, "model", None, None)
+    assert flat["segs/1_mla_moe/moe/experts/wi"] == P(None, "model", None, None)
 
 
 def test_mixtral_experts_tensor_parallel():

@@ -1,6 +1,7 @@
 """Compile-only walls: the main path's Pallas kernels lower and compile for a
 described TPU v5e chip at qwen2-1.5b widths (the selective scan at
-hymba-1.5b's), with no chip attached.
+hymba-1.5b's, MLA's flash call and the dropless experts at
+deepseek-v2-lite's), with no chip attached.
 
 Nothing runs here; the TPU compiler (installed with libtpu) compiles for
 the described device and refuses what the chip would refuse: misaligned
@@ -113,6 +114,38 @@ def test_flash_attention_compiles_for_v5e(one_chip, masked, dtype):
 
     avals = (q, kv, kv, pos) + ((mask,) if masked else ())
     assert "tpu_custom_call" in _compiled_text(call, *avals)
+
+
+def test_mla_flash_attention_compiles_for_v5e(one_chip, monkeypatch):
+    """MLA's call at deepseek-v2-lite's widths: 16 heads, q/k head dim 192
+    and v padded up to it (``models/mla.py``), both padded to 256 here."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q = jax.ShapeDtypeStruct((2, 1024, 16, 192), jnp.bfloat16, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((1024,), jnp.int32, sharding=one_chip)
+
+    def call(q, k, v, pos):
+        return ops.flash_attention(q, k, v, pos, pos, causal=False)
+
+    assert "tpu_custom_call" in _compiled_text(call, q, q, q, pos)
+
+
+def test_dropless_experts_compile_for_v5e(one_chip):
+    """The held-experts layer at the deepseek-v2-lite.offline cell's shape
+    (16 x 1024 tokens, 8 of 64 experts of 1408 held, top-6, 2 shared):
+    its products compile to the chip's own ragged dot, not to the masked
+    dense contraction that other platforms lower ``ragged_dot`` to."""
+    from repro.configs import get_config
+    from repro.models import layers as L
+    from repro.models.moe import moe_ffn, moe_specs
+
+    cfg = get_config("deepseek-v2-lite-16b-ep8")
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        L.abstract_params(moe_specs(cfg)),
+    )
+    x = jax.ShapeDtypeStruct((16, 1024, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+    text = _compiled_text(lambda p, x: moe_ffn(p, x, cfg)[0], params, x)
+    assert text.count("ragged-dot") >= 3
 
 
 def _scan_avals(sharding, rows, seq, dtype, rep=None):
