@@ -2,10 +2,15 @@
 
 Canonical TPU online-softmax pattern: 3-D grid ``(batch*heads, q_blocks,
 kv_blocks)`` iterated sequentially on-core; the (acc, m, l) state lives in
-VMEM scratch and persists across the innermost kv dimension.  Blocks are
-MXU-aligned (q/kv block 128, head_dim padded to a multiple of 128 by the
-ops.py wrapper).  GQA is expressed in the k/v BlockSpec index maps (q head
-h reads kv head h // G), so no KV replication is materialized in HBM.
+VMEM scratch and persists across the innermost kv dimension.  The ops.py
+wrapper pads head_dim to a multiple of 128 and picks the tiles from the
+call's shape (``ops._flash_blocks``): q tiles up to 512 rows, and the whole
+key length as one kv block where such a tile fits the VMEM budget.  With
+one kv block the k/v block index stays the same from one grid step to the
+next, over the q tiles of a head and the G heads of a GQA group, so the
+pipeline fetches a head's K and V once, not once per q tile.  GQA is
+expressed in the k/v BlockSpec index maps (q head h reads kv head h // G),
+so no KV replication is materialized in HBM.
 
 Masking is positional, matching :func:`repro.kernels.ref.flash_attention_ref`:
 q_pos / kv_pos carry absolute positions (-1 = invalid slot), and
@@ -16,7 +21,11 @@ gets position -1), so right-padded mixed-seq-len batches run this kernel
 instead of falling back to chunked SDPA: masked-out keys contribute
 exp(-inf)=0 to the online softmax, and a kv block whose keys are all masked
 leaves (acc, m, l) bitwise unchanged — a padded batch's valid positions
-compute exactly the unpadded batch's math.
+compute exactly the unpadded batch's math.  That holds as long as a row's
+valid keys split into kv blocks at the same places whatever the row is
+padded to, which the wrapper's tile rule keeps: padding only widens the
+block that holds a row's last valid key with masked keys, and adds fully
+masked blocks after it.
 
 Operand layouts follow the TPU tiling rule (the last two dims of every
 block are multiples of (8, 128) or span the whole array dim): query

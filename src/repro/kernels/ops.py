@@ -3,7 +3,8 @@
 Handles the hardware-alignment plumbing so callers keep natural shapes:
 * pads head_dim to a 128 multiple and seq lens to block multiples
   (padded key slots get position -1 => masked out; padded head dims are
-  zeros => contribute nothing to dot products, scale uses the true hd);
+  zeros => contribute nothing to dot products, scale uses the true hd),
+  with the flash kernel's tiles picked from the call's shape;
 * pads GQA group G to the f32 sublane multiple (8) for the decode kernel;
 * lays out the selective scan's operands (A and the state transposed, d_inner
   on lanes) and pads its sequence to the time block with identity steps;
@@ -48,9 +49,50 @@ def _pad_to(x: Array, mult: int, axis: int, value=0) -> Array:
     return jnp.pad(x, widths, constant_values=value)
 
 
+#: VMEM one step of the flash kernel may fill (v5e's default scoped limit is
+#: 16 MiB)
+FLASH_VMEM_BYTES = 12 << 20
+#: the tallest q tile
+FLASH_BLOCK_Q = 512
+
+
+def _flash_vmem_bytes(bq: int, bk: int, hd: int, itemsize: int) -> int:
+    """VMEM a (bq, bk) step of the flash kernel holds: the float32 scores
+    and their exponentials, p in the operand dtype, the q, k, v and output
+    blocks double-buffered, the float32 accumulator, and the position and
+    softmax-state columns, which pad to 128 lanes ((bq, 1)) or 8 sublanes
+    ((1, bk))."""
+    scores = bq * bk * (2 * 4 + itemsize)
+    blocks = 2 * (2 * bq + 2 * bk) * hd * itemsize
+    columns = 4 * bq * 128 * 4 + 2 * 8 * bk * 4
+    return scores + blocks + bq * hd * 4 + columns
+
+
+def _flash_blocks(sq: int, sk: int, hd: int, itemsize: int) -> tuple[int, int]:
+    """The flash kernel's (q tile, key block) for a call's shape.
+
+    q tiles are as tall as ``FLASH_BLOCK_Q``, split evenly over ``sq`` and
+    rounded up to 16 rows.  The key block is the whole key length, rounded
+    up to 128, up to the widest power of two whose ``FLASH_BLOCK_Q``-tall
+    tile fits ``FLASH_VMEM_BYTES``: where the keys fit one block, one head's
+    K and V are fetched once, not once per q tile.  That widest block
+    depends on ``hd`` and the dtype alone, so where keys take more than one
+    block they split at the same multiples of it however far a row is
+    padded: padding adds masked keys past a row's valid prefix to its last
+    block, and fully masked blocks after it."""
+    nq = -(-sq // FLASH_BLOCK_Q)
+    bq = -(-sq // (16 * nq)) * 16
+    widest = 128
+    while (
+        _flash_vmem_bytes(FLASH_BLOCK_Q, 2 * widest, hd, itemsize)
+        <= FLASH_VMEM_BYTES
+    ):
+        widest *= 2
+    return bq, min(-(-sk // 128) * 128, widest)
+
+
 @functools.partial(
-    jax.jit,
-    static_argnames=("window", "causal", "softcap", "protected", "block_q", "block_k"),
+    jax.jit, static_argnames=("window", "causal", "softcap", "protected")
 )
 def flash_attention(
     q: Array,       # (B, Sq, H, hd) — model layout
@@ -64,13 +106,19 @@ def flash_attention(
     causal: bool = True,
     softcap: float = 0.0,
     protected: int = 0,
-    block_q: int = 128,
-    block_k: int = 128,
 ) -> Array:
+    """Flash attention in the model layout.  The tiles come from the call's
+    shape alone (``_flash_blocks``): q tiles up to 512 rows, and the whole
+    key length as one block where that fits the VMEM budget.
+
+    A row right-padded to a longer key length with ``kv_mask`` comes back
+    bit-identical on its valid slice to its exact-shape run: its valid keys
+    split into blocks at the same places, the block holding its last valid
+    key only gains masked keys, and whole blocks past it are masked."""
     b, sq, h, hd = q.shape
     sk, kvh = k.shape[1], k.shape[2]
-    bq = min(block_q, max(8, 1 << (sq - 1).bit_length()))
-    bk = min(block_k, 128)
+    hdp = -(-hd // 128) * 128
+    bq, bk = _flash_blocks(sq, sk, hdp, max(q.dtype.itemsize, k.dtype.itemsize))
     # kernel layout (B, H, S, hd)
     qt = _pad_to(_pad_to(q.transpose(0, 2, 1, 3), 128, 3), bq, 2)
     kt = _pad_to(_pad_to(k.transpose(0, 2, 1, 3), 128, 3), bk, 2)

@@ -32,6 +32,11 @@ FLASH_CASES = [
     (1, 6, 3, 130, 130, 80, 32, True, 0.0, jnp.float32),      # window
     (1, 4, 4, 64, 64, 64, 16, True, 0.0, jnp.bfloat16),       # bf16
     (2, 2, 2, 96, 96, 64, 0, True, 30.0, jnp.float32),        # softcap
+    # past one q tile and one key block: 3 q tiles (Sq no multiple of
+    # either tile) and 2 key blocks, GQA 6 and 5
+    (1, 6, 1, 1100, 1100, 128, 0, False, 0.0, jnp.float32),
+    (1, 5, 1, 1300, 1300, 64, 256, True, 0.0, jnp.float32),
+    (1, 10, 2, 1040, 1040, 128, 0, False, 0.0, jnp.bfloat16),
 ]
 
 
@@ -190,6 +195,10 @@ MASKED_FLASH_CASES = [
     (4, 1, 100, 48, 0, True, 0.0, 0, (99, 31)),          # MQA + ragged shape
     (6, 3, 130, 80, 32, True, 0.0, 4, (120, 77)),        # window + sinks
     (2, 2, 96, 64, 0, True, 30.0, 0, (96, 5)),           # softcap
+    # past one q tile and one key block (3 q tiles, 2 key blocks), GQA 6
+    # and 5, rows ending in either block or before the second q tile
+    (6, 1, 1100, 128, 0, False, 0.0, 0, (1100, 700, 0)),
+    (5, 1, 1300, 64, 256, True, 0.0, 4, (1300, 1030, 300)),
 ]
 
 
@@ -271,25 +280,63 @@ def test_masked_flash_padding_invariance_bitwise():
     """The serving property the mask exists for: a row right-padded from L
     to S with kv_mask runs BIT-IDENTICAL (on its valid slice) to the same
     row's exact-shape unmasked kernel run — extra fully-masked kv blocks
-    rescale the online-softmax state by exp(0) == 1.0 exactly."""
-    b, h, kv, hd, s = 1, 4, 2, 64, 96
-    for L in (1, 31, 64, 95):
-        q = _rand(10, (b, s, h, hd))
-        k = _rand(11, (b, s, kv, hd))
-        v = _rand(12, (b, s, kv, hd))
-        for causal in (False, True):
-            exact = ops.flash_attention(
-                q[:, :L], k[:, :L], v[:, :L],
-                jnp.arange(L), jnp.arange(L), causal=causal,
-            )
-            padded = ops.flash_attention(
-                q, k, v, jnp.arange(s), jnp.arange(s),
-                kv_mask=_ragged_mask(s, [L]), causal=causal,
-            )
-            np.testing.assert_array_equal(
-                np.asarray(padded[:, :L]), np.asarray(exact),
-                err_msg=f"L={L} causal={causal}",
-            )
+    rescale the online-softmax state by exp(0) == 1.0 exactly, and a wider
+    key block whose added keys are all masked sums the same terms.  The
+    later shapes cross the tile rule's boundaries: one key block of 384 or
+    768 against one of 1024, and one of 384 or two of 1024 against two or
+    three of 1024 (GQA 3 and 5, hd 128)."""
+    b = 1
+    for h, kv, hd, s, lengths in (
+        (4, 2, 64, 96, (1, 31, 64, 95)),
+        (6, 2, 128, 1024, (300, 700)),
+        (10, 2, 128, 3072, (300, 1500)),
+    ):
+        for L in lengths:
+            q = _rand(10, (b, s, h, hd))
+            k = _rand(11, (b, s, kv, hd))
+            v = _rand(12, (b, s, kv, hd))
+            for causal in (False, True):
+                exact = ops.flash_attention(
+                    q[:, :L], k[:, :L], v[:, :L],
+                    jnp.arange(L), jnp.arange(L), causal=causal,
+                )
+                padded = ops.flash_attention(
+                    q, k, v, jnp.arange(s), jnp.arange(s),
+                    kv_mask=_ragged_mask(s, [L]), causal=causal,
+                )
+                np.testing.assert_array_equal(
+                    np.asarray(padded[:, :L]), np.asarray(exact),
+                    err_msg=f"S={s} L={L} causal={causal}",
+                )
+
+
+@pytest.mark.parametrize(
+    "hd, itemsize", [(128, 2), (256, 2), (128, 4), (256, 4), (512, 2)]
+)
+def test_flash_tile_rule(hd, itemsize):
+    """``ops._flash_blocks`` over a grid of shapes: tiles aligned, within the
+    VMEM budget, q padding under one 16-row step a tile, and the cuts
+    between key blocks inside a valid prefix the same at the row's own
+    length as at any length it is padded to."""
+    lengths = (1, 17, 100, 128, 300, 512, 513, 700, 1024, 1025, 1100, 1500, 2048,
+               3000, 4096)
+    for sq in lengths:
+        bq, bk = ops._flash_blocks(sq, sq, hd, itemsize)
+        assert bq % 16 == 0 and bq <= ops.FLASH_BLOCK_Q
+        assert bk % 128 == 0 and bk <= -(-sq // 128) * 128
+        assert ops._flash_vmem_bytes(bq, bk, hd, itemsize) <= ops.FLASH_VMEM_BYTES
+        assert -(-sq // bq) * bq - sq < 16 * -(-sq // ops.FLASH_BLOCK_Q)
+
+    def cuts(valid, padded_to):
+        bk = ops._flash_blocks(padded_to, padded_to, hd, itemsize)[1]
+        return list(range(bk, valid, bk))
+
+    for valid in lengths:
+        for padded_to in (n for n in lengths if n >= valid):
+            assert cuts(valid, padded_to) == cuts(valid, valid), (valid, padded_to)
+    if (hd, itemsize) in ((128, 2), (256, 2), (128, 4)):
+        # the cells' calls and qwen2's float32 twin: one key block at 1024
+        assert ops._flash_blocks(1024, 1024, hd, itemsize) == (512, 1024)
 
 
 def test_unmasked_flash_unchanged_by_mask_plumbing():

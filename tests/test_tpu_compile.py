@@ -129,6 +129,43 @@ def test_mla_flash_attention_compiles_for_v5e(one_chip, monkeypatch):
     assert "tpu_custom_call" in _compiled_text(call, q, q, q, pos)
 
 
+# each benchmark cell's flash call in the model layout: rows, seq, q heads,
+# kv heads, head dim, window, dtype (float32: the chip check's twin engine)
+CELL_FLASH_CALLS = {
+    "qwen2-1.5b": (16, 1024, 12, 2, 128, 0, jnp.bfloat16),
+    "qwen2-1.5b-f32": (16, 1024, 12, 2, 128, 0, jnp.float32),
+    "hymba-1.5b": (4, 1024, 25, 5, 64, 1024, jnp.bfloat16),
+    "deepseek-v2-lite": (16, 1024, 16, 16, 192, 0, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("call", CELL_FLASH_CALLS.values(), ids=list(CELL_FLASH_CALLS))
+def test_flash_attention_compiles_at_the_cells_calls_for_v5e(
+    one_chip, monkeypatch, call
+):
+    """Through the wrapper, so at the tiles the call's shape picks (512-row
+    q tiles, the whole 1024 keys in one block): a tile past the chip's VMEM
+    fails here, not on the chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, seq, h, kv, hd, window, dtype = call
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def attend(q, k, v, pos, mask):
+        return ops.flash_attention(
+            q, k, v, pos, pos, kv_mask=mask, causal=False, window=window
+        )
+
+    text = _compiled_text(
+        attend,
+        sds((rows, seq, h, hd), dtype),
+        sds((rows, seq, kv, hd), dtype),
+        sds((rows, seq, kv, hd), dtype),
+        sds((seq,), jnp.int32),
+        sds((rows, seq), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
 def test_dropless_experts_compile_for_v5e(one_chip):
     """The held-experts layer at the deepseek-v2-lite.offline cell's shape
     (16 x 1024 tokens, 8 of 64 experts of 1408 held, top-6, 2 shared):
