@@ -20,11 +20,9 @@ from __future__ import annotations
 
 import functools
 import os
-import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs import get_config
 from repro.core import default_config, get_solver, linear_schedule
@@ -40,9 +38,6 @@ from repro.training import (
 
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts")
 SCHEDULE = linear_schedule()
-# CI smoke mode: tiny shapes / few repeats so the whole suite runs in
-# seconds on a CPU runner (Pallas kernels in interpret mode)
-SMOKE = os.environ.get("REPRO_BENCH_SMOKE", "") == "1"
 
 
 class AnalyticMixture:
@@ -119,16 +114,6 @@ def solve(eps_fn, xT, solver: str, nfe: int, **kw):
         default_config(solver, nfe=nfe)
     )
     return get_solver(solver)(eps_fn, xT, SCHEDULE, cfg).x0
-
-
-def timer(fn, *args, repeats=3):
-    fn(*args)  # compile
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        best = min(best, time.perf_counter() - t0)
-    return best
 
 
 def emit(name: str, value_us: float, derived: str = "") -> None:

@@ -19,13 +19,9 @@ def main() -> None:
     from benchmarks import (
         bench_ablation_ers,
         bench_ablation_scale,
-        bench_coldstart,
         bench_error_measure,
         bench_renoise_error,
-        bench_serving,
         bench_solver_quality,
-        bench_walltime,
-        roofline,
     )
 
     suites = {
@@ -34,10 +30,6 @@ def main() -> None:
         "ablation_scale": bench_ablation_scale.run,   # Figs 5/6
         "error_measure": bench_error_measure.run,     # Fig 3
         "renoise_error": bench_renoise_error.run,     # Appendix C
-        "walltime": bench_walltime.run,               # Table 7
-        "serving": bench_serving.run,                 # batched engine lat/thpt
-        "coldstart": bench_coldstart.run,             # boot: cold vs warmup vs cache
-        "roofline": roofline.run,                     # deliverable (g)
     }
     if args.only and args.only not in suites:
         print(f"unknown suite {args.only!r}; available: {sorted(suites)}")

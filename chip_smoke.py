@@ -13,7 +13,7 @@ lengths below the 512 bucket so the masked flash kernel and the padded ERA
 step both run.  It then checks that
 
 * every ``x0`` has the requested shape and is finite;
-* a wire result is bitwise equal to the same request drained in-process by
+* a wire result is bitwise equal to the same request flushed in-process by
   the same engine (same compiled shape);
 * one ERA request agrees with a float32 reference of the same sampler
   (``highest`` matmul precision, naive XLA attention, the jnp ERA combine)
@@ -220,18 +220,25 @@ def rounding_bound(reference, params, x, ref_x0) -> float:
     return bound
 
 
+def run_now(engine, params, reqs):
+    """Queue ``reqs`` on a scheduler over ``engine``, flush them on this
+    thread, and return their results in order."""
+    from repro.serving import AsyncBatchedSampler
+
+    queue = AsyncBatchedSampler(engine, params)
+    futs = [queue.submit(r) for r in reqs]
+    queue.flush()
+    return [f.result() for f in futs]
+
+
 def bucket_gap(label, engine, params, solo, mate) -> None:
     """Print how far one request moves between batch buckets: run alone
     (bucket 1), then fused with ``mate`` into bucket 8.  docs/serving.md
     promises ``atol=1e-6`` across batch buckets; this measures it."""
     import numpy as np
 
-    _, alone = engine.submit_with_future(solo)
-    engine.drain(params)
-    _, fused = engine.submit_with_future(solo)
-    engine.submit_with_future(mate)
-    engine.drain(params)
-    a, b = alone.result(), fused.result()
+    (a,) = run_now(engine, params, [solo])
+    b, _ = run_now(engine, params, [solo, mate])
     check(
         (a.padded_batch, b.padded_batch) == (1, 8),
         f"ran at batch buckets {a.padded_batch}, {b.padded_batch}",
@@ -270,9 +277,7 @@ def check_reference(dlm, params, schedule, cfg, req, served_bf16, seq_bucket, so
         twin = build_engine(
             float32_twin(dlm), schedule, dataclasses.replace(cfg, warmup="none")
         )
-        _, fut = twin.submit_with_future(req)
-        twin.drain(params)
-        served32 = np.asarray(fut.result().x0)
+        served32 = np.asarray(run_now(twin, params, [req])[0].x0)
         bucket_gap("float32", twin, params, solo, req)
 
         reference = reference_sampler(dlm, schedule)
@@ -389,9 +394,7 @@ def one_chip(args, device) -> None:
 
         with phase("wire == in-process"):
             req = reqs[0]
-            _, fut = engine.submit_with_future(req)
-            engine.drain(params)
-            local = np.asarray(fut.result().x0)
+            local = np.asarray(run_now(engine, params, [req])[0].x0)
             check(np.array_equal(local, wire[req]), "wire x0 != in-process x0")
 
         with phase("batch buckets"):
@@ -460,18 +463,16 @@ def four_chips(args, devices) -> None:
         SampleRequest(batch=5, seq_len=seq_bucket, nfe=NFE, seed=22),
     ]
 
-    def drain(denoiser, p, mesh=None):
+    def run(denoiser, p, mesh=None):
         engine = build_engine(denoiser, schedule, cfg, mesh=mesh)
-        futs = [engine.submit_with_future(r)[1] for r in reqs]
-        engine.drain(p)
-        out = [f.result() for f in futs]
+        out = run_now(engine, p, reqs)
         check(out[0].padded_batch == 8, f"batch ran at {out[0].padded_batch}")
         return engine, [np.asarray(r.x0) for r in out]
 
     with phase("one chip (device 0)"):
-        _, single16 = drain(dlm, params)
+        _, single16 = run(dlm, params)
         with jax.default_matmul_precision("highest"):
-            _, single32 = drain(twin, params)
+            _, single32 = run(twin, params)
             reference = reference_sampler(dlm, schedule)
             x = request_noise(reqs[0], dlm.config.d_model)
             tol = rounding_bound(reference, params, x, np.asarray(reference(params, x)))
@@ -486,9 +487,9 @@ def four_chips(args, devices) -> None:
             f"{sorted(s.device.id for s in leaf.addressable_shards)}",
             flush=True,
         )
-        meshed, mesh16 = drain(dlm, params, mesh)
+        meshed, mesh16 = run(dlm, params, mesh)
         with jax.default_matmul_precision("highest"):
-            _, mesh32 = drain(twin, params, mesh)
+            _, mesh32 = run(twin, params, mesh)
         (compiled,) = meshed.compile_cache().values()
         x_sharding = compiled.input_shardings[0][1]
         shape = (8, seq_bucket, dlm.config.d_model)

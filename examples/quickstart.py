@@ -96,16 +96,17 @@ def main() -> None:
           f"{np.asarray(out.aux['delta_eps_history'])[3:].round(3).tolist()}")
 
     # --- the same model behind the batched serving engine ---
-    from repro.serving import BatchedSampler, SampleRequest
+    from repro.serving import (
+        AsyncBatchedSampler, EngineConfig, SampleRequest, build_engine,
+    )
 
-    engine = BatchedSampler(dlm, sched, batch_buckets=(1, 8))
+    engine = build_engine(dlm, sched, EngineConfig(batch_buckets=(1, 8)))
+    queue = AsyncBatchedSampler(engine, res.params)
     futs = [
-        engine.submit_with_future(
-            SampleRequest(batch=1, seq_len=seq, nfe=args.nfe, seed=s)
-        )[1]
+        queue.submit(SampleRequest(batch=1, seq_len=seq, nfe=args.nfe, seed=s))
         for s in range(4)
     ]
-    engine.drain(res.params)
+    queue.flush()
     results = [f.result() for f in futs]
     lat = sum(r.latency_s for r in results) / len(results)
     print(f"batched engine: {len(results)} requests fused to "

@@ -3,6 +3,7 @@
 Public API:
     get_solver(name)                 -> sampling function
     get_program(name)                -> SolverProgram (the serving surface)
+    sample_program(dlm, ...)         -> one jittable params, x_T -> x0 program
     SolverConfig / ERAConfig         -> solver options
     NoiseSchedule / get_schedule     -> VP noise schedules
     timesteps                        -> solver time grids
@@ -15,6 +16,7 @@ from repro.core.registry import (
     default_config,
     get_program,
     get_solver,
+    sample_program,
     solver_names,
 )
 from repro.core.schedules import (
@@ -41,6 +43,7 @@ __all__ = [
     "get_schedule",
     "get_solver",
     "linear_schedule",
+    "sample_program",
     "solver_names",
     "timesteps",
 ]

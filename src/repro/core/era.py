@@ -26,7 +26,7 @@ Engine notes (serving path):
   compile covers a whole (sample-shape, nfe, k) bucket and XLA can reuse the
   Lagrange buffers in place.
 * :func:`sample_scan` takes the eps/t buffers as explicit arguments so a
-  jitting caller (``repro.serving.BatchedSampler``) can donate them.
+  jitting caller (``repro.serving.FusedExecutor``) can donate them.
 * Steps 2-4 run the fused Pallas kernel (``repro.kernels.era_update``) —
   one HBM round trip per operand instead of ~(k+5).  The platform picks how
   it runs: compiled by Mosaic on TPU, in interpret mode everywhere else.

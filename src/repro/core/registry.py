@@ -50,6 +50,18 @@ def get_program(name: str) -> SolverProgram:
         ) from None
 
 
+def sample_program(dlm, schedule, solver: str, solver_config: SolverConfig):
+    """The whole sampling loop as one jittable ``program(params, x_init)
+    -> x0``: solver ``solver``'s ``sample`` over ``dlm.eps_fn(params)``.
+    This is the program the multi-pod dry run lowers."""
+    sample_fn = get_program(solver).sample
+
+    def program(params, x_init):
+        return sample_fn(dlm.eps_fn(params), x_init, schedule, solver_config).x0
+
+    return program
+
+
 def get_solver(name: str) -> SampleFn:
     """The classic functional entry: ``f(eps_fn, x_T, schedule, cfg)``."""
     return get_program(name).sample

@@ -179,9 +179,8 @@ def run_solver_program(
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.core import ERAConfig, SolverConfig, linear_schedule
+    from repro.core import ERAConfig, SolverConfig, linear_schedule, sample_program
     from repro.models.diffusion import DiffusionLM
-    from repro.serving import SamplerService
 
     cfg = get_config(arch).with_(param_dtype=jnp.bfloat16)
     dlm = DiffusionLM(build_model(cfg))
@@ -204,7 +203,7 @@ def run_solver_program(
         )
     else:
         sc = SolverConfig(nfe=nfe)
-    svc = SamplerService(dlm, linear_schedule(), solver, sc)
+    program = sample_program(dlm, linear_schedule(), solver, sc)
 
     rec = {
         "arch": arch, "mesh": mesh_kind, "entry": f"sample_{solver}",
@@ -215,7 +214,7 @@ def run_solver_program(
     t0 = time.perf_counter()
     with mesh, activation_sharding(data_axes(mesh)):
         compiled = (
-            jax.jit(svc.sample_program(), in_shardings=(psh, xsh))
+            jax.jit(program, in_shardings=(psh, xsh))
             .lower(aparams, x)
             .compile()
         )

@@ -44,8 +44,3 @@ def make_sampler_mesh(max_devices: int | None = None):
         n = min(n, max_devices)
     return jax.make_mesh((n,), ("data",), _auto(1), devices=jax.devices()[:n])
 
-
-# TPU v5e hardware constants for the roofline analysis (per chip).
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW_PER_LINK = 50e9          # B/s per link

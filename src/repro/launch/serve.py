@@ -29,9 +29,11 @@ disk loads from ``$JAX_COMPILATION_CACHE_DIR``, or the checkout's
 wire client: it needs no model or params, just the server's URL.
 
 Every diffusion mode builds its engine through
-:func:`repro.serving.build_engine` — the one-shot facade, the continuous
-simulator, and the HTTP server run the same construction path, so a
-result observed over the wire is the result the in-process paths produce.
+:func:`repro.serving.build_engine` and queues its requests on
+:class:`repro.serving.AsyncBatchedSampler` — the one-shot mode (submit,
+then ``flush()``), the continuous simulator, and the HTTP server run the
+same path, so a result observed over the wire is the result the
+in-process paths produce.
 """
 
 from __future__ import annotations
@@ -54,7 +56,6 @@ from repro.serving import (
     EngineConfig,
     FrontDoorClient,
     SampleRequest,
-    SamplerService,
     SchedulerPolicy,
     ServeConfig,
     build_engine,
@@ -70,7 +71,7 @@ def _engine_config(
     warmup_seq_lens: tuple[int, ...] | None = None,
 ) -> EngineConfig:
     """CLI args -> the one EngineConfig every diffusion mode builds from.
-    ``fused`` engines get the serving bucket ladder; the one-shot facade
+    ``fused`` engines get the serving bucket ladder; the one-shot mode
     runs exact-size (no fusion).  ``warmup_seq_lens`` names the exact
     lengths the AOT warmup grid covers when the engine has no seq-bucket
     ladder (each mode passes the lengths its traffic will use)."""
@@ -107,7 +108,7 @@ def _engine_config(
 
 def _warm_engine(engine, params, cfg: EngineConfig, mix) -> None:
     """AOT-compile the engine's program grid for every solver in ``mix``
-    (no sampling — abstract shapes only; see ``BatchedSampler.warmup``)."""
+    (no sampling — abstract shapes only; see ``FusedExecutor.warmup``)."""
     kw = warmup_kwargs(cfg)
     if kw is None:
         return
@@ -204,7 +205,7 @@ def run_listen(dlm, params, args) -> None:
             {**kw, "solvers": (args.solver,)} if kw is not None else None
         ),
     )
-    # machine-parsable sentinel: bench_serving and tests wait for this
+    # machine-parsable sentinel: tests and scripts wait for this
     # line before opening the client (bind != ready — poll /readyz for
     # the end of the compile wall)
     print(f"FRONTDOOR READY {door.url}", flush=True)
@@ -379,17 +380,17 @@ def main() -> None:
         if args.continuous:
             run_continuous(dlm, params, args)
             return
-        svc = SamplerService(
-            engine=build_engine(
-                dlm,
-                linear_schedule(),
-                _engine_config(args, per_sample=False, fused=False),
-            )
+        engine = build_engine(
+            dlm,
+            linear_schedule(),
+            _engine_config(args, per_sample=False, fused=False),
         )
-        req = SampleRequest(
+        sched = AsyncBatchedSampler(engine, params)
+        fut = sched.submit(SampleRequest(
             batch=args.batch, seq_len=args.seq, nfe=args.nfe, seed=args.seed
-        )
-        res = svc.sample(params, req)
+        ))
+        sched.flush()
+        res = fut.result()
         x0 = res.x0
         print(
             f"sampled latents {x0.shape} via {args.solver} nfe={args.nfe} "

@@ -10,7 +10,6 @@ from repro.serving.compile_cache import (
     configure_persistent_cache,
     disk_cache_hits,
 )
-from repro.serving.diffusion_sampler import BatchedSampler, SamplerService
 from repro.serving.engine import Engine, ServeConfig, cache_slots, resolve_window
 from repro.serving.executor import (
     DEFAULT_MAX_BATCH,
@@ -57,7 +56,6 @@ __all__ = [
     "SEED_MAX",
     "SEED_MIN",
     "AsyncBatchedSampler",
-    "BatchedSampler",
     "DeadlineExceededError",
     "Engine",
     "EngineConfig",
@@ -68,7 +66,6 @@ __all__ = [
     "QueueFullError",
     "SampleRequest",
     "SampleResult",
-    "SamplerService",
     "SamplerShardings",
     "SamplerSpecs",
     "SchedulerPolicy",

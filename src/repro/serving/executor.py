@@ -3,14 +3,10 @@
 :class:`FusedExecutor` owns everything below the request queue: request
 validation, bucket selection, mesh placement, the jit cache (one compiled
 program per (solver, config, padded-batch, seq_len) bucket), chunk
-execution, and per-request aux scoping.  Both entry points share one
-executor instance:
-
-* the sync :class:`~repro.serving.diffusion_sampler.BatchedSampler.drain`
-  path, which fuses whatever is pending at call time, and
-* the continuous-batching
-  :class:`~repro.serving.scheduler.AsyncBatchedSampler`, whose background
-  drain thread fuses requests across arrival time.
+execution, and per-request aux scoping.  The one request queue in front
+of it is :class:`~repro.serving.scheduler.AsyncBatchedSampler`, whose
+background drain thread fuses requests across arrival time and whose
+``flush()`` runs everything queued on the caller's thread.
 
 The executor is **solver-agnostic**: every registry solver is a
 :class:`~repro.core.SolverProgram` (scan entry + donatable buffers + carry
@@ -67,9 +63,9 @@ waste a too-coarse ladder buys).
 
 All mutable state (jit cache, shardings cache, param replication cache) is
 guarded by one re-entrant lock, and chunk execution itself is serialized
-under the same lock — concurrent ``drain()`` callers and the scheduler
-thread can share an executor without double-compiling a bucket or
-interleaving donated-buffer executions.
+under the same lock — a drain thread and ``flush()`` callers, of one
+scheduler or several, can share an executor without double-compiling a
+bucket or interleaving donated-buffer executions.
 """
 
 from __future__ import annotations
@@ -79,7 +75,6 @@ import functools
 import math
 import threading
 import time
-import warnings
 import weakref
 from concurrent.futures import Future, InvalidStateError
 from typing import Any
@@ -151,8 +146,7 @@ class SampleRequest:
     ``deadline_ms`` after submit fails fast with
     :class:`~repro.serving.scheduler.DeadlineExceededError` instead of
     occupying a fused batch.  Neither field affects results — a request's
-    ``x0`` depends only on ``(seed, seq_len, nfe, solver)``.  The sync
-    ``drain()`` path runs everything pending, so both are no-ops there.
+    ``x0`` depends only on ``(seed, seq_len, nfe, solver)``.
     """
 
     batch: int
@@ -179,13 +173,11 @@ class SampleResult:
     fused batch the request rode in and are shared by its batch-mates;
     ``latency_s`` is this request's own submit→result wall time.
 
-    This is the **one** result type across the stack: engine drains, the
-    scheduler's futures, ``SamplerService.sample``, and the front door's
-    wire schema all carry exactly this dataclass.  :attr:`info` flattens
-    the telemetry fields plus ``aux`` into one dict under the documented
-    :mod:`~repro.serving.result_keys` keys (what the facade used to return
-    as the second tuple element).  Tuple unpacking ``x0, info = result``
-    still works as a deprecated shim.
+    This is the **one** result type across the stack: the scheduler's
+    futures and the front door's wire schema carry exactly this
+    dataclass.  :attr:`info` flattens the telemetry fields plus ``aux``
+    into one dict under the documented :mod:`~repro.serving.result_keys`
+    keys.
     """
 
     x0: Array                # (batch, seq_len, d_model)
@@ -215,26 +207,9 @@ class SampleResult:
             **self.aux,
         }
 
-    # ---- deprecated (x0, info) tuple shim -------------------------------
-    def _tuple_shim(self):
-        warnings.warn(
-            "tuple unpacking of SampleResult is deprecated; use "
-            "result.x0 and result.info",
-            DeprecationWarning,
-            stacklevel=3,
-        )
-        return (self.x0, self.info)
 
-    def __iter__(self):
-        return iter(self._tuple_shim())
-
-    def __getitem__(self, i):
-        return self._tuple_shim()[i]
-
-
-# A queued request: (ticket, request, submit-time).  Both the sync engine's
-# pending list and the scheduler's per-shape queues carry this shape, so the
-# executor can run a chunk from either source.
+# A queued request: (ticket, request, submit-time), as the scheduler's
+# per-group queues carry it and the executor runs it.
 QueueItem = tuple[int, SampleRequest, float]
 
 
@@ -243,7 +218,7 @@ def resolve_future(fut: Future, result=None, exception=None) -> None:
 
     A waiter that gave up (``fut.cancel()`` after a result() timeout) leaves
     the future in CANCELLED state; ``set_result``/``set_exception`` on it
-    raises InvalidStateError, which must not take down the drain path — the
+    raises InvalidStateError, which must not take down the scheduler — the
     other requests in the batch still have live waiters.
     """
     try:
@@ -268,12 +243,12 @@ def _denoiser_scope(eps_fn):
 
 
 class FusedExecutor:
-    """Fused-chunk runner shared by the sync drain path and the scheduler.
+    """Fused-chunk runner behind the scheduler's request queue.
 
     Thread-safety contract: every public method may be called from any
     thread.  Reads of the jit / shardings / replication caches and chunk
-    execution itself serialize under one re-entrant lock, so sync
-    ``drain()`` callers and the scheduler's drain thread share compiled
+    execution itself serialize under one re-entrant lock, so ``flush()``
+    callers and the scheduler's drain thread share compiled
     buckets without double-compiling or interleaving donated-buffer
     executions; ``run_chunk`` blocks for the whole fused execution
     (device-synchronous — it calls ``block_until_ready``).
@@ -369,7 +344,7 @@ class FusedExecutor:
             "programs compiled by warmup(), by solver",
         )
         # plain-python mirror of the source-labelled compile counters, for
-        # callers (tests, bench_coldstart) that want exact counts without
+        # callers (tests) that want exact counts without
         # scraping label combinations out of the registry
         self._compile_counts = {"fresh": 0, "disk": 0, "memory": 0}
         self._warmup_state: dict[str, Any] = {"state": "none", "done": 0, "total": 0}
@@ -551,8 +526,8 @@ class FusedExecutor:
         )
 
     def group_key(self, req: SampleRequest) -> tuple[str, int, int]:
-        """The fuse-group key ``(solver, seq, nfe)`` — what the sync
-        drain's groups, the scheduler's queues, and the jit cache batch by.
+        """The fuse-group key ``(solver, seq, nfe)`` — what the scheduler's
+        queues and the jit cache batch by.
         Under seq bucketing ``seq`` is the request's seq *bucket*, so
         mixed-length traffic shares a group and the compile count is
         bounded by the ladder; otherwise it is the exact ``seq_len``.
@@ -705,12 +680,12 @@ class FusedExecutor:
         pad: bool = True,
     ) -> None:
         """Run one chunk as a single fused program; fill ``results`` by
-        ticket.  All requests in a chunk share one group key (the queues
-        and drain groups key on it): one solver, and one seq length —
+        ticket.  All requests in a chunk share one group key (the
+        scheduler's queues key on it): one solver, and one seq length —
         exact, or the shared seq bucket ``seq_len`` each request's rows are
         right-padded up to.  Serialized under the executor lock — safe
-        to call from the scheduler thread and sync drain() callers
-        concurrently; blocks until the fused result is on host.
+        to call from the scheduler's drain thread and ``flush()``
+        callers concurrently; blocks until the fused result is on host.
 
         Profiler spans (no-ops unless a ``jax.profiler`` session is active):
         ``sampler.chunk`` covers the call, the wait for the lock included,

@@ -1,12 +1,12 @@
 """One engine-construction path for every serve mode.
 
-``launch/serve.py`` grew three ways to stand up a sampling engine (facade,
-continuous scheduler, and now the HTTP front door), each hand-assembling
+``launch/serve.py`` has three ways to stand up a sampling engine (one-shot,
+continuous scheduler, and the HTTP front door), each hand-assembling
 solver configs and bucket ladders.  This module is the single factory they
 all go through: an :class:`EngineConfig` captures every engine-shape
 decision as one frozen, hashable value, and :func:`build_engine` turns it
-into a :class:`~repro.serving.diffusion_sampler.BatchedSampler`.  The HTTP
-server, the ``--continuous`` simulator, and the one-shot facade therefore
+into a :class:`~repro.serving.executor.FusedExecutor`.  The HTTP
+server, the ``--continuous`` simulator, and the one-shot mode therefore
 serve *the same engine* — same solver config, same fuse buckets, same
 compile-cache shape — so a result observed over the wire is the result the
 in-process paths produce.
@@ -24,11 +24,11 @@ from repro.core import (
 )
 from repro.models.diffusion import DiffusionLM
 from repro.serving.compile_cache import configure_persistent_cache
-from repro.serving.diffusion_sampler import BatchedSampler
 from repro.serving.executor import (
     DEFAULT_MAX_BATCH,
     DEFAULT_MAX_NFE,
     DEFAULT_MAX_SEQ_LEN,
+    FusedExecutor,
 )
 from repro.serving.metrics import MetricsRegistry
 
@@ -50,7 +50,7 @@ class EngineConfig:
       scalar delta_eps, which couples a batch, so the engine serves such
       configs one exact-size request at a time.
     * ``batch_buckets`` — compiled batch-shape ladder (``None`` =
-      exact-size, no fusion — the facade's shape).
+      exact-size, no fusion — the one-shot mode's shape).
     * ``seq_buckets`` — opt-in mixed-seq-len fusion ladder (``None`` =
       exact seq_len per fuse group).
     * ``nfe_buckets`` — opt-in mixed-NFE fusion ladder (``None`` = exact
@@ -67,7 +67,7 @@ class EngineConfig:
       ``seq_buckets`` ladder already bounds the sequence axis.
     * ``warmup`` — cold-start policy: ``"grid"`` = callers should AOT
       pre-compile the configured program grid at boot
-      (:meth:`~repro.serving.diffusion_sampler.BatchedSampler.warmup` with
+      (:meth:`~repro.serving.executor.FusedExecutor.warmup` with
       :func:`warmup_kwargs`); ``"none"`` = programs compile lazily at
       first request.  ``warmup_nfes`` / ``warmup_seq_lens`` extend the
       grid beyond the defaults (the config's ``nfe``; the seq-bucket
@@ -114,7 +114,7 @@ def build_engine(
     cfg: EngineConfig | None = None,
     mesh=None,
     metrics: MetricsRegistry | None = None,
-) -> BatchedSampler:
+) -> FusedExecutor:
     """Construct the engine every serve mode shares.
 
     ``mesh`` and ``metrics`` are runtime resources, not engine shape, so
@@ -135,7 +135,7 @@ def build_engine(
         )
     if cfg.compile_cache:
         configure_persistent_cache()
-    return BatchedSampler(
+    return FusedExecutor(
         dlm,
         schedule,
         cfg.solver,

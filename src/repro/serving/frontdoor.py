@@ -32,7 +32,7 @@ parallel wire types.  Endpoints:
   compile.
 
 :class:`FrontDoorClient` is the matching stdlib client (used by
-``launch/serve.py --connect`` and ``bench_serving --frontdoor``); it maps
+``launch/serve.py --connect`` and ``chip_smoke.py``); it maps
 the typed wire errors back to the same exception classes the in-process
 scheduler raises, so retry logic is transport-agnostic.
 
@@ -255,7 +255,7 @@ class FrontDoor:
         self._ready = threading.Event()
         if warmup is None:
             self._ready.set()
-        self._m_http = scheduler.engine.metrics.counter(
+        self._m_http = scheduler.executor.metrics.counter(
             "frontdoor_http_requests_total",
             "HTTP requests served, by route and status code",
         )
@@ -371,7 +371,7 @@ class FrontDoor:
             elif method == "GET" and route == "/metrics":
                 self._respond_text(
                     handler, route, 200,
-                    self.scheduler.engine.metrics.render(),
+                    self.scheduler.executor.metrics.render(),
                     METRICS_CONTENT_TYPE,
                 )
             elif method == "GET" and route == "/healthz":

@@ -4,7 +4,7 @@ A :class:`MetricsRegistry` holds named counters, gauges, and histograms;
 ``render()`` emits the Prometheus text exposition format that the front
 door serves at ``GET /metrics``.  One registry rides with each
 :class:`~repro.serving.executor.FusedExecutor`, so every layer above it —
-sync drains, the continuous-batching scheduler, the HTTP front door —
+the scheduler (drain thread and ``flush()``), the HTTP front door —
 instruments into the same scrape:
 
 * executor: program acquisitions by source (memory / disk / fresh),

@@ -3,8 +3,8 @@
 Every stringly-typed key that crosses a serving API boundary lives here,
 once:
 
-* **info keys** — what :attr:`SampleResult.info` (and therefore
-  ``SamplerService.sample(...).info``) carries alongside ``x0``;
+* **info keys** — what :attr:`SampleResult.info` carries alongside
+  ``x0``;
 * **aux keys** — the solver diagnostics merged into ``info`` (produced by
   the solver programs in ``core/``, scoped per request by the executor);
 * **stats keys** — ``AsyncBatchedSampler.stats()`` counters.
@@ -20,7 +20,7 @@ these keys are also exactly what a front-door client sees in a response's
 
 from __future__ import annotations
 
-# ---- SampleResult.info keys (facade info dict / wire response) ----------
+# ---- SampleResult.info keys (info dict / wire response) ----------------
 #: wall time of the fused batch the request rode in (shared by batch-mates)
 WALL_S = "wall_s"
 #: submit -> result wall time for this request alone

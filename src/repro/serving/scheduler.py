@@ -1,10 +1,11 @@
-"""Continuous-batching async scheduler for the diffusion sampling engine.
+"""Continuous-batching scheduler: the one request queue in front of the
+:class:`~repro.serving.executor.FusedExecutor`.
 
-The sync :class:`~repro.serving.diffusion_sampler.BatchedSampler` only fuses
-requests that happen to be pending at the same ``drain()`` call, so a steady
-open-loop request stream degenerates to batch-of-1 drains and wastes the
-fused step and mesh sharding.  :class:`AsyncBatchedSampler` fixes that with
-the standard continuous-batching shape for fixed-cost (known-NFE) solvers:
+Every caller goes through :class:`AsyncBatchedSampler`.  Online callers
+start its background drain thread; sync callers (scripts, tests, the
+one-shot CLI mode) submit and then call :meth:`AsyncBatchedSampler.flush`,
+which runs everything queued on the caller's thread.  The queue has the
+standard continuous-batching shape for fixed-cost (known-NFE) solvers:
 
 * ``submit()`` is callable from any thread and returns a
   :class:`concurrent.futures.Future` that resolves to a
@@ -38,11 +39,11 @@ drain pass — it never occupies a seat in a fused batch it can no longer
 use.  Both are pure queue policy: neither affects any admitted request's
 results.
 
-Execution goes through the same thread-safe
-:class:`~repro.serving.executor.FusedExecutor` as the sync path, so the
-compiled-bucket cache, mesh placement, and per-sample ERS isolation are
-shared — a request's ``x0`` is bit-identical whether it runs via sync
-``drain()``, via this scheduler under any arrival interleaving, or solo.
+Execution goes through the thread-safe executor, so the compiled-bucket
+cache, mesh placement, and per-sample ERS isolation are shared by every
+scheduler over one executor — a request's ``x0`` is bit-identical whether
+it runs via ``flush()``, via the drain thread under any arrival
+interleaving, or solo.
 
 All policy decisions read an injectable ``clock`` and are reachable via
 :meth:`AsyncBatchedSampler.drain_once`, so the scheduling logic is testable
@@ -62,8 +63,8 @@ from typing import Callable
 import jax
 
 from repro.serving import result_keys as K
-from repro.serving.diffusion_sampler import BatchedSampler
 from repro.serving.executor import (
+    FusedExecutor,
     QueueItem,
     SampleRequest,
     SampleResult,
@@ -193,21 +194,21 @@ class SchedulerPolicy:
 
 
 class AsyncBatchedSampler:
-    """Continuous-batching front end over a :class:`BatchedSampler`.
+    """The request queue in front of a
+    :class:`~repro.serving.executor.FusedExecutor`.
 
     ``submit()`` from any thread; a background drain thread (``start()`` /
     ``stop()``, or use as a context manager) fuses requests across arrival
-    time through the engine's shared
-    :class:`~repro.serving.executor.FusedExecutor`.
+    time, and ``flush()`` runs everything queued on the caller's thread.
 
     Thread-safety and blocking behavior: ``submit`` / ``pending`` /
     ``stats`` are non-blocking and callable from any thread (results are
     delivered through futures); execution happens on the drain thread, or
-    on the caller's thread for explicit ``drain_once()`` pumping.  Sharing
-    the engine between this scheduler and sync ``drain()`` callers is safe
-    — both serialize in the executor and share its compile cache.
-    ``stop()`` blocks: it flushes every queued request (all futures
-    resolve) and joins the drain thread; schedulers are one-shot.
+    on the caller's thread for ``flush()`` and ``drain_once()``.  Several
+    schedulers over one executor are safe — chunks serialize in the
+    executor and share its compile cache.  ``stop()`` blocks: it joins the
+    drain thread and flushes every queued request (all futures resolve);
+    schedulers are one-shot.
 
     ``params`` is bound at construction: the drain thread launches batches
     on its own schedule, so it must not depend on caller state at drain
@@ -216,12 +217,12 @@ class AsyncBatchedSampler:
 
     def __init__(
         self,
-        engine: BatchedSampler,
+        executor: FusedExecutor,
         params,
         policy: SchedulerPolicy | None = None,
         clock: Callable[[], float] = time.perf_counter,
     ):
-        self.engine = engine
+        self.executor = executor
         self.params = params
         self.policy = policy or SchedulerPolicy()
         self._clock = clock
@@ -241,9 +242,9 @@ class AsyncBatchedSampler:
         self._batches = 0
         self._rows = 0
         # Prometheus-style instruments, registered into the shared executor
-        # registry (get-or-create: front doors and sync drains scrape the
-        # same /metrics)
-        m = engine.executor.metrics
+        # registry (get-or-create: every scheduler over one executor scrapes
+        # the same /metrics)
+        m = executor.metrics
         self._m_depth = m.gauge(
             "sampler_queue_depth_rows",
             "pending request rows per fuse-group queue (solver, seq, nfe)",
@@ -283,9 +284,9 @@ class AsyncBatchedSampler:
         poison a fused batch.  Raises :class:`QueueFullError` when the
         request's fuse-group queue is at the policy's admission bound, and
         RuntimeError after ``stop()``."""
-        self.engine.executor.validate(req)
+        self.executor.validate(req)
         fut: Future = Future()
-        key = self.engine.executor.group_key(req)
+        key = self.executor.group_key(req)
         label = self._key_labels(key)
         with self._cv:
             if self._stopping:
@@ -349,7 +350,7 @@ class AsyncBatchedSampler:
         traffic (grid points a request compiled first are skipped); the
         front door runs this on a background thread at boot and gates
         ``/readyz`` on it."""
-        return self.engine.warmup(
+        return self.executor.warmup(
             self.params, solvers=solvers, seq_lens=seq_lens, nfes=nfes,
             progress=progress,
         )
@@ -357,7 +358,7 @@ class AsyncBatchedSampler:
     def warmup_status(self) -> dict:
         """Warmup progress of the underlying executor (what ``/readyz``
         reports)."""
-        return self.engine.warmup_status()
+        return self.executor.warmup_status()
 
     # ---- lifecycle (one-shot: stop() is final; build a new scheduler to
     # serve again) ---------------------------------------------------------
@@ -377,22 +378,30 @@ class AsyncBatchedSampler:
         return self
 
     def stop(self) -> None:
-        """Clean shutdown: flush every queued request (their futures all
-        resolve), then join the drain thread."""
+        """Clean shutdown: join the drain thread, then flush every queued
+        request (their futures all resolve)."""
         with self._cv:
             self._stopping = True
             self._cv.notify_all()
             thread, self._thread = self._thread, None
         if thread is not None:
             thread.join()
-        else:
-            # never started: flush synchronously so no future is orphaned
+        self.flush()
+
+    def flush(self) -> int:
+        """Run every queued request now, on the caller's thread, and return
+        the number of fused batches launched.  Requests past their deadline
+        fail first; the rest are grouped by the executor's group key and
+        packed into chunks exactly as the drain thread does.  A failed
+        chunk fails only its own futures.  The scheduler stays usable: a
+        request submitted afterwards waits for the next flush (or the drain
+        thread)."""
+        with self._cv:
             now = self._clock()
-            with self._cv:
-                expired = self._expire_locked(now)
-                batches = self._pop_all()
-            self._fail_expired(expired, now)
-            self._run_batches(batches)
+            expired = self._expire_locked(now)
+            batches = self._pop_all()
+        self._fail_expired(expired, now)
+        return self._run_batches(batches)
 
     def __enter__(self) -> "AsyncBatchedSampler":
         return self.start()
@@ -448,7 +457,7 @@ class AsyncBatchedSampler:
         """Pop ready chunks under the lock: highest-priority queue first
         (a queue's priority is its most urgent pending request's), oldest
         arrival breaking ties."""
-        exe = self.engine.executor
+        exe = self.executor
         ready: list[tuple[int, float, tuple[str, int, int]]] = []
         for key, q in self._queues.items():
             if not q:
@@ -477,7 +486,7 @@ class AsyncBatchedSampler:
         order); the remainder keeps its arrival times for the next launch.
         On flush the whole queue goes.  Non-fusable configs split into
         exact-size solo chunks."""
-        exe = self.engine.executor
+        exe = self.executor
         entries = list(self._queues[key])
         order = sorted(
             range(len(entries)),
@@ -520,7 +529,7 @@ class AsyncBatchedSampler:
         for (_solver, seq_len, nfe), chunk, pad, futures in batches:
             results: dict[int, SampleResult] = {}
             try:
-                self.engine.executor.run_chunk(
+                self.executor.run_chunk(
                     self.params, seq_len, nfe, chunk, results, pad=pad
                 )
             except Exception as e:  # noqa: BLE001 - delivered via futures
@@ -553,7 +562,6 @@ class AsyncBatchedSampler:
 
     def _loop(self) -> None:
         while True:
-            batches, expired, now = [], [], self._clock()
             with self._cv:
                 while not self._stopping:
                     now = self._clock()
@@ -563,12 +571,7 @@ class AsyncBatchedSampler:
                         break
                     with jax.profiler.TraceAnnotation("sampler.wait"):
                         self._cv.wait(timeout=self._next_deadline_s(now))
-                stopping = self._stopping
-                if stopping:
-                    now = self._clock()
-                    expired.extend(self._expire_locked(now))
-                    batches = self._pop_all()
+                else:
+                    return  # stop() flushes what is left
             self._fail_expired(expired, now)
             self._run_batches(batches)
-            if stopping:
-                return

@@ -13,7 +13,7 @@ import json
 
 # sys.path[0] is this script's dir (tests/), so conftest resolves; the
 # parent provides src/ on PYTHONPATH
-from conftest import AnalyticGaussian, OracleDenoiser
+from conftest import AnalyticGaussian, OracleDenoiser, run_flush
 
 from repro.serving import (
     EngineConfig,
@@ -36,11 +36,8 @@ def main() -> None:
     engine = build_engine(OracleDenoiser(analytic), analytic.schedule, cfg)
     report = engine.warmup(None, **warmup_kwargs(cfg))
 
-    _, fut = engine.submit_with_future(
-        SampleRequest(batch=2, seq_len=8, nfe=6, seed=7)
-    )
-    engine.drain(None)
-    x0 = fut.result().x0
+    (res,) = run_flush(engine, [SampleRequest(batch=2, seq_len=8, nfe=6, seed=7)])
+    x0 = res.x0
 
     print(
         json.dumps(

@@ -70,6 +70,17 @@ class OracleDenoiser:
         return self.analytic.eps
 
 
+def run_flush(engine, reqs, params=None):
+    """Queue ``reqs`` on a scheduler over ``engine`` (a ``FusedExecutor``),
+    flush them on this thread, and return their results in submit order."""
+    from repro.serving import AsyncBatchedSampler
+
+    queue = AsyncBatchedSampler(engine, params)
+    futs = [queue.submit(r) for r in reqs]
+    queue.flush()
+    return [f.result(timeout=0) for f in futs]
+
+
 def host_spans(log_dir, prefix="sampler."):
     """Every host event of the profiler trace under ``log_dir`` whose name
     starts with ``prefix``: name, thread, start and end in ns, and its

@@ -3,11 +3,12 @@
 The serving contract: a seeded request's ``x0`` is **bit-identical**
 whether it runs
 
-* via the sync engine's ``drain()`` (fused with whoever was pending),
-* via the async scheduler under an arbitrary arrival interleaving — client
-  threads racing, random delays, whatever batch compositions the policy
-  happens to form — or
-* solo through :class:`SamplerService` (exact-size batch, no padding).
+* via the scheduler's ``flush()`` on the caller's thread (fused with
+  whoever was queued) — the "sync" path below,
+* via the scheduler's drain thread under an arbitrary arrival
+  interleaving — client threads racing, random delays, whatever batch
+  compositions the policy happens to form — the "async" path, or
+* solo, flushed alone through an exact-size engine (no padding).
 
 Per-sample ERS is what makes this hold (each row's delta_eps measurement
 and Lagrange base selection read only its own row), and this property is
@@ -19,7 +20,7 @@ mesh fixture.
 PR-4 extends the wall to **mixed-solver streams**: requests routed to
 different registry solvers (`era` / `ddim` / `dpm_solver_pp2m`) interleave
 in one scheduler, batch per (solver, seq_len, nfe) queue, and every
-request's x0 still matches its sync-drain and solo runs bit-for-bit.
+request's x0 still matches its flushed and solo runs bit-for-bit.
 
 PR-5 extends it to **mixed-seq-len streams**: with `seq_buckets` the
 scheduler queues key on the seq *bucket*, so requests of different lengths
@@ -32,7 +33,7 @@ key on the NFE *bucket*, so 10/18/25-NFE requests share step-masked fused
 batches.  The step-masked contract is composition-shaped: a request's x0
 depends only on the compiled batch shape it ran at — never on its
 batch-mates' values, NFEs, or row order — so async results are bitwise
-equal to the sync drain whenever the scheduler formed the same batch
+equal to the flush whenever the scheduler formed the same batch
 bucket, and within float tolerance (last-ulp transcendental rounding on
 batch-shaped time columns) when it formed a different one (see also
 `tests/test_nfe_bucketing.py`).
@@ -45,13 +46,12 @@ import time
 import numpy as np
 
 from hypothesis import given, settings, strategies as st
-from conftest import AnalyticGaussian, OracleDenoiser
+from conftest import AnalyticGaussian, OracleDenoiser, run_flush
 from repro.core import ERAConfig
 from repro.serving import (
     AsyncBatchedSampler,
-    BatchedSampler,
+    FusedExecutor,
     SampleRequest,
-    SamplerService,
     SchedulerPolicy,
 )
 
@@ -76,7 +76,7 @@ def _requests(n, seq_len, nfe, seed0, mixed=False):
 
 
 def _engine(mesh=None, seq_buckets=None, nfe_buckets=None):
-    return BatchedSampler(
+    return FusedExecutor(
         OracleDenoiser(ANALYTIC),
         ANALYTIC.schedule,
         batch_buckets=(2, 4, 8),
@@ -87,10 +87,7 @@ def _engine(mesh=None, seq_buckets=None, nfe_buckets=None):
 
 
 def _sync_results(reqs, mesh=None, seq_buckets=None, nfe_buckets=None):
-    engine = _engine(mesh, seq_buckets, nfe_buckets)
-    tickets = [engine.submit(r) for r in reqs]
-    results = engine.drain(params=None)
-    return [results[t] for t in tickets]
+    return run_flush(_engine(mesh, seq_buckets, nfe_buckets), reqs)
 
 
 def _sync_x0(reqs, mesh=None, seq_buckets=None):
@@ -145,14 +142,14 @@ def _async_x0(reqs, delay_seed, mesh=None, seq_buckets=None):
     ]
 
 
-def _solo_x0(reqs, mesh=None):
-    svc = SamplerService(
+def _solo_x0(reqs):
+    engine = FusedExecutor(
         OracleDenoiser(ANALYTIC),
         ANALYTIC.schedule,
         solver_config=ERAConfig(per_sample=True),
-        mesh=mesh,
+        batch_buckets=None,
     )
-    return [np.asarray(svc.sample(None, r).x0) for r in reqs]
+    return [np.asarray(run_flush(engine, [r])[0].x0) for r in reqs]
 
 
 @settings(max_examples=4, deadline=None)
@@ -269,7 +266,7 @@ NFE_STREAM = (10, 18, 25)  # 10/18 share the 18-bucket; 25 rides the 32
 
 def _assert_composition_shaped(asyn, sync, label):
     """The step-masked determinism contract: bitwise whenever the
-    scheduler formed the same batch bucket as the sync drain, float-
+    scheduler formed the same batch bucket as the flush, float-
     tolerance (last-ulp transcendental rounding) when it formed a
     different one."""
     for i, (a, s) in enumerate(zip(asyn, sync)):

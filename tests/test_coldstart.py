@@ -2,10 +2,10 @@
 persistent compilation cache survives process boots, and ``/readyz``
 gates traffic on warmup.
 
-The wall these tests form around :meth:`BatchedSampler.warmup`:
+The wall these tests form around :meth:`FusedExecutor.warmup`:
 
 * warmup populates the full program grid with **zero** sampling — no
-  ``run_chunk`` calls, no drained batches — and serving after it is pure
+  ``run_chunk`` calls, no batches run — and serving after it is pure
   memory hits with output bit-identical to a cold engine's;
 * a second process boot against the same ``JAX_COMPILATION_CACHE_DIR``
   loads its programs from disk instead of compiling them;
@@ -24,11 +24,11 @@ import jax
 import numpy as np
 import pytest
 
-from conftest import OracleDenoiser
+from conftest import OracleDenoiser, run_flush
 from repro.serving import (
-    BatchedSampler,
     EngineConfig,
     FrontDoorClient,
+    FusedExecutor,
     SampleRequest,
     SchedulerPolicy,
     build_engine,
@@ -43,7 +43,7 @@ SEQS = (4, 8)
 
 @pytest.fixture()
 def engine(analytic):
-    return BatchedSampler(
+    return FusedExecutor(
         OracleDenoiser(analytic),
         analytic.schedule,
         batch_buckets=BATCHES,
@@ -66,7 +66,7 @@ def grid_requests(nfe=10):
 
 
 def test_warmup_compiles_grid_without_sampling(engine, monkeypatch):
-    ex = engine.executor
+    ex = engine
     chunks = []
     real_run_chunk = ex.run_chunk
     monkeypatch.setattr(
@@ -96,20 +96,18 @@ def test_warmed_engine_serves_grid_with_zero_fresh_compiles(engine, analytic):
     engine.warmup(None)
     fresh_after_warmup = engine.compile_stats()["fresh"]
 
-    cold = BatchedSampler(
+    cold = FusedExecutor(
         OracleDenoiser(analytic),
         analytic.schedule,
         batch_buckets=BATCHES,
         seq_buckets=SEQS,
     )
-    for r in grid_requests(nfe=engine.executor.solver_config.nfe):
-        _, warm_fut = engine.submit_with_future(r)
-        engine.drain(None)
-        _, cold_fut = cold.submit_with_future(r)
-        cold.drain(None)
+    for r in grid_requests(nfe=engine.solver_config.nfe):
+        (warm,) = run_flush(engine, [r])
+        (cold_res,) = run_flush(cold, [r])
         # warmed programs == cold-compiled programs, bit for bit
         np.testing.assert_array_equal(
-            np.asarray(warm_fut.result().x0), np.asarray(cold_fut.result().x0)
+            np.asarray(warm.x0), np.asarray(cold_res.x0)
         )
     # every serving-path acquisition was a memory hit
     assert engine.compile_stats()["fresh"] == fresh_after_warmup
@@ -143,16 +141,14 @@ def test_warmup_rejects_unserveable_grid(engine):
 
 
 def test_warmup_without_ladder_needs_seq_lens(analytic):
-    eng = BatchedSampler(
+    eng = FusedExecutor(
         OracleDenoiser(analytic), analytic.schedule, batch_buckets=BATCHES
     )
     with pytest.raises(ValueError, match="seq_lens"):
         eng.warmup(None)
     report = eng.warmup(None, seq_lens=(6,))
     assert report["programs"] == len(BATCHES)
-    _, fut = eng.submit_with_future(SampleRequest(batch=1, seq_len=6, nfe=10, seed=0))
-    eng.drain(None)
-    fut.result()
+    run_flush(eng, [SampleRequest(batch=1, seq_len=6, nfe=10, seed=0)])
     assert eng.compile_stats()["memory"] == 1
 
 
@@ -178,7 +174,7 @@ NFE_BUCKETS = (8, 16)
 
 
 def _nfe_bucketed_engine(analytic):
-    return BatchedSampler(
+    return FusedExecutor(
         OracleDenoiser(analytic),
         analytic.schedule,
         batch_buckets=BATCHES,
@@ -207,17 +203,15 @@ def test_warmed_engine_serves_mixed_nfes_memory_hit_only(analytic):
     eng = _nfe_bucketed_engine(analytic)
     eng.warmup(None)
     fresh_after_warmup = eng.compile_stats()["fresh"]
-    futures = []
+    served = []
     for i, (nfe, seq) in enumerate(
         [(5, 3), (8, 4), (10, 7), (16, 8), (6, 5), (13, 2)]
     ):
-        _, fut = eng.submit_with_future(
-            SampleRequest(batch=1, seq_len=seq, nfe=nfe, seed=i)
+        (res,) = run_flush(
+            eng, [SampleRequest(batch=1, seq_len=seq, nfe=nfe, seed=i)]
         )
-        futures.append((fut, nfe))
-        eng.drain(None)
-    for fut, nfe in futures:
-        res = fut.result()
+        served.append((res, nfe))
+    for res, nfe in served:
         assert res.padded_nfe in NFE_BUCKETS and res.padded_nfe >= nfe
     # post-warmup mixed-NFE serving is pure memory hits
     assert eng.compile_stats()["fresh"] == fresh_after_warmup
@@ -285,7 +279,7 @@ def test_cache_configured_after_first_compile_still_takes_effect(
     from repro.serving import configure_persistent_cache
 
     def boot():
-        eng = BatchedSampler(
+        eng = FusedExecutor(
             OracleDenoiser(analytic), analytic.schedule,
             batch_buckets=(1,), seq_buckets=(4,),
         )
@@ -312,7 +306,7 @@ def test_cache_configured_after_first_compile_still_takes_effect(
 
 
 def _ready_door(analytic, warmup):
-    eng = BatchedSampler(
+    eng = FusedExecutor(
         OracleDenoiser(analytic), analytic.schedule,
         batch_buckets=BATCHES, seq_buckets=SEQS,
     )

@@ -24,11 +24,11 @@ import jax
 import numpy as np
 import pytest
 
-from conftest import AnalyticGaussian, OracleDenoiser
+from conftest import AnalyticGaussian, OracleDenoiser, run_flush
 from repro.core import AdaptiveDPMConfig, get_solver
 from repro.serving import (
-    BatchedSampler,
     FrontDoorClient,
+    FusedExecutor,
     SampleRequest,
     SchedulerPolicy,
     result_keys as K,
@@ -144,7 +144,7 @@ def test_tightening_tolerances_monotonically_raises_realized_nfe():
 
 def _engine(config=None, **kw):
     kw.setdefault("batch_buckets", (2,))
-    return BatchedSampler(
+    return FusedExecutor(
         OracleDenoiser(ANALYTIC),
         ANALYTIC.schedule,
         solver="dpm_adaptive",
@@ -156,15 +156,15 @@ def _engine(config=None, **kw):
 def test_validate_rejects_unserveable_configs_at_submit():
     req = SampleRequest(batch=1, seq_len=4, nfe=12)
     with pytest.raises(ValueError, match="budget must be >= 2"):
-        _engine().submit(SampleRequest(batch=1, seq_len=4, nfe=1))
+        _engine().validate(SampleRequest(batch=1, seq_len=4, nfe=1))
     with pytest.raises(ValueError, match="must be positive"):
-        _engine(AdaptiveDPMConfig(rtol=-0.1)).submit(req)
+        _engine(AdaptiveDPMConfig(rtol=-0.1)).validate(req)
     with pytest.raises(ValueError, match="below the serveable floor"):
-        _engine(AdaptiveDPMConfig(rtol=1e-6, atol=1e-6)).submit(req)
+        _engine(AdaptiveDPMConfig(rtol=1e-6, atol=1e-6)).validate(req)
     with pytest.raises(ValueError, match="limiter ceiling"):
-        _engine(AdaptiveDPMConfig(accept_safety=2.8)).submit(req)
+        _engine(AdaptiveDPMConfig(accept_safety=2.8)).validate(req)
     # the floor is per-pair: one serveable tolerance is enough
-    _engine(AdaptiveDPMConfig(rtol=1e-6, atol=0.01)).submit(req)
+    _engine(AdaptiveDPMConfig(rtol=1e-6, atol=0.01)).validate(req)
 
 
 def test_adaptive_serves_mixed_budgets_under_nfe_bucketing():
@@ -172,23 +172,22 @@ def test_adaptive_serves_mixed_budgets_under_nfe_bucketing():
     reports its own realized NFE, capped by its own budget — not the
     bucket's."""
     engine = _engine(batch_buckets=(2, 4), nfe_buckets=(32,))
-    ta = engine.submit(SampleRequest(batch=1, seq_len=4, nfe=10, seed=1))
-    tb = engine.submit(SampleRequest(batch=2, seq_len=4, nfe=25, seed=2))
-    results = engine.drain(None)
-    assert results[ta].padded_nfe == 32
-    for t, budget, rows in ((ta, 10, 1), (tb, 25, 2)):
-        realized = np.asarray(results[t].aux["realized_nfe"])
+    ra, rb = run_flush(engine, [
+        SampleRequest(batch=1, seq_len=4, nfe=10, seed=1),
+        SampleRequest(batch=2, seq_len=4, nfe=25, seed=2),
+    ])
+    assert ra.padded_nfe == 32
+    for res, budget, rows in ((ra, 10, 1), (rb, 25, 2)):
+        realized = np.asarray(res.aux["realized_nfe"])
         assert realized.shape == (rows,)
         assert (realized >= 2).all() and (realized <= budget).all()
-        assert results[t].info[K.REALIZED_NFE] is results[t].aux[
-            "realized_nfe"
-        ]
+        assert res.info[K.REALIZED_NFE] is res.aux["realized_nfe"]
 
 
 def test_adaptive_serves_through_front_door_with_realized_nfe():
     """The acceptance check: an adaptive request through the unchanged
     front door returns 200 with the per-row realized NFE in ``info``,
-    bit-identical to the in-process drain."""
+    bit-identical to the in-process flush."""
     door = serve_frontdoor(
         _engine(nfe_buckets=(16,)), params=None,
         policy=SchedulerPolicy(max_wait_ms=5.0),
@@ -203,9 +202,7 @@ def test_adaptive_serves_through_front_door_with_realized_nfe():
     assert 2 <= int(realized[0]) <= 12 and int(realized[0]) % 2 == 0
     assert wire.info[K.PADDED_NFE] == 16
 
-    local_engine = _engine(nfe_buckets=(16,))
-    t = local_engine.submit(req)
-    local = local_engine.drain(None)[t]
+    (local,) = run_flush(_engine(nfe_buckets=(16,)), [req])
     np.testing.assert_array_equal(np.asarray(local.x0), wire.x0)
     np.testing.assert_array_equal(
         np.asarray(local.aux["realized_nfe"]), realized

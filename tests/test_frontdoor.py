@@ -11,15 +11,20 @@ it.
 * priority — higher-priority requests board a launch first under a fake
   clock (``drain_once()``, no threads, no sleeps);
 * loopback end-to-end — a wire request's x0 is bit-identical to the same
-  seed through the in-process SamplerService, through the same
-  ``build_engine`` factory path;
+  seed flushed in-process, through the same ``build_engine`` factory path;
 * observability — /metrics exposes the serving instruments, /healthz
-  reports scheduler stats, errors map to typed JSON.
+  reports scheduler stats, errors map to typed JSON;
+* the CLI server — ``repro.launch.serve --listen`` boots in a subprocess,
+  prints its ready line and serves a wire request.
 
 All engine tests use the analytic OracleDenoiser: exact, fast, no params.
 """
 
 import json
+import os
+import queue
+import subprocess
+import sys
 import threading
 import time
 from http.client import HTTPConnection
@@ -28,7 +33,7 @@ import jax
 import numpy as np
 import pytest
 
-from conftest import AnalyticGaussian, OracleDenoiser, host_spans
+from conftest import AnalyticGaussian, OracleDenoiser, host_spans, run_flush
 from repro.core import linear_schedule
 from repro.serving import (
     AsyncBatchedSampler,
@@ -38,7 +43,6 @@ from repro.serving import (
     FrontDoorClient,
     QueueFullError,
     SampleRequest,
-    SamplerService,
     SchedulerPolicy,
     SchemaError,
     build_engine,
@@ -124,10 +128,7 @@ def test_request_field_types_validated():
 
 
 def test_result_round_trip_bit_exact():
-    engine = make_engine()
-    _, fut = engine.submit_with_future(req(seed=3, batch=2))
-    engine.drain(None)
-    res = fut.result()
+    (res,) = run_flush(make_engine(), [req(seed=3, batch=2)])
     back = decode_result(json.loads(json.dumps(encode_result(res))))
     np.testing.assert_array_equal(np.asarray(res.x0), back.x0)
     assert set(back.aux) == set(res.aux)
@@ -204,7 +205,7 @@ def test_deadline_expired_fails_fast():
     with pytest.raises(DeadlineExceededError, match="expired in queue"):
         doomed.result(timeout=5)
     assert healthy.result(timeout=5).x0.shape == (1, 6, D_MODEL)
-    m = sched.engine.metrics.get("sampler_deadline_expired_total")
+    m = sched.executor.metrics.get("sampler_deadline_expired_total")
     assert m.value() == 1.0
 
 
@@ -217,30 +218,31 @@ def test_deadline_not_expired_is_untouched():
 
 
 def test_deadline_validated_at_submit():
-    engine = make_engine()
+    sched = AsyncBatchedSampler(make_engine(), params=None)
     for bad in (0.0, -5.0, float("inf"), float("nan"), "soon"):
         with pytest.raises(ValueError, match="deadline_ms"):
-            engine.submit_with_future(req(deadline_ms=bad))
+            sched.submit(req(deadline_ms=bad))
     for bad in (1.5, "high", True):
         with pytest.raises(ValueError, match="priority"):
-            engine.submit_with_future(req(priority=bad))
+            sched.submit(req(priority=bad))
+    assert sched.pending == 0
 
 
 def test_seed_validated_at_submit():
     """A seed PRNGKey cannot fold (outside int64 — JSON ints are
     unbounded) must be rejected at submit, not explode at drain time
     inside a fused batch."""
-    engine = make_engine()
+    sched = AsyncBatchedSampler(make_engine(), params=None)
     for bad in (2**63, -(2**63) - 1, 2**200):
         with pytest.raises(ValueError, match="seed"):
-            engine.submit_with_future(req(seed=bad))
+            sched.submit(req(seed=bad))
     for bad in (1.5, "7", True):
         with pytest.raises(ValueError, match="seed"):
-            engine.submit_with_future(req(seed=bad))
+            sched.submit(req(seed=bad))
     # the extremes of the accepted range sample fine
     for ok in (2**63 - 1, -(2**63)):
-        _, fut = engine.submit_with_future(req(seed=ok))
-        engine.drain(None)
+        fut = sched.submit(req(seed=ok))
+        sched.flush()
         assert fut.result().x0.shape == (1, 6, D_MODEL)
 
 
@@ -248,29 +250,31 @@ def test_resource_caps_validated_at_submit():
     """Server-side maxima on wire-exposed resource fields: an admitted
     request must never be able to force a multi-GB allocation or an
     unbounded jit cache at drain."""
-    engine = make_engine(max_batch=4, max_nfe=8, max_seq_len=16)
+    sched = AsyncBatchedSampler(
+        make_engine(max_batch=4, max_nfe=8, max_seq_len=16), params=None
+    )
     with pytest.raises(ValueError, match="max_batch"):
-        engine.submit_with_future(req(batch=5))
+        sched.submit(req(batch=5))
     with pytest.raises(ValueError, match="max_nfe"):
-        engine.submit_with_future(req(nfe=9))
+        sched.submit(req(nfe=9))
     with pytest.raises(ValueError, match="max_seq_len"):
-        engine.submit_with_future(req(seq_len=17))
+        sched.submit(req(seq_len=17))
     # at the caps everything still runs
-    _, fut = engine.submit_with_future(req(batch=4, nfe=8, seq_len=16))
-    engine.drain(None)
+    fut = sched.submit(req(batch=4, nfe=8, seq_len=16))
+    sched.flush()
     assert fut.result().x0.shape == (4, 16, D_MODEL)
     # a seq-bucket ladder takes over bounding the sequence axis: the
     # ladder top (not max_seq_len) is the contract
-    bucketed = make_engine(
-        max_seq_len=16, seq_buckets=(8,), batch_buckets=(1, 2)
+    bucketed = AsyncBatchedSampler(
+        make_engine(max_seq_len=16, seq_buckets=(8,), batch_buckets=(1, 2)),
+        params=None,
     )
     with pytest.raises(ValueError, match="seq bucket"):
-        bucketed.submit_with_future(req(seq_len=9))
+        bucketed.submit(req(seq_len=9))
     # caps are opt-out for trusted in-process callers
     unbounded = make_engine(max_batch=None, max_nfe=None, max_seq_len=None)
-    _, fut = unbounded.submit_with_future(req(batch=5, nfe=9, seq_len=17))
-    unbounded.drain(None)
-    assert fut.result().x0.shape == (5, 17, D_MODEL)
+    (res,) = run_flush(unbounded, [req(batch=5, nfe=9, seq_len=17)])
+    assert res.x0.shape == (5, 17, D_MODEL)
 
 
 def test_admission_bound_rejects_then_recovers():
@@ -293,17 +297,8 @@ def test_admission_bound_rejects_then_recovers():
     clk[0] = 2.0
     sched.drain_once(now=clk[0])
     assert fut.result(timeout=5).x0.shape == (1, 6, D_MODEL)
-    m = sched.engine.metrics.get("sampler_admission_rejects_total")
+    m = sched.executor.metrics.get("sampler_admission_rejects_total")
     assert m.value(solver="era", seq=6, nfe=6) == 1.0
-
-
-def test_submit_int_ticket_deprecated():
-    engine = make_engine()
-    with pytest.warns(DeprecationWarning, match="submit_with_future"):
-        ticket = engine.submit(req(seed=0))
-    fut = engine.future(ticket)
-    engine.drain(None)
-    assert fut.result().x0.shape == (1, 6, D_MODEL)
 
 
 # ---------------------------------------------------------------------------
@@ -322,11 +317,11 @@ def door():
 
 def test_wire_matches_in_process_bit_identical(door):
     """The acceptance check: a loopback wire request returns x0 bit-
-    identical to the same request through the in-process SamplerService,
-    both engines built by the same factory config."""
+    identical to the same request flushed in-process, both engines built
+    by the same factory config."""
     r = req(seed=7, batch=2)
     wire = FrontDoorClient(door.url, timeout=60).sample(r)
-    local = SamplerService(engine=make_engine()).sample(None, r)
+    (local,) = run_flush(make_engine(), [r])
     np.testing.assert_array_equal(np.asarray(local.x0), wire.x0)
     assert wire.x0.dtype == np.asarray(local.x0).dtype
     for k in local.aux:
@@ -351,9 +346,7 @@ def test_wire_concurrent_requests_fuse_and_stay_isolated(door):
     for t in threads:
         t.join()
     for seed in (11, 12):
-        solo = SamplerService(engine=make_engine()).sample(
-            None, req(seed=seed)
-        )
+        (solo,) = run_flush(make_engine(), [req(seed=seed)])
         np.testing.assert_array_equal(np.asarray(solo.x0), out[seed].x0)
 
 
@@ -449,9 +442,7 @@ def test_wire_burst_429_while_inflight_completes():
     assert not errors
     assert sorted(results) == [0, 1]
     for seed, res in results.items():
-        solo = SamplerService(engine=make_engine()).sample(
-            None, req(seed=seed)
-        )
+        (solo,) = run_flush(make_engine(), [req(seed=seed)])
         np.testing.assert_array_equal(np.asarray(solo.x0), res.x0)
 
 
@@ -512,7 +503,7 @@ def test_wire_poison_request_400_not_500(door):
         assert body["error"]["type"] == "invalid_request"
     conn.close()
     th.join(timeout=60)
-    solo = SamplerService(engine=make_engine()).sample(None, req(seed=21))
+    (solo,) = run_flush(make_engine(), [req(seed=21)])
     np.testing.assert_array_equal(np.asarray(solo.x0), out["res"].x0)
 
 
@@ -636,6 +627,66 @@ def test_metrics_and_healthz(door):
     assert "# TYPE sampler_request_latency_seconds histogram" in text
     assert 'le="+Inf"' in text
     assert text.endswith("\n")
+
+
+def test_serve_listen_subprocess_boots_and_serves():
+    """``python -m repro.launch.serve --listen --port 0`` in a child
+    process: it prints ``FRONTDOOR READY <url>``, warms its grid behind
+    /readyz, serves a wire request and exposes the serving instruments
+    on /metrics."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(root, "src") + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
+    )
+    proc = subprocess.Popen(
+        [
+            sys.executable, "-m", "repro.launch.serve",
+            "--arch", "qwen2-1.5b", "--smoke", "--mode", "diffusion",
+            "--listen", "--port", "0", "--nfe", "6", "--k", "3",
+            "--seq", "8", "--batch-buckets", "1",
+        ],
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+        cwd=root, env=env,
+    )
+    lines: queue.Queue = queue.Queue()
+    threading.Thread(
+        target=lambda: [lines.put(ln) for ln in proc.stdout] + [lines.put(None)],
+        daemon=True,
+    ).start()
+    try:
+        seen = []
+        while True:
+            line = lines.get(timeout=300)
+            assert line is not None, f"server exited before ready: {seen}"
+            seen.append(line)
+            if line.startswith("FRONTDOOR READY "):
+                url = line.split()[-1]
+                break
+        client = FrontDoorClient(url, timeout=300)
+        t_deadline = time.monotonic() + 300
+        while not client.readyz()["ready"]:
+            assert time.monotonic() < t_deadline, client.readyz()
+            time.sleep(0.1)
+        res = client.sample(req(seed=5, seq_len=8))
+        assert res.x0.shape[:2] == (1, 8)
+        assert np.isfinite(res.x0).all()
+        text = client.metrics()
+        for name in (
+            "sampler_queue_depth_rows",
+            "sampler_compile_programs_total",
+            "sampler_warmup_programs_total",
+            "sampler_request_latency_seconds_bucket",
+            "frontdoor_http_requests_total",
+        ):
+            assert name in text, name
+    finally:
+        proc.terminate()
+        try:
+            proc.wait(timeout=30)
+        except subprocess.TimeoutExpired:
+            proc.kill()
+            proc.wait()
 
 
 def test_codec_spans(door, tmp_path):

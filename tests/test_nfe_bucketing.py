@@ -20,18 +20,18 @@ non-fusable configs) fall back to exact-NFE grouping on the
 ``sampler_masked_fallback_total`` canary, wasted pad step-rows are counted
 on ``sampler_nfe_padding_rows_total``, step-stacked aux is scoped back to
 each request's own step count, ``padded_nfe`` is surfaced through results
-and the info dict, and the mesh8 mixed-NFE drain matches.
+and the info dict, and the mesh8 mixed-NFE run matches.
 """
 
 import numpy as np
 import pytest
 
 from hypothesis import given, settings, strategies as st
-from conftest import AnalyticGaussian, OracleDenoiser
+from conftest import AnalyticGaussian, OracleDenoiser, run_flush
 from repro.core import ERAConfig, solver_names
 from repro.serving import (
     AsyncBatchedSampler,
-    BatchedSampler,
+    FusedExecutor,
     SampleRequest,
     result_keys as K,
 )
@@ -57,7 +57,7 @@ UNSTEPPED_SOLVERS = ("dpm_solver_2", "dpm_solver_fast")
 
 
 def _engine(nfe_buckets, mesh=None, **kw):
-    return BatchedSampler(
+    return FusedExecutor(
         OracleDenoiser(ANALYTIC),
         ANALYTIC.schedule,
         batch_buckets=(2, 4),
@@ -69,10 +69,7 @@ def _engine(nfe_buckets, mesh=None, **kw):
 
 
 def _drain_one(engine, req, mates=()):
-    ticket = engine.submit(req)
-    for m in mates:
-        engine.submit(m)
-    return engine.drain(None)[ticket]
+    return run_flush(engine, [req, *mates])[0]
 
 
 @settings(max_examples=2, deadline=None)
@@ -141,9 +138,9 @@ def test_every_registry_solver_is_classified():
     )
     engine = _engine((8, 16))
     for s in STEPPED_SOLVERS:
-        assert engine.executor.nfe_masked(s) is True, s
+        assert engine.nfe_masked(s) is True, s
     for s in UNSTEPPED_SOLVERS:
-        assert engine.executor.nfe_masked(s) is False, s
+        assert engine.nfe_masked(s) is False, s
 
 
 def test_unstepped_solver_falls_back_to_exact_nfe():
@@ -152,11 +149,11 @@ def test_unstepped_solver_falls_back_to_exact_nfe():
     verdict is counted once on the fallback canary."""
     engine = _engine((12, 25))
     for solver in UNSTEPPED_SOLVERS:
-        assert engine.executor.nfe_masked(solver) is False
+        assert engine.nfe_masked(solver) is False
         req = SampleRequest(
             batch=1, seq_len=5, nfe=10, solver=solver, seed=77
         )
-        assert engine.executor.group_key(req) == (solver, 8, 10)
+        assert engine.group_key(req) == (solver, 8, 10)
         got = _drain_one(engine, req)
         assert got.padded_nfe == 10  # exact, not a ladder bucket
         ref = _drain_one(_engine(None), req)
@@ -164,12 +161,12 @@ def test_unstepped_solver_falls_back_to_exact_nfe():
             np.asarray(got.x0), np.asarray(ref.x0),
             err_msg=f"exact-NFE fallback diverged (solver={solver})",
         )
-    counter = engine.executor.metrics.get("sampler_masked_fallback_total")
+    counter = engine.metrics.get("sampler_masked_fallback_total")
     assert counter.value(
         impl="nfe-bucketing", reason="program-no-steps"
     ) == len(UNSTEPPED_SOLVERS)
     # the verdict is cached per solver: re-asking does not re-count
-    assert engine.executor.nfe_masked("dpm_solver_fast") is False
+    assert engine.nfe_masked("dpm_solver_fast") is False
     assert counter.value(
         impl="nfe-bucketing", reason="program-no-steps"
     ) == len(UNSTEPPED_SOLVERS)
@@ -178,19 +175,19 @@ def test_unstepped_solver_falls_back_to_exact_nfe():
 def test_shared_delta_era_falls_back_to_exact_nfe():
     """Shared-delta ERA (per_sample=False) cannot pad in steps any more
     than in rows: exact-NFE grouping, counted as non-fusable-config."""
-    engine = BatchedSampler(
+    engine = FusedExecutor(
         OracleDenoiser(ANALYTIC),
         ANALYTIC.schedule,
         solver_config=ERAConfig(nfe=6, k=3, per_sample=False),
         batch_buckets=(2, 4),
         nfe_buckets=(8, 16),
     )
-    assert engine.executor.nfe_masked("era") is False
-    counter = engine.executor.metrics.get("sampler_masked_fallback_total")
+    assert engine.nfe_masked("era") is False
+    counter = engine.metrics.get("sampler_masked_fallback_total")
     assert counter.value(
         impl="nfe-bucketing", reason="non-fusable-config"
     ) == 1
-    assert engine.executor.group_key(
+    assert engine.group_key(
         SampleRequest(batch=1, seq_len=5, nfe=6)
     ) == ("era", 5, 6)
 
@@ -203,11 +200,9 @@ def test_mixed_nfes_fuse_into_one_chunk_per_bucket():
         SampleRequest(batch=1, seq_len=4, nfe=n, seed=10 + i)
         for i, n in enumerate([5, 7, 8, 6])  # all bucket to 8
     ]
-    tickets = [engine.submit(r) for r in reqs]
-    results = engine.drain(None)
-    for t in tickets:
-        assert results[t].padded_nfe == 8
-        assert results[t].padded_batch == 4  # one fused chunk of 4 rows
+    for res in run_flush(engine, reqs):
+        assert res.padded_nfe == 8
+        assert res.padded_batch == 4  # one fused chunk of 4 rows
     keys = set(engine.compile_cache())
     assert len(keys) == 1
     (key,) = keys
@@ -220,9 +215,8 @@ def test_mixed_nfes_fuse_into_one_chunk_per_bucket():
         SampleRequest(batch=1, seq_len=4, nfe=n, seed=50 + i)
         for i, n in enumerate([6, 9, 12, 10])
     ]
-    tickets = [engine.submit(r) for r in more]
-    results = engine.drain(None)
-    assert {results[t].padded_nfe for t in tickets} == {8, 12}
+    results = run_flush(engine, more)
+    assert {r.padded_nfe for r in results} == {8, 12}
     assert {k[1].nfe for k in engine.compile_cache()} <= {8, 12}
     compiled = len(engine.compile_cache())
 
@@ -233,22 +227,23 @@ def test_mixed_nfes_fuse_into_one_chunk_per_bucket():
         SampleRequest(batch=1, seq_len=4, nfe=n, seed=80 + i)
         for i, n in enumerate([4, 5, 7, 6, 11, 9, 10])
     ]
-    tickets = [engine.submit(r) for r in third]
-    engine.drain(None)
+    run_flush(engine, third)
     assert len(engine.compile_cache()) == compiled
 
 
 def test_nfe_above_ladder_rejected_at_submit():
     engine = _engine((8, 12))
     with pytest.raises(ValueError, match="exceeds the largest nfe bucket"):
-        engine.submit(SampleRequest(batch=1, seq_len=4, nfe=13))
-    # the async scheduler rejects at submit too (same validate path)
+        engine.validate(SampleRequest(batch=1, seq_len=4, nfe=13))
+    # the scheduler rejects at submit (same validate path)
     sched = AsyncBatchedSampler(engine, params=None)
     with pytest.raises(ValueError, match="exceeds the largest nfe bucket"):
         sched.submit(SampleRequest(batch=1, seq_len=4, nfe=40))
     sched.stop()
     # engines without a ladder accept the same nfe
-    _engine(None).submit(SampleRequest(batch=1, seq_len=4, nfe=13))
+    sched = AsyncBatchedSampler(_engine(None), params=None)
+    sched.submit(SampleRequest(batch=1, seq_len=4, nfe=13))
+    assert sched.pending == 1
 
 
 def test_nfe_padding_rows_counter_counts_wasted_step_rows():
@@ -256,25 +251,26 @@ def test_nfe_padding_rows_counter_counts_wasted_step_rows():
     with padded (inert) steps — the ladder-tuning signal — and stays
     silent for traffic landing exactly on a bucket."""
     engine = _engine((8,))
-    engine.submit(SampleRequest(batch=1, seq_len=4, nfe=5, seed=1))
-    engine.submit(SampleRequest(batch=2, seq_len=4, nfe=8, seed=2))
-    engine.drain(None)
-    counter = engine.executor.metrics.get("sampler_nfe_padding_rows_total")
+    sched = AsyncBatchedSampler(engine, params=None)
+    sched.submit(SampleRequest(batch=1, seq_len=4, nfe=5, seed=1))
+    sched.submit(SampleRequest(batch=2, seq_len=4, nfe=8, seed=2))
+    sched.flush()
+    counter = engine.metrics.get("sampler_nfe_padding_rows_total")
     assert counter is not None
     # only the 5-NFE request's single row padded; the 8-NFE rows ran
     # exactly, and the batch pad row runs the full bucket grid by design
     assert counter.value(solver="era") == 1
 
-    engine.submit(SampleRequest(batch=2, seq_len=4, nfe=8, seed=3))
-    engine.drain(None)
-    assert counter.value(solver="era") == 1  # fully-active drain: no-op
+    sched.submit(SampleRequest(batch=2, seq_len=4, nfe=8, seed=3))
+    sched.flush()
+    assert counter.value(solver="era") == 1  # fully-active batch: no-op
 
 
 def test_step_stacked_aux_scoped_to_request_nfe():
     """Step-stacked aux (trajectory, ERS histories) drops the inert pad
     tail: a 5-NFE request fused into an 8-NFE bucket gets histories at
     its own step count, same as its unpadded run."""
-    engine = BatchedSampler(
+    engine = FusedExecutor(
         OracleDenoiser(ANALYTIC),
         ANALYTIC.schedule,
         solver_config=ERAConfig(per_sample=True, return_trajectory=True),
@@ -282,46 +278,43 @@ def test_step_stacked_aux_scoped_to_request_nfe():
         seq_buckets=SEQ_BUCKETS,
         nfe_buckets=(8,),
     )
-    ta = engine.submit(SampleRequest(batch=1, seq_len=3, nfe=5, seed=0))
-    tb = engine.submit(SampleRequest(batch=2, seq_len=7, nfe=8, seed=1))
-    results = engine.drain(None)
+    ra, rb = run_flush(engine, [
+        SampleRequest(batch=1, seq_len=3, nfe=5, seed=0),
+        SampleRequest(batch=2, seq_len=7, nfe=8, seed=1),
+    ])
     # trajectory: x_init + one entry per *own* step, not per bucket step
-    assert results[ta].aux["trajectory"].shape == (
+    assert ra.aux["trajectory"].shape == (
         6, 1, 3, OracleDenoiser.D_MODEL
     )
-    assert results[tb].aux["trajectory"].shape == (
+    assert rb.aux["trajectory"].shape == (
         9, 2, 7, OracleDenoiser.D_MODEL
     )
-    assert results[ta].aux["ers_selection_history"].shape[0] == 5
-    assert results[ta].aux["delta_eps_history_per_sample"].shape[0] == 5
-    assert results[tb].aux["ers_selection_history"].shape[0] == 8
+    assert ra.aux["ers_selection_history"].shape[0] == 5
+    assert ra.aux["delta_eps_history_per_sample"].shape[0] == 5
+    assert rb.aux["ers_selection_history"].shape[0] == 8
 
 
 def test_mesh_mixed_nfe_drain_parity(mesh8):
-    """Mixed-NFE fused drains on the 8-device mesh: bit-identical to the
-    mesh exact-NFE-bucket drains, and matching the single-device bucketed
+    """Mixed-NFE fused batches on the 8-device mesh: bit-identical to the
+    mesh exact-NFE-bucket runs, and matching the single-device bucketed
     run to float tolerance (the established mesh-parity bar)."""
     reqs = [
         SampleRequest(batch=1, seq_len=5, nfe=n, seed=900 + i)
         for i, n in enumerate([6, 10, 13])
     ]
     ladder = (16,)
-    mesh_engine = _engine(ladder, mesh=mesh8)
-    tickets = [mesh_engine.submit(r) for r in reqs]
-    fused = mesh_engine.drain(None)
-    single = _engine(ladder)
-    stickets = [single.submit(r) for r in reqs]
-    sres = single.drain(None)
-    for ticket, sticket, req in zip(tickets, stickets, reqs):
-        # mesh reference: same request drained at its exact NFE bucket
+    fused = run_flush(_engine(ladder, mesh=mesh8), reqs)
+    sres = run_flush(_engine(ladder), reqs)
+    for got, single, req in zip(fused, sres, reqs):
+        # mesh reference: same request run at its exact NFE bucket
         ref = _drain_one(_engine((req.nfe, 16), mesh=mesh8), req)
         np.testing.assert_array_equal(
-            np.asarray(fused[ticket].x0), np.asarray(ref.x0),
+            np.asarray(got.x0), np.asarray(ref.x0),
             err_msg=f"mesh NFE-padded vs mesh exact-bucket diverged "
             f"(nfe={req.nfe})",
         )
         np.testing.assert_allclose(
-            np.asarray(fused[ticket].x0), np.asarray(sres[sticket].x0),
+            np.asarray(got.x0), np.asarray(single.x0),
             atol=1e-5,
             err_msg=f"mesh vs single-device bucketed diverged "
             f"(nfe={req.nfe})",

@@ -13,8 +13,8 @@ from pathlib import Path
 
 from repro.core import ERAConfig, get_solver, linear_schedule
 
-from conftest import AnalyticGaussian, OracleDenoiser
-from repro.serving import SampleRequest, SamplerService, result_keys as K
+from conftest import AnalyticGaussian, OracleDenoiser, run_flush
+from repro.serving import FusedExecutor, SampleRequest, result_keys as K
 
 ROOT = Path(__file__).resolve().parent.parent
 SCANNED_DIRS = ("src/repro/serving", "benchmarks")
@@ -51,12 +51,14 @@ def test_constants_cover_info_dict():
     for the engine telemetry, AUX_KEYS for the solver diagnostics — no
     undocumented key can appear without failing here."""
     analytic = AnalyticGaussian()
-    svc = SamplerService(
+    engine = FusedExecutor(
         OracleDenoiser(analytic),
         linear_schedule(),
         solver_config=ERAConfig(nfe=6, k=3, per_sample=True),
+        batch_buckets=None,
     )
-    info = svc.sample(None, SampleRequest(batch=1, seq_len=4, nfe=6)).info
+    (res,) = run_flush(engine, [SampleRequest(batch=1, seq_len=4, nfe=6)])
+    info = res.info
     documented = set(K.INFO_KEYS) | set(K.AUX_KEYS)
     assert set(K.INFO_KEYS) <= set(info)
     undocumented = set(info) - documented

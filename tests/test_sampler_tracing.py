@@ -25,7 +25,7 @@ from repro.models import build_model
 from repro.models.diffusion import DiffusionLM
 from repro.serving import (
     AsyncBatchedSampler,
-    BatchedSampler,
+    FusedExecutor,
     SampleRequest,
     SchedulerPolicy,
 )
@@ -44,23 +44,25 @@ def smoke_engine():
     ERS, the fused update kernel), and its weights."""
     dlm = DiffusionLM(build_model(get_config("qwen2-1.5b", smoke=True)))
     params = dlm.init(jax.random.PRNGKey(0))
-    return BatchedSampler(dlm, linear_schedule(), batch_buckets=(4,)), params
+    return FusedExecutor(dlm, linear_schedule(), batch_buckets=(4,)), params
 
 
 def test_chunk_spans_nest(smoke_engine, tmp_path):
     eng, params = smoke_engine
-    eng.executor._jitted.clear()   # the first chunk compiles in the trace
+    eng._jitted.clear()   # the first chunk compiles in the trace
+    sched = AsyncBatchedSampler(eng, params)
     with jax.profiler.trace(str(tmp_path)):
-        t0, _ = eng.submit_with_future(SampleRequest(batch=1, seq_len=SEQ, nfe=NFE, seed=1))
-        t1, _ = eng.submit_with_future(SampleRequest(batch=2, seq_len=SEQ, nfe=NFE, seed=2))
-        eng.drain(params)
-        eng.submit_with_future(SampleRequest(batch=3, seq_len=SEQ, nfe=NFE, seed=3))
-        eng.drain(params)
+        sched.submit(SampleRequest(batch=1, seq_len=SEQ, nfe=NFE, seed=1))
+        sched.submit(SampleRequest(batch=2, seq_len=SEQ, nfe=NFE, seed=2))
+        sched.flush()
+        sched.submit(SampleRequest(batch=3, seq_len=SEQ, nfe=NFE, seed=3))
+        sched.flush()
     spans = host_spans(str(tmp_path))
     chunks = [s for s in spans if s["name"] == "sampler.chunk"]
     assert len(chunks) == 2
-    # the chunk's tickets and group key ride as metadata; the name stays bare
-    assert chunks[0]["stats"] == {"tickets": f"{t0} {t1}", "key": f"era/{SEQ}/{NFE}"}
+    # the chunk's tickets (the scheduler's, from 0) and group key ride as
+    # metadata; the name stays bare
+    assert chunks[0]["stats"] == {"tickets": "0 1", "key": f"era/{SEQ}/{NFE}"}
     for chunk in chunks:
         kids = [s for s in spans if s is not chunk and inside(s, chunk)]
         order = [s["name"] for s in kids if s["name"] != "sampler.compile"]
@@ -77,7 +79,7 @@ def test_chunk_spans_nest(smoke_engine, tmp_path):
 def test_bucket_program_metadata_holds_scopes(smoke_engine):
     eng, params = smoke_engine
     eng.warmup(params, seq_lens=(SEQ,), nfes=(NFE,))
-    (compiled,) = eng.executor.compile_cache().values()
+    (compiled,) = eng.compile_cache().values()
     op_names = re.findall(r'op_name="([^"]*)"', compiled.as_text())
     for scope in ("denoiser", "era.ers", "era.update"):
         assert any(f"/{scope}/" in n for n in op_names), scope
@@ -88,7 +90,7 @@ def test_bucket_program_metadata_holds_scopes(smoke_engine):
 
 
 def test_scheduler_waits_are_spans(analytic, tmp_path):
-    eng = BatchedSampler(
+    eng = FusedExecutor(
         OracleDenoiser(analytic), analytic.schedule, batch_buckets=(2,)
     )
     sched = AsyncBatchedSampler(eng, None, SchedulerPolicy(max_wait_ms=1.0))
@@ -113,7 +115,7 @@ class FakeClock:
 
 def test_queue_wait_and_assembly_counters(analytic):
     clock = FakeClock()
-    eng = BatchedSampler(
+    eng = FusedExecutor(
         OracleDenoiser(analytic), analytic.schedule, batch_buckets=(2,)
     )
     sched = AsyncBatchedSampler(

@@ -1,8 +1,10 @@
 """Scheduler wall for the continuous-batching AsyncBatchedSampler: policy
 logic under a fake clock (no real sleeps), liveness (a lone request never
 starves), thread-safe submission (no lost or duplicated tickets under
-concurrent submit stress), clean shutdown with in-flight work, and chunk-
-scoped failure isolation."""
+concurrent submit stress), clean shutdown with in-flight work, chunk-
+scoped failure isolation, and ``flush()`` — the caller-thread path sync
+callers use — sharing the queue and compiled buckets with the drain
+thread."""
 
 import threading
 import time
@@ -11,10 +13,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import OracleDenoiser
+from conftest import OracleDenoiser, run_flush
 from repro.serving import (
     AsyncBatchedSampler,
-    BatchedSampler,
+    FusedExecutor,
     SampleRequest,
     SchedulerPolicy,
     result_keys as K,
@@ -24,7 +26,7 @@ D_MODEL = OracleDenoiser.D_MODEL
 
 
 def make_engine(analytic, buckets=(2, 4, 8)):
-    return BatchedSampler(
+    return FusedExecutor(
         OracleDenoiser(analytic), analytic.schedule, batch_buckets=buckets
     )
 
@@ -118,13 +120,13 @@ def test_oldest_queue_served_first(analytic, monkeypatch):
         clock=clock,
     )
     order = []
-    orig = engine.executor.run_chunk
+    orig = engine.run_chunk
 
     def recording(params, seq_len, nfe, chunk, results, pad=True):
         order.append((seq_len, nfe))
         return orig(params, seq_len, nfe, chunk, results, pad=pad)
 
-    monkeypatch.setattr(engine.executor, "run_chunk", recording)
+    monkeypatch.setattr(engine, "run_chunk", recording)
     sched.submit(req(seed=0, seq_len=4))
     clock.now += 0.002
     sched.submit(req(seed=1, seq_len=6))
@@ -165,14 +167,14 @@ def test_chunk_failure_is_isolated(analytic, monkeypatch):
         policy=SchedulerPolicy(max_wait_ms=10.0),
         clock=clock,
     )
-    orig = engine.executor.run_chunk
+    orig = engine.run_chunk
 
     def flaky(params, seq_len, nfe, chunk, results, pad=True):
         if seq_len == 4:
             raise RuntimeError("injected kernel failure")
         return orig(params, seq_len, nfe, chunk, results, pad=pad)
 
-    monkeypatch.setattr(engine.executor, "run_chunk", flaky)
+    monkeypatch.setattr(engine, "run_chunk", flaky)
     bad = sched.submit(req(seed=0, seq_len=4))
     good = sched.submit(req(seed=1, seq_len=6))
     assert sched.drain_once(now=clock.now + 0.02) == 2
@@ -238,12 +240,11 @@ def test_concurrent_submit_stress_no_lost_or_duplicate_tickets(analytic):
     assert stats["rows"] == total  # no row lost, none launched twice
     # spot-check isolation: each future resolved to ITS request's samples
     # (bit-identical to a solo run of the same seed), not a batch-mate's
-    solo = BatchedSampler(
+    solo = FusedExecutor(
         OracleDenoiser(analytic), analytic.schedule, batch_buckets=None
     )
     for seed in (0, 1003, 3005):
-        ticket = solo.submit(req(seed=seed))
-        ref = solo.drain(params=None)[ticket].x0
+        ref = run_flush(solo, [req(seed=seed)])[0].x0
         np.testing.assert_array_equal(
             np.asarray(results[seed].x0), np.asarray(ref)
         )
@@ -305,29 +306,31 @@ def test_cancelled_future_does_not_kill_the_drain_thread(analytic):
 
 
 def test_engine_drain_tolerates_cancelled_future(analytic):
-    engine = make_engine(analytic)
-    t1, fut1 = engine.submit_with_future(req(seed=0))
-    t2, fut2 = engine.submit_with_future(req(seed=1))
+    """A future cancelled before ``flush()`` must not break the flush: the
+    co-batched request still gets its result and the launch is counted."""
+    sched = AsyncBatchedSampler(make_engine(analytic), params=None)
+    fut1 = sched.submit(req(seed=0))
+    fut2 = sched.submit(req(seed=1))
     assert fut1.cancel()
-    results = engine.drain(params=None)
-    assert set(results) == {t1, t2}  # the drain dict still carries both
+    assert sched.flush() == 1
+    assert sched.stats()[K.ROWS] == 2  # the cancelled row still ran
     assert fut2.result(timeout=0).x0.shape == (1, 6, D_MODEL)
 
 
-def test_submit_with_future_is_atomic_under_concurrent_drains(analytic):
-    """A drain loop racing submitters can never orphan a result: the Future
+def test_submit_is_atomic_under_concurrent_flushes(analytic):
+    """A flush loop racing submitters can never orphan a result: the Future
     comes back from the same locked section that enqueues the ticket."""
-    engine = make_engine(analytic)
+    sched = AsyncBatchedSampler(make_engine(analytic), params=None)
     stop = threading.Event()
 
-    def drain_loop():
+    def flush_loop():
         while not stop.is_set():
-            engine.drain(params=None)
+            sched.flush()
 
-    th = threading.Thread(target=drain_loop)
+    th = threading.Thread(target=flush_loop)
     th.start()
     try:
-        futs = [engine.submit_with_future(req(seed=s))[1] for s in range(8)]
+        futs = [sched.submit(req(seed=s)) for s in range(8)]
         for f in futs:
             assert f.result(timeout=60).x0.shape == (1, 6, D_MODEL)
     finally:
@@ -336,11 +339,10 @@ def test_submit_with_future_is_atomic_under_concurrent_drains(analytic):
 
 
 def test_sync_and_async_paths_share_compiled_buckets(analytic):
-    """The scheduler reuses the sync engine's jit cache — same bucket, same
-    program, zero extra compiles."""
+    """The drain thread reuses the program ``flush()`` compiled — same
+    bucket, same program, zero extra compiles."""
     engine = make_engine(analytic, buckets=(4,))
-    engine.submit(req(seed=0))
-    engine.drain(params=None)
+    run_flush(engine, [req(seed=0)])
     cached = set(engine.compile_cache())
     with AsyncBatchedSampler(
         engine, params=None, policy=SchedulerPolicy(max_wait_ms=2.0)
@@ -349,3 +351,35 @@ def test_sync_and_async_paths_share_compiled_buckets(analytic):
         for f in futs:
             f.result(timeout=60)
     assert set(engine.compile_cache()) == cached
+
+
+def test_flush_serves_mixed_groups_on_caller_thread(analytic, monkeypatch):
+    """``flush()`` runs every queued fuse group on the calling thread, one
+    chunk per group, with no drain thread started; a request submitted
+    after it waits for the next flush."""
+    engine = make_engine(analytic)
+    threads = []
+    orig = engine.run_chunk
+
+    def recording(params, seq_len, nfe, chunk, results, pad=True):
+        threads.append(threading.current_thread())
+        return orig(params, seq_len, nfe, chunk, results, pad=pad)
+
+    monkeypatch.setattr(engine, "run_chunk", recording)
+    sched = AsyncBatchedSampler(engine, params=None)
+    mixed = [
+        sched.submit(req(seed=0, seq_len=4)),
+        sched.submit(req(seed=1, seq_len=6)),
+        sched.submit(req(seed=2, seq_len=4, batch=2)),
+        sched.submit(req(seed=3, seq_len=6, nfe=10)),
+    ]
+    assert sched.flush() == 3  # (4, 8), (6, 8) and (6, 10)
+    assert threads == [threading.current_thread()] * 3
+    assert [f.result(timeout=0).padded_batch for f in mixed] == [4, 2, 4, 2]
+    assert sched.pending == 0
+
+    late = sched.submit(req(seed=4))
+    assert not late.done()
+    assert sched.flush() == 1
+    assert late.result(timeout=0).x0.shape == (1, 6, D_MODEL)
+    assert sched.stats()[K.BATCHES] == 4

@@ -14,22 +14,21 @@ reduction (see ``era._seq_sq_sums``).
 
 Also walled here: the compile count is bounded by the bucket ladder (not by
 distinct seq_lens), over-ladder requests are rejected at submit with an
-actionable message, ``padded_seq_len`` is surfaced through results and the
-facade info dict, unmaskable denoisers / non-fusable configs fall back to
-exact-shape grouping, and the mesh8 mixed-length drain matches.
+actionable message, ``padded_seq_len`` is surfaced through results and
+their ``info`` dict, unmaskable denoisers / non-fusable configs fall back to
+exact-shape grouping, and the mesh8 mixed-length run matches.
 """
 
 import numpy as np
 import pytest
 
 from hypothesis import given, settings, strategies as st
-from conftest import AnalyticGaussian, OracleDenoiser
+from conftest import AnalyticGaussian, OracleDenoiser, run_flush
 from repro.core import ERAConfig
 from repro.serving import (
     AsyncBatchedSampler,
-    BatchedSampler,
+    FusedExecutor,
     SampleRequest,
-    SamplerService,
     result_keys as K,
 )
 
@@ -40,7 +39,7 @@ SEQ_BUCKETS = (4, 8)
 
 
 def _bucketed_engine(mesh=None, seq_buckets=SEQ_BUCKETS, **kw):
-    return BatchedSampler(
+    return FusedExecutor(
         OracleDenoiser(ANALYTIC),
         ANALYTIC.schedule,
         batch_buckets=(2, 4, 8),
@@ -51,7 +50,7 @@ def _bucketed_engine(mesh=None, seq_buckets=SEQ_BUCKETS, **kw):
 
 
 def _exact_engine(mesh=None):
-    return BatchedSampler(
+    return FusedExecutor(
         OracleDenoiser(ANALYTIC),
         ANALYTIC.schedule,
         batch_buckets=None,
@@ -61,9 +60,7 @@ def _exact_engine(mesh=None):
 
 def _solo(req, mesh=None):
     """Exact-shape solo run of one request (no seq bucketing anywhere)."""
-    engine = _exact_engine(mesh=mesh)
-    ticket = engine.submit(req)
-    return engine.drain(None)[ticket]
+    return run_flush(_exact_engine(mesh=mesh), [req])[0]
 
 
 @settings(max_examples=4, deadline=None)
@@ -85,11 +82,8 @@ def test_padding_invariance_bitwise(n, seq0, extra, seed0):
                       seed=seed0 + i)
         for i in range(n)
     ]
-    engine = _bucketed_engine()
-    tickets = [engine.submit(r) for r in reqs]
-    fused = engine.drain(None)
-    for ticket, req in zip(tickets, reqs):
-        got = fused[ticket]
+    fused = run_flush(_bucketed_engine(), reqs)
+    for got, req in zip(fused, reqs):
         ref = _solo(req)
         assert got.x0.shape == (req.batch, req.seq_len,
                                 OracleDenoiser.D_MODEL)
@@ -119,11 +113,9 @@ def test_mixed_lengths_fuse_into_one_chunk_per_bucket():
         SampleRequest(batch=1, seq_len=L, nfe=6, seed=10 + i)
         for i, L in enumerate([1, 3, 4, 2])  # all bucket to 4
     ]
-    tickets = [engine.submit(r) for r in reqs]
-    results = engine.drain(None)
-    for t in tickets:
-        assert results[t].padded_seq_len == 4
-        assert results[t].padded_batch == 4  # one fused chunk of 4 rows
+    for res in run_flush(engine, reqs):
+        assert res.padded_seq_len == 4
+        assert res.padded_batch == 4  # one fused chunk of 4 rows
     keys = set(engine.compile_cache())
     assert len(keys) == 1
     (key,) = keys
@@ -134,9 +126,8 @@ def test_mixed_lengths_fuse_into_one_chunk_per_bucket():
         SampleRequest(batch=1, seq_len=L, nfe=6, seed=50 + i)
         for i, L in enumerate([2, 4, 6, 8, 5])
     ]
-    tickets = [engine.submit(r) for r in more]
-    results = engine.drain(None)
-    assert {results[t].padded_seq_len for t in tickets} == {4, 8}
+    results = run_flush(engine, more)
+    assert {r.padded_seq_len for r in results} == {4, 8}
     assert {k[3] for k in engine.compile_cache()} <= set(SEQ_BUCKETS)
     compiled = len(engine.compile_cache())
 
@@ -147,38 +138,35 @@ def test_mixed_lengths_fuse_into_one_chunk_per_bucket():
         SampleRequest(batch=1, seq_len=L, nfe=6, seed=80 + i)
         for i, L in enumerate([1, 2, 5, 6, 7, 8])
     ]
-    tickets = [engine.submit(r) for r in third]
-    engine.drain(None)
+    run_flush(engine, third)
     assert len(engine.compile_cache()) == compiled
 
 
 def test_seq_len_above_ladder_rejected_at_submit():
     engine = _bucketed_engine()
     with pytest.raises(ValueError, match="exceeds the largest seq bucket"):
-        engine.submit(SampleRequest(batch=1, seq_len=9, nfe=6))
-    # the async scheduler rejects at submit too (same validate path)
+        engine.validate(SampleRequest(batch=1, seq_len=9, nfe=6))
+    # the scheduler rejects at submit (same validate path)
     sched = AsyncBatchedSampler(engine, params=None)
     with pytest.raises(ValueError, match="exceeds the largest seq bucket"):
         sched.submit(SampleRequest(batch=1, seq_len=64, nfe=6))
     sched.stop()
     # engines without a ladder accept any length
-    _exact_engine().submit(SampleRequest(batch=1, seq_len=64, nfe=6))
+    sched = AsyncBatchedSampler(_exact_engine(), params=None)
+    sched.submit(SampleRequest(batch=1, seq_len=64, nfe=6))
+    assert sched.pending == 1
 
 
 def test_padded_seq_len_surfaced_in_results_and_facade_info():
-    engine = _bucketed_engine()
-    t = engine.submit(SampleRequest(batch=1, seq_len=3, nfe=6, seed=1))
-    res = engine.drain(None)[t]
+    req = SampleRequest(batch=1, seq_len=3, nfe=6, seed=1)
+    (res,) = run_flush(_bucketed_engine(), [req])
     assert res.padded_seq_len == 4
     assert res.padded_batch >= 1
 
-    svc = SamplerService(
-        OracleDenoiser(ANALYTIC),
-        ANALYTIC.schedule,
-        solver_config=ERAConfig(per_sample=True),
+    (res,) = run_flush(
+        _exact_engine(), [SampleRequest(batch=2, seq_len=6, nfe=6)]
     )
-    res = svc.sample(None, SampleRequest(batch=2, seq_len=6, nfe=6))
-    assert res.info[K.PADDED_SEQ_LEN] == 6  # facade runs exact-shape
+    assert res.info[K.PADDED_SEQ_LEN] == 6  # no ladder: exact shape
     assert res.info[K.PADDED_BATCH] == 2
     assert res.x0.shape == (2, 6, OracleDenoiser.D_MODEL)
 
@@ -188,57 +176,59 @@ def test_unmaskable_denoiser_falls_back_to_exact_shape():
     groups even when a ladder is configured."""
     dlm = OracleDenoiser(ANALYTIC)
     dlm.supports_length_masking = False
-    engine = BatchedSampler(
+    engine = FusedExecutor(
         dlm, ANALYTIC.schedule, batch_buckets=(2, 4),
         seq_buckets=SEQ_BUCKETS,
     )
-    assert engine.executor.seq_masked("era") is False
-    assert engine.executor.group_key(
+    assert engine.seq_masked("era") is False
+    assert engine.group_key(
         SampleRequest(batch=1, seq_len=3, nfe=6)
     ) == ("era", 3, 6)
-    t = engine.submit(SampleRequest(batch=1, seq_len=3, nfe=6, seed=0))
-    res = engine.drain(None)[t]
-    assert res.padded_seq_len == 3  # exact shape, no masking
+    sched = AsyncBatchedSampler(engine, params=None)
+    fut = sched.submit(SampleRequest(batch=1, seq_len=3, nfe=6, seed=0))
+    sched.flush()
+    assert fut.result().padded_seq_len == 3  # exact shape, no masking
     # the ladder still bounds accepted lengths (serving contract)
     with pytest.raises(ValueError, match="exceeds the largest seq bucket"):
-        engine.submit(SampleRequest(batch=1, seq_len=99, nfe=6))
+        sched.submit(SampleRequest(batch=1, seq_len=99, nfe=6))
 
 
 def test_non_fusable_config_falls_back_to_exact_shape():
     """Shared-delta ERA couples rows through one error norm — it cannot pad
     (rows or positions), so its traffic groups by exact seq_len."""
-    engine = BatchedSampler(
+    engine = FusedExecutor(
         OracleDenoiser(ANALYTIC),
         ANALYTIC.schedule,
         solver_config=ERAConfig(per_sample=False),
         batch_buckets=(2, 4),
         seq_buckets=SEQ_BUCKETS,
     )
-    assert engine.executor.seq_masked("era") is False
-    assert engine.executor.group_key(
+    assert engine.seq_masked("era") is False
+    assert engine.group_key(
         SampleRequest(batch=2, seq_len=5, nfe=6)
     ) == ("era", 5, 6)
 
 
 def test_trajectory_aux_sliced_to_request_seq_len():
-    engine = BatchedSampler(
+    engine = FusedExecutor(
         OracleDenoiser(ANALYTIC),
         ANALYTIC.schedule,
         solver_config=ERAConfig(per_sample=True, return_trajectory=True),
         batch_buckets=(4,),
         seq_buckets=SEQ_BUCKETS,
     )
-    ta = engine.submit(SampleRequest(batch=1, seq_len=3, nfe=6, seed=0))
-    tb = engine.submit(SampleRequest(batch=2, seq_len=7, nfe=6, seed=1))
-    results = engine.drain(None)
-    assert results[ta].aux["trajectory"].shape == (
+    ra, rb = run_flush(engine, [
+        SampleRequest(batch=1, seq_len=3, nfe=6, seed=0),
+        SampleRequest(batch=2, seq_len=7, nfe=6, seed=1),
+    ])
+    assert ra.aux["trajectory"].shape == (
         7, 1, 3, OracleDenoiser.D_MODEL
     )
-    assert results[tb].aux["trajectory"].shape == (
+    assert rb.aux["trajectory"].shape == (
         7, 2, 7, OracleDenoiser.D_MODEL
     )
     # per-sample aux keeps per-request rows only
-    assert results[tb].aux["ers_selection_history"].shape[1] == 2
+    assert rb.aux["ers_selection_history"].shape[1] == 2
 
 
 def test_mixed_solver_mixed_length_routing():
@@ -253,12 +243,11 @@ def test_mixed_solver_mixed_length_routing():
              (4, "ddim"), (7, None)]
         )
     ]
-    tickets = [engine.submit(r) for r in reqs]
-    fused = engine.drain(None)
-    for ticket, req in zip(tickets, reqs):
+    fused = run_flush(engine, reqs)
+    for got, req in zip(fused, reqs):
         ref = _solo(req)
         np.testing.assert_array_equal(
-            np.asarray(fused[ticket].x0), np.asarray(ref.x0),
+            np.asarray(got.x0), np.asarray(ref.x0),
             err_msg=f"solver={req.solver} seq_len={req.seq_len}",
         )
     solvers_compiled = {k[0] for k in engine.compile_cache()}
@@ -315,10 +304,10 @@ def _real_dlm_engine(arch: str):
     dlm = DiffusionLM(build_model(cfg))
     params = dlm.init(jax.random.PRNGKey(0))
     schedule = linear_schedule()
-    engine = BatchedSampler(
+    engine = FusedExecutor(
         dlm, schedule, batch_buckets=(2, 4), seq_buckets=SEQ_BUCKETS
     )
-    exact = BatchedSampler(dlm, schedule, batch_buckets=None)
+    exact = FusedExecutor(dlm, schedule, batch_buckets=None)
     return engine, exact, params
 
 
@@ -326,23 +315,20 @@ def _real_dlm_engine(arch: str):
 def test_real_denoiser_padding_invariance_wall(arch):
     """The full padding-invariance wall (x0 + per-sample ERS selections) on
     real SSM (xlstm) and MLA (deepseek-v2-lite) DiffusionLM stacks — the
-    block kinds PR 5 excluded from fusion.  A mixed-length fused drain must
+    block kinds PR 5 excluded from fusion.  A mixed-length fused batch must
     match each request's exact-shape solo run at the real-denoiser parity
     bar (atol=1e-6; observed bit-identical on CPU smoke shapes), with
     bitwise-identical ERS basis selections."""
     engine, exact, params = _real_dlm_engine(arch)
-    assert engine.executor.seq_masked("era") is True
+    assert engine.seq_masked("era") is True
     reqs = [
         SampleRequest(batch=1, seq_len=L, nfe=5, seed=700 + i)
         for i, L in enumerate([3, 8, 5])
     ]
-    tickets = [engine.submit(r) for r in reqs]
-    fused = engine.drain(params)
-    for ticket, req in zip(tickets, reqs):
-        got = fused[ticket]
+    fused = run_flush(engine, reqs, params)
+    for got, req in zip(fused, reqs):
         assert got.padded_seq_len == (4 if req.seq_len <= 4 else 8)
-        t_ref = exact.submit(req)
-        ref = exact.drain(params)[t_ref]
+        (ref,) = run_flush(exact, [req], params)
         np.testing.assert_allclose(
             np.asarray(got.x0), np.asarray(ref.x0), atol=1e-6,
             err_msg=f"{arch}: fused padded x0 diverged from exact-shape "
@@ -354,9 +340,9 @@ def test_real_denoiser_padding_invariance_wall(arch):
             err_msg=f"{arch}: ERS basis selection flipped under padding "
             f"(seq_len={req.seq_len})",
         )
-    # the canary: a fully-maskable stack drains masked fused traffic with
+    # the canary: a fully-maskable stack runs masked fused traffic with
     # zero fast-path fallbacks
-    counter = engine.executor.metrics.get("sampler_masked_fallback_total")
+    counter = engine.metrics.get("sampler_masked_fallback_total")
     assert counter is not None
     assert not counter._values, dict(counter._values)
 
@@ -366,40 +352,36 @@ def test_masked_fallback_counter_counts_engine_fallbacks():
     ``sampler_masked_fallback_total`` canary with the engine label."""
     dlm = OracleDenoiser(ANALYTIC)
     dlm.supports_length_masking = False
-    engine = BatchedSampler(
+    engine = FusedExecutor(
         dlm, ANALYTIC.schedule, batch_buckets=(2, 4), seq_buckets=SEQ_BUCKETS
     )
-    assert engine.executor.seq_masked("era") is False
-    counter = engine.executor.metrics.get("sampler_masked_fallback_total")
+    assert engine.seq_masked("era") is False
+    counter = engine.metrics.get("sampler_masked_fallback_total")
     assert counter.value(impl="seq-bucketing", reason="denoiser-unmaskable") == 1
     # the verdict is cached per solver: re-asking does not re-count
-    assert engine.executor.seq_masked("era") is False
+    assert engine.seq_masked("era") is False
     assert counter.value(impl="seq-bucketing", reason="denoiser-unmaskable") == 1
 
 
 def test_mesh_mixed_length_drain_parity(mesh8):
-    """Mixed-length fused drains on the 8-device mesh: bit-identical to the
-    mesh exact-shape drains, and matching the single-device bucketed run
+    """Mixed-length fused batches on the 8-device mesh: bit-identical to
+    the mesh exact-shape runs, and matching the single-device bucketed run
     to float tolerance (the established mesh-parity bar)."""
     reqs = [
         SampleRequest(batch=1, seq_len=L, nfe=7, seed=900 + i)
         for i, L in enumerate([2, 5, 8, 3, 6])
     ]
-    mesh_engine = _bucketed_engine(mesh=mesh8)
-    tickets = [mesh_engine.submit(r) for r in reqs]
-    fused = mesh_engine.drain(None)
-    single = _bucketed_engine()
-    stickets = [single.submit(r) for r in reqs]
-    sres = single.drain(None)
-    for ticket, sticket, req in zip(tickets, stickets, reqs):
+    fused = run_flush(_bucketed_engine(mesh=mesh8), reqs)
+    sres = run_flush(_bucketed_engine(), reqs)
+    for got, single, req in zip(fused, sres, reqs):
         ref = _solo(req, mesh=mesh8)
         np.testing.assert_array_equal(
-            np.asarray(fused[ticket].x0), np.asarray(ref.x0),
+            np.asarray(got.x0), np.asarray(ref.x0),
             err_msg=f"mesh bucketed vs mesh exact diverged "
             f"(seq_len={req.seq_len})",
         )
         np.testing.assert_allclose(
-            np.asarray(fused[ticket].x0), np.asarray(sres[sticket].x0),
+            np.asarray(got.x0), np.asarray(single.x0),
             atol=1e-5,
             err_msg=f"mesh vs single-device bucketed diverged "
             f"(seq_len={req.seq_len})",

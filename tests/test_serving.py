@@ -1,17 +1,20 @@
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
+from conftest import run_flush
 from repro.configs import get_config
-from repro.core import ERAConfig, linear_schedule
+from repro.core import ERAConfig, linear_schedule, sample_program
 from repro.models import build_model
 from repro.models.diffusion import DiffusionLM
 from repro.serving import (
     Engine,
+    EngineConfig,
     SampleRequest,
-    SamplerService,
     ServeConfig,
+    build_engine,
     cache_slots,
     resolve_window,
     result_keys as K,
@@ -90,16 +93,27 @@ def test_engine_generate_on_mesh_matches_single_device(mesh8):
     np.testing.assert_array_equal(np.asarray(single)[:3], np.asarray(toks3))
 
 
+def _one_shot_engine(dlm, solver="era"):
+    """The one-shot CLI mode's engine: exact-size, the paper's shared-delta
+    ERA (k=3 at NFE 6) or the solver's registry default."""
+    cfg = EngineConfig(
+        solver=solver, nfe=6, k=3, per_sample=False, batch_buckets=None
+    )
+    return build_engine(dlm, linear_schedule(), cfg)
+
+
 def test_sampler_service_solver_choice():
     cfg = get_config("qwen2-1.5b", smoke=True)
     dlm = DiffusionLM(build_model(cfg))
     params = dlm.init(KEY)
-    sched = linear_schedule()
     outs = {}
     for solver in ("ddim", "era"):
-        sc = ERAConfig(nfe=6, k=3) if solver == "era" else None
-        svc = SamplerService(dlm, sched, solver, sc)
-        x0 = svc.sample(params, SampleRequest(batch=2, seq_len=8, nfe=6)).x0
+        (res,) = run_flush(
+            _one_shot_engine(dlm, solver),
+            [SampleRequest(batch=2, seq_len=8, nfe=6)],
+            params,
+        )
+        x0 = res.x0
         assert x0.shape == (2, 8, cfg.d_model)
         assert not bool(jnp.any(jnp.isnan(x0)))
         outs[solver] = np.asarray(x0)
@@ -107,30 +121,60 @@ def test_sampler_service_solver_choice():
 
 
 def test_sampler_service_surfaces_engine_telemetry():
-    """The facade's info dict carries the same telemetry as the engine's
-    SampleResult: latency_s and padded_batch, not just wall_s + aux."""
+    """A result's info dict carries the engine telemetry alongside the
+    solver aux: latency_s and padded_batch, not just wall_s."""
     cfg = get_config("qwen2-1.5b", smoke=True)
     dlm = DiffusionLM(build_model(cfg))
     params = dlm.init(KEY)
-    svc = SamplerService(dlm, linear_schedule(), "era", ERAConfig(nfe=6, k=3))
-    res = svc.sample(params, SampleRequest(batch=2, seq_len=8, nfe=6))
+    (res,) = run_flush(
+        _one_shot_engine(dlm), [SampleRequest(batch=2, seq_len=8, nfe=6)], params
+    )
     info = res.info
-    assert info[K.PADDED_BATCH] == 2  # exact-size facade buckets
+    assert info[K.PADDED_BATCH] == 2  # exact-size buckets
     assert info[K.LATENCY_S] >= info[K.WALL_S] > 0
     assert K.DELTA_EPS_HISTORY in info
-    # the pre-unification tuple unpacking still works, with a warning
-    with pytest.warns(DeprecationWarning, match="tuple unpacking"):
-        x0, info2 = res
-    assert x0 is res.x0 and set(info2) == set(info)
 
 
 def test_sample_program_lowerable():
-    """The whole ERA sampling loop lowers as one XLA program."""
+    """The whole ERA sampling loop lowers as one XLA program:
+    ``sample_program``, the program ``launch/dryrun.py`` lowers."""
     cfg = get_config("llama3.2-1b", smoke=True)
     dlm = DiffusionLM(build_model(cfg))
-    svc = SamplerService(dlm, linear_schedule(), "era", ERAConfig(nfe=6, k=3))
-    prog = svc.sample_program()
+    prog = sample_program(dlm, linear_schedule(), "era", ERAConfig(nfe=6, k=3))
     aparams = dlm.init_abstract()
     x = jax.ShapeDtypeStruct((2, 8, cfg.d_model), jnp.float32)
     lowered = jax.jit(prog).lower(aparams, x)
     assert lowered.compile() is not None
+
+
+def test_serve_cli_one_shot_diffusion(monkeypatch, capsys):
+    """``launch/serve.py --mode diffusion`` (no --continuous / --listen)
+    builds the engine, submits one request and flushes it on its own
+    thread."""
+    from repro.launch import serve
+
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--arch", "qwen2-1.5b", "--smoke", "--mode", "diffusion",
+        "--nfe", "6", "--k", "3", "--batch", "2", "--seq", "8",
+    ])
+    serve.main()
+    out = capsys.readouterr().out
+    d_model = get_config("qwen2-1.5b", smoke=True).d_model
+    assert f"sampled latents (2, 8, {d_model}) via era nfe=6" in out
+
+
+def test_serve_cli_continuous_diffusion(monkeypatch, capsys):
+    """``launch/serve.py --mode diffusion --continuous`` drives an
+    open-loop Poisson stream (``open_loop``) through the scheduler's
+    drain thread and reports its latency percentiles."""
+    from repro.launch import serve
+
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--arch", "qwen2-1.5b", "--smoke", "--mode", "diffusion",
+        "--continuous", "--requests", "4", "--rate", "100", "--no-warm",
+        "--nfe", "6", "--k", "3", "--seq", "8", "--batch-buckets", "1,4",
+    ])
+    serve.main()
+    out = capsys.readouterr().out
+    assert "continuous[era]: 4 req @ 100.0/s" in out
+    assert "p50=" in out and "batches=" in out

@@ -207,26 +207,26 @@ def test_sampler_shardings_on_real_mesh(mesh8):
 
 
 def test_mesh_drain_places_rows_across_devices(mesh8, analytic):
-    from repro.serving import BatchedSampler, SampleRequest
+    import numpy as np
 
-    eng = BatchedSampler(
+    from conftest import run_flush
+    from repro.serving import FusedExecutor, SampleRequest
+
+    eng = FusedExecutor(
         OracleDenoiser(analytic), analytic.schedule, mesh=mesh8
     )
     assert eng.dp == 8
-    t = eng.submit(SampleRequest(batch=8, seq_len=6, nfe=6, seed=0))
-    res = eng.drain(params=None)[t]
+    req = SampleRequest(batch=8, seq_len=6, nfe=6, seed=0)
+    (res,) = run_flush(eng, [req])
     assert res.padded_batch == 8
-    # one row per device: the drain really ran data-parallel
+    # one row per device: the batch really ran data-parallel
     assert len(res.x0.sharding.device_set) == 8
-    solo = BatchedSampler(
+    solo = FusedExecutor(
         OracleDenoiser(analytic), analytic.schedule, batch_buckets=None
     )
-    t2 = solo.submit(SampleRequest(batch=8, seq_len=6, nfe=6, seed=0))
-    import numpy as np
-
     np.testing.assert_allclose(
         np.asarray(res.x0),
-        np.asarray(solo.drain(params=None)[t2].x0),
+        np.asarray(run_flush(solo, [req])[0].x0),
         atol=1e-5,
     )
 

@@ -16,14 +16,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import OracleDenoiser
+from conftest import OracleDenoiser, run_flush
 from repro.core import ERAConfig, default_config, get_solver
 from repro.serving import (
     AsyncBatchedSampler,
-    BatchedSampler,
+    EngineConfig,
+    FusedExecutor,
     SampleRequest,
-    SamplerService,
     SchedulerPolicy,
+    build_engine,
 )
 
 D_MODEL = OracleDenoiser.D_MODEL
@@ -31,7 +32,7 @@ D_MODEL = OracleDenoiser.D_MODEL
 
 @pytest.fixture()
 def engine(analytic):
-    return BatchedSampler(
+    return FusedExecutor(
         OracleDenoiser(analytic), analytic.schedule, batch_buckets=(2, 4, 8)
     )
 
@@ -59,24 +60,21 @@ def _solo(analytic, solver, seed, batch, nfe, seq_len=6):
 
 
 def test_mixed_solver_requests_in_one_drain_route_correctly(engine, analytic):
-    """Two requests with different ``solver`` fields in one drain() come
+    """Two requests with different ``solver`` fields in one flush() come
     back from *their own* solvers, not the engine default."""
-    t_era = engine.submit(SampleRequest(batch=1, seq_len=6, nfe=8, seed=1))
-    t_ddim = engine.submit(
-        SampleRequest(batch=2, seq_len=6, nfe=8, solver="ddim", seed=2)
-    )
-    t_pp2m = engine.submit(
-        SampleRequest(batch=1, seq_len=6, nfe=8, solver="dpm_solver_pp2m", seed=3)
-    )
-    results = engine.drain(params=None)
-    for ticket, solver, seed, batch in (
-        (t_era, "era", 1, 1),
-        (t_ddim, "ddim", 2, 2),
-        (t_pp2m, "dpm_solver_pp2m", 3, 1),
+    r_era, r_ddim, r_pp2m = run_flush(engine, [
+        SampleRequest(batch=1, seq_len=6, nfe=8, seed=1),
+        SampleRequest(batch=2, seq_len=6, nfe=8, solver="ddim", seed=2),
+        SampleRequest(batch=1, seq_len=6, nfe=8, solver="dpm_solver_pp2m", seed=3),
+    ])
+    for res, solver, seed, batch in (
+        (r_era, "era", 1, 1),
+        (r_ddim, "ddim", 2, 2),
+        (r_pp2m, "dpm_solver_pp2m", 3, 1),
     ):
         ref = _solo(analytic, solver, seed, batch, nfe=8)
         np.testing.assert_allclose(
-            np.asarray(results[ticket].x0),
+            np.asarray(res.x0),
             np.asarray(ref.x0),
             atol=1e-5,
             err_msg=f"{solver} request did not route to {solver}",
@@ -85,8 +83,8 @@ def test_mixed_solver_requests_in_one_drain_route_correctly(engine, analytic):
     assert (
         np.max(
             np.abs(
-                np.asarray(results[t_ddim].x0[:1])
-                - np.asarray(results[t_pp2m].x0)
+                np.asarray(r_ddim.x0[:1])
+                - np.asarray(r_pp2m.x0)
             )
         )
         > 1e-4
@@ -96,21 +94,20 @@ def test_mixed_solver_requests_in_one_drain_route_correctly(engine, analytic):
 def test_mixed_solver_requests_never_share_a_fused_chunk(
     engine, analytic, monkeypatch
 ):
-    """Same shape, different solvers: the drain groups per solver, so each
+    """Same shape, different solvers: the queue groups per solver, so each
     executed chunk is solver-homogeneous (no bucket cross-contamination)."""
     chunks = []
-    orig = engine.executor.run_chunk
+    orig = engine.run_chunk
 
     def recording(params, seq_len, nfe, chunk, results, pad=True):
         chunks.append({req.solver or "era" for _, req, _ in chunk})
         return orig(params, seq_len, nfe, chunk, results, pad=pad)
 
-    monkeypatch.setattr(engine.executor, "run_chunk", recording)
-    for seed, solver in enumerate([None, "ddim", None, "ddim", "era"]):
-        engine.submit(
-            SampleRequest(batch=1, seq_len=6, nfe=8, solver=solver, seed=seed)
-        )
-    engine.drain(params=None)
+    monkeypatch.setattr(engine, "run_chunk", recording)
+    run_flush(engine, [
+        SampleRequest(batch=1, seq_len=6, nfe=8, solver=solver, seed=seed)
+        for seed, solver in enumerate([None, "ddim", None, "ddim", "era"])
+    ])
     assert len(chunks) == 2  # one era chunk (None+era), one ddim chunk
     for solvers in chunks:
         assert len(solvers) == 1
@@ -118,14 +115,16 @@ def test_mixed_solver_requests_never_share_a_fused_chunk(
 
 def test_unknown_solver_rejected_at_submit_not_drain(engine):
     with pytest.raises(ValueError, match="unknown solver"):
-        engine.submit(SampleRequest(batch=1, seq_len=6, nfe=8, solver="nope"))
-    assert engine.pending == 0  # nothing queued to poison the drain
+        engine.validate(
+            SampleRequest(batch=1, seq_len=6, nfe=8, solver="nope")
+        )
 
 
 def test_unknown_solver_rejected_at_scheduler_submit(engine):
     sched = AsyncBatchedSampler(engine, params=None)
     with pytest.raises(ValueError, match="unknown solver"):
         sched.submit(SampleRequest(batch=1, seq_len=6, nfe=8, solver="nope"))
+    assert sched.pending == 0  # nothing queued to poison a launch
     sched.stop()
 
 
@@ -133,10 +132,9 @@ def test_jit_cache_keys_carry_the_solver(engine):
     """Same (batch, seq_len, nfe) bucket, different solvers -> different
     compiled programs, each compiled once."""
     for solver in (None, "ddim", None, "ddim"):
-        engine.submit(
+        run_flush(engine, [
             SampleRequest(batch=1, seq_len=6, nfe=8, solver=solver, seed=0)
-        )
-        engine.drain(params=None)
+        ])
     cache = engine.compile_cache()
     assert sorted(k[0] for k in cache) == ["ddim", "era"]
     # entries are AOT-compiled executables: one entry == one compile; the
@@ -149,12 +147,18 @@ def test_jit_cache_keys_carry_the_solver(engine):
 
 
 def test_sampler_service_routes_request_solver(analytic):
-    """The facade serves a request naming a different solver than its own
-    default — per-request routing reaches the one-call surface too."""
-    svc = SamplerService(OracleDenoiser(analytic), analytic.schedule, "era")
-    x0 = svc.sample(
-        None, SampleRequest(batch=2, seq_len=6, nfe=8, solver="ddim", seed=5)
-    ).x0
+    """An exact-size engine whose default is the paper's shared-delta ERA
+    (the one-shot CLI mode's engine) serves a request naming a different
+    solver — per-request routing reaches that path too."""
+    engine = build_engine(
+        OracleDenoiser(analytic),
+        analytic.schedule,
+        EngineConfig(per_sample=False, batch_buckets=None),
+    )
+    (res,) = run_flush(engine, [
+        SampleRequest(batch=2, seq_len=6, nfe=8, solver="ddim", seed=5)
+    ])
+    x0 = res.x0
     ref = get_solver("ddim")(
         analytic.eps, _x_init(5, 2), analytic.schedule,
         default_config("ddim", nfe=8),
@@ -169,50 +173,50 @@ def test_sampler_service_routes_request_solver(analytic):
 
 def test_era_validate_rejects_nfe_below_k(engine):
     with pytest.raises(ValueError, match="nfe >= k"):
-        engine.submit(SampleRequest(batch=1, seq_len=6, nfe=3, solver="era"))
+        engine.validate(SampleRequest(batch=1, seq_len=6, nfe=3, solver="era"))
 
 
 def test_pece_validate_rejects_sub_budget_nfe(engine):
     with pytest.raises(ValueError, match="2 NFE per PECE step"):
-        engine.submit(
+        engine.validate(
             SampleRequest(batch=1, seq_len=6, nfe=1, solver="implicit_adams_pece")
         )
     # nfe=2 (one PECE step) is the smallest legal budget
-    t = engine.submit(
+    (res,) = run_flush(engine, [
         SampleRequest(batch=1, seq_len=6, nfe=2, solver="implicit_adams_pece")
-    )
-    res = engine.drain(params=None)[t]
+    ])
     assert res.x0.shape == (1, 6, D_MODEL)
 
 
 def test_pp2m_validate_rejects_warmup_starved_nfe(engine):
     with pytest.raises(ValueError, match="order-1 warmup"):
-        engine.submit(
+        engine.validate(
             SampleRequest(batch=1, seq_len=6, nfe=1, solver="dpm_solver_pp2m")
         )
 
 
 def test_batch_and_nfe_floor_validation(engine):
     with pytest.raises(ValueError, match="batch must be >= 1"):
-        engine.submit(SampleRequest(batch=0, seq_len=6, nfe=8))
+        engine.validate(SampleRequest(batch=0, seq_len=6, nfe=8))
     with pytest.raises(ValueError, match="nfe must be >= 1"):
-        engine.submit(SampleRequest(batch=1, seq_len=6, nfe=0, solver="ddim"))
+        engine.validate(SampleRequest(batch=1, seq_len=6, nfe=0, solver="ddim"))
 
 
 def test_shared_delta_era_route_is_not_fusable(analytic):
     """A request routed to the engine's shared-delta ERA config still runs
     exact-size/unfused (program.fusable consults the routed config)."""
-    eng = BatchedSampler(
+    eng = FusedExecutor(
         OracleDenoiser(analytic),
         analytic.schedule,
         solver_config=ERAConfig(per_sample=False),
         batch_buckets=(8,),
     )
-    t1 = eng.submit(SampleRequest(batch=2, seq_len=6, nfe=10, solver="era", seed=1))
-    t2 = eng.submit(SampleRequest(batch=1, seq_len=6, nfe=10, solver="ddim", seed=2))
-    results = eng.drain(params=None)
-    assert results[t1].padded_batch == 2  # exact size: era not fusable here
-    assert results[t2].padded_batch == 8  # ddim stays fusable: pads to bucket
+    r1, r2 = run_flush(eng, [
+        SampleRequest(batch=2, seq_len=6, nfe=10, solver="era", seed=1),
+        SampleRequest(batch=1, seq_len=6, nfe=10, solver="ddim", seed=2),
+    ])
+    assert r1.padded_batch == 2  # exact size: era not fusable here
+    assert r2.padded_batch == 8  # ddim stays fusable: pads to bucket
 
 
 # ---------------------------------------------------------------------------
